@@ -22,21 +22,24 @@ from repro.core import (
 from repro.data.synthetic import clustered_corpus, pair_batches
 
 
+# The paper's three products, at their published dimensionalities (the
+# corpus sizes are scaled to CPU-runnable ones):
+#   coco:      512-dim float (16384-bit) CLIP-like, -> 1024-bit codes
+#   web:       256-dim float (8192-bit) web search, -> 512-bit codes
+#   video:     128-dim float (4096-bit) copyright,  -> 256-bit codes
+PRODUCTS = {
+    "coco": dict(dim=512, code=256, levels=4, docs=8000, queries=256,
+                 clusters=80, noise=0.30, qnoise=0.20, spectrum=0.5),
+    "web": dict(dim=256, code=128, levels=4, docs=10000, queries=256,
+                clusters=96, noise=0.30, qnoise=0.25, spectrum=0.5),
+    "video": dict(dim=128, code=64, levels=4, docs=10000, queries=256,
+                  clusters=96, noise=0.25, qnoise=0.20, spectrum=0.5),
+}
+
+
 def make_corpus(name: str):
-    """Three corpora matching the paper's dataset statistics (scaled to
-    CPU-runnable sizes; dimensionalities match the paper exactly):
-      coco:      512-dim float (16384-bit) CLIP-like, -> 1024-bit codes
-      web:       256-dim float (8192-bit) web search, -> 512-bit codes
-      video:     128-dim float (4096-bit) copyright,  -> 256-bit codes
-    """
-    spec = {
-        "coco": dict(dim=512, code=256, levels=4, docs=8000, queries=256,
-                     clusters=80, noise=0.30, qnoise=0.20, spectrum=0.5),
-        "web": dict(dim=256, code=128, levels=4, docs=10000, queries=256,
-                    clusters=96, noise=0.30, qnoise=0.25, spectrum=0.5),
-        "video": dict(dim=128, code=64, levels=4, docs=10000, queries=256,
-                      clusters=96, noise=0.25, qnoise=0.20, spectrum=0.5),
-    }[name]
+    """One product's corpus (``PRODUCTS``) at its benchmark size."""
+    spec = PRODUCTS[name]
     docs, queries, gt = clustered_corpus(
         hash(name) % 2**31, spec["docs"], spec["queries"], spec["dim"],
         n_clusters=spec["clusters"], noise=spec["noise"],

@@ -18,6 +18,9 @@ def main() -> None:
     ap.add_argument("--only", default=None)
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     steps = 120 if args.fast else 400
     from benchmarks import (
         bits_sweep,

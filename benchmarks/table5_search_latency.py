@@ -818,7 +818,7 @@ def _autoscale_row(encode, codes_np, levels: int, batches, pcfg,
     cfg = dataclasses.replace(pcfg, queue_depth=2, policy="shed")
     spec = autoscale.TierSpec(
         min_replicas=1, max_replicas=3, index="flat",
-        build_params={"k": 10, "backend": "xla"},
+        build_params={"k": 10}, backend="xla",
         router=router_policy, policy="shed", queue_depth=cfg.queue_depth,
         high_water=0.6, low_water=0.15,
         cooldown_s=0.15, window_s=0.1, tick_s=0.05,

@@ -3,8 +3,9 @@
     PYTHONPATH=src python examples/serve_bebr.py [--index flat|hnsw]
                                                  [--replicas N] [--router P]
 
-Forces 8 host devices and carves them into ``--replicas`` disjoint
-submeshes (``mesh.make_replica_meshes``). Each replica shards the whole
+Carves the devices JAX finds into ``--replicas`` disjoint submeshes
+(``mesh.make_replica_meshes``): the chips of a TPU host, or 8 forced
+host devices on a CPU-only machine. Each replica shards the whole
 binary index over its own leaves and runs the same shard_map
 proxy/leaf/merge program the 512-chip dry-run compiles; a ``QueryRouter``
 (``launch/proxy.py``) spreads query batches across the replicas —
@@ -21,10 +22,10 @@ host-side O(N^2) — the *search* program is the production one.
 
 import os
 
-N_DEVICES = 8  # forced host devices; the --replicas submeshes split these
-
+# Only the CPU platform reads this flag: a CPU-only run gets 8 host
+# devices to carve into submeshes; on a TPU host the chips are the devices.
 os.environ["XLA_FLAGS"] = (
-    f"--xla_force_host_platform_device_count={N_DEVICES} "
+    "--xla_force_host_platform_device_count=8 "
     + os.environ.get("XLA_FLAGS", "")
 )
 
@@ -43,6 +44,7 @@ from repro.kernels.sdc import ref as R
 from repro.launch import (
     autoscale,
     binarizer_cache,
+    compile_cache,
     faults,
     lifecycle,
     proxy,
@@ -56,15 +58,15 @@ def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--index", choices=["flat", "hnsw"], default="flat")
     ap.add_argument("--replicas", type=int, default=2,
-                    help="engine replicas; the 8 host devices are split "
-                         "into this many disjoint submeshes")
+                    help="engine replicas; the devices are split into "
+                         "this many disjoint submeshes")
     ap.add_argument("--router", choices=sorted(proxy.ROUTING_POLICIES),
                     default="round-robin", help="replica routing policy")
     ap.add_argument("--tier-spec", default=None, metavar="SPEC.json",
                     help="declarative tier spec (launch/autoscale.py): "
                          "starts the tier at min_replicas and runs the "
                          "shed-pressure autoscaler over the stream. The "
-                         "8 host devices are carved into max_replicas "
+                         "devices are carved into max_replicas "
                          "submeshes up front, so every replica the "
                          "autoscaler may ever add already owns its "
                          "devices; scale-ups build the engine program on "
@@ -108,6 +110,12 @@ def main():
                          "to watch failover + revival on the sharded "
                          "tier")
     args = ap.parse_args()
+    cache_dir, warm = compile_cache.enable_compile_cache()
+    devices = jax.devices()
+    n_devices = len(devices)
+    print(f"devices: platform={devices[0].platform} "
+          f"kind={devices[0].device_kind} count={n_devices}; compile cache "
+          f"{cache_dir} ({'warm' if warm else 'cold'})")
     spec = None
     if args.tier_spec:
         try:
@@ -120,14 +128,15 @@ def main():
     # scale-up must only instantiate a program on an already-reserved
     # submesh, never re-partition live devices.
     n_slots = spec.max_replicas if spec is not None else args.replicas
-    if N_DEVICES % n_slots:
-        ap.error(f"replica slots ({n_slots}) must divide {N_DEVICES}")
+    if n_devices % n_slots:
+        ap.error(f"replica slots ({n_slots}) must divide the {n_devices} "
+                 "devices")
     if bool(args.coarse_levels) != bool(args.k_coarse):
         ap.error("--coarse-levels and --k-coarse must be set together")
     if args.coarse_levels and args.index != "flat":
         ap.error("--coarse-levels requires --index flat (per-leaf coarse "
                  "scan + post-merge rerank)")
-    per = N_DEVICES // n_slots
+    per = n_devices // n_slots
     shape = (per // 2, 2) if per % 2 == 0 else (per, 1)
 
     dim, code, levels = 128, 64, 4
@@ -227,9 +236,9 @@ def main():
     replica_fns, injectors = faults.apply_chaos(replica_fns, args.chaos)
     t0 = time.time()
     # share_device stays False: the submeshes model disjoint production
-    # hardware (where replica scans genuinely run in parallel). The 8
-    # forced host "devices" actually share this machine's cores, so the
-    # demo's QPS numbers carry that contention — agreement, routing,
+    # hardware (where replica scans genuinely run in parallel). On a CPU
+    # host the 8 forced "devices" actually share this machine's cores, so
+    # the demo's QPS numbers carry that contention — agreement, routing,
     # failover and rolling-swap semantics are what this example
     # demonstrates. The router is driven directly (rather than through
     # serve_replicated) so a mid-stream rolling swap / canary probe can
@@ -284,8 +293,8 @@ def main():
     print(f"leaf/merge top-10 vs exact agreement: {agree:.3f}")
     print(f"ground-truth recall@10: {recall:.3f}")
     print(f"sequential (1 replica): {n_q/dt_seq:.0f} QPS | routed "
-          f"({args.replicas} replicas): {n_q/dt:.0f} QPS on {N_DEVICES} "
-          f"host-CPU leaves (p50 {stats['latency_p50_ms']:.1f} ms, "
+          f"({args.replicas} replicas): {n_q/dt:.0f} QPS on {n_devices} "
+          f"{devices[0].platform} leaves (p50 {stats['latency_p50_ms']:.1f} ms, "
           f"p99 {stats['latency_p99_ms']:.1f} ms, device idle "
           f"{100*stats['device_idle_frac']:.0f}%)")
     for srep in stats["per_replica"]:

@@ -400,19 +400,20 @@ def pack_codes_nibbles(codes: jax.Array) -> jax.Array:
 
 
 def unpack_nibble_planes(packed: jax.Array):
-    """Packed uint8 [..., D//2] -> (lo, hi) uint8 planes in [0, 16).
+    """Packed uint8 [..., D//2] -> (lo, hi) int32 planes in [0, 16).
 
     ``lo`` holds the even dims (0, 2, ...), ``hi`` the odd dims — the
     layout-critical inverse of ``pack_codes_nibbles``. Every packed scoring
     path (Pallas tiles, jnp fallbacks, IVF gather) unpacks through this one
     helper so the nibble layout cannot silently diverge between backends.
+    The shifts run on int32: Mosaic cannot legalize an 8-bit shift.
     """
-    p = packed.astype(jnp.uint8)
+    p = packed.astype(jnp.uint8).astype(jnp.int32)
     return p & 0xF, (p >> 4) & 0xF
 
 
 def unpack_codes_nibbles(packed: jax.Array) -> jax.Array:
     """Packed uint8 [..., D//2] -> integer codes [..., D] (int8)."""
     lo, hi = unpack_nibble_planes(packed)
-    out = jnp.stack([lo, hi], axis=-1)
-    return out.reshape(*packed.shape[:-1], packed.shape[-1] * 2).astype(jnp.int8)
+    out = jnp.stack([lo, hi], axis=-1).astype(jnp.int8)
+    return out.reshape(*packed.shape[:-1], packed.shape[-1] * 2)

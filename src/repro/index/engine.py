@@ -34,7 +34,7 @@ from typing import Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.index.hnsw_lite import ShardedHNSW, hnsw_frontier_search
@@ -50,7 +50,7 @@ def _leaf_scan(
     *,
     n_levels: int,
     k: int,
-    backend: str = "xla",
+    backend: str = "auto",
     packed: bool = False,
     block_q: int = BLOCK_Q,
     block_n: int = BLOCK_N,
@@ -61,6 +61,7 @@ def _leaf_scan(
     materialised) or the jnp fallback; both treat shard_inv == 0 entries
     as excluded (drained docs) and surface empty slots as -inf.
     """
+    backend = resolve_backend(backend)
     if backend in ("pallas", "interpret"):
         vals, idx = sdc_search(
             q_codes,
@@ -132,7 +133,7 @@ def _make_search(
     in_specs = (P(), P(axes), P(axes)) + ((P(),) if failover else ())
     fn = shard_map(
         search, mesh=mesh, in_specs=in_specs, out_specs=(P(), P()),
-        check_rep=False,
+        check_vma=False,
     )
     return jax.jit(fn)
 
@@ -278,7 +279,7 @@ def make_hnsw_search(
     in_specs = (P(),) + (P(axes),) * 6
     fn = shard_map(
         search, mesh=mesh, in_specs=in_specs, out_specs=(P(), P()),
-        check_rep=False,
+        check_vma=False,
     )
     return jax.jit(fn)
 
@@ -413,6 +414,7 @@ def engine_search_from_snapshot(
         def snapshot_search(q_codes):
             return search(jax.device_put(q_codes, qspec), *ins)
 
+        snapshot_search.placed = tuple(ins)  # the device-placed shards
         return snapshot_search
 
     import numpy as np
@@ -451,6 +453,7 @@ def engine_search_from_snapshot(
     if effort is not None:
         snapshot_search.effort = effort
     snapshot_search.reranked = True
+    snapshot_search.placed = tuple(ins)
     return snapshot_search
 
 
@@ -547,4 +550,5 @@ def hnsw_engine_search_from_snapshot(
     def snapshot_search(q_codes):
         return search(jax.device_put(q_codes, qspec), *ins)
 
+    snapshot_search.placed = tuple(ins)
     return snapshot_search

@@ -24,7 +24,7 @@ from repro.core.binarize_lib import (
 from repro.kernels.binary_dot.ops import binary_dot_search
 from repro.kernels.sdc import ref as sdc_ref
 from repro.kernels.sdc.defaults import BLOCK_N, FLAT_BLOCK_Q, BlockPlan, plan_for
-from repro.kernels.sdc.ops import resolve_backend, sdc_search_backend
+from repro.kernels.sdc.ops import sdc_search_backend
 from repro.kernels.sdc.rerank import fine_inv_norms, sdc_rerank_backend
 
 
@@ -51,14 +51,13 @@ class FlatSDC:
     codes: jax.Array  # [N, m] int8; nibble-packed uint8 [N, m//2] if packed
     inv_norm: jax.Array  # [N] f32
     n_levels: int
-    interpret: bool = True  # legacy flag: Pallas interpreter on CPU
     packed: bool = False  # int4 code streaming (2 dims/byte in HBM)
-    backend: str | None = None  # overrides `interpret` when set
+    backend: str = "auto"  # ops.resolve_backend: Pallas on TPU, jnp elsewhere
 
     @staticmethod
     def build(
-        codes: jax.Array, n_levels: int, interpret: bool = True,
-        packed: bool = False, backend: str | None = None,
+        codes: jax.Array, n_levels: int, packed: bool = False,
+        backend: str = "auto",
     ) -> "FlatSDC":
         inv = sdc_ref.doc_inv_norms(codes, n_levels)
         if packed:
@@ -68,7 +67,7 @@ class FlatSDC:
                 )
             codes = pack_codes_nibbles(codes)
         return FlatSDC(codes=codes, inv_norm=inv, n_levels=n_levels,
-                       interpret=interpret, packed=packed, backend=backend)
+                       packed=packed, backend=backend)
 
     @property
     def code_dim(self) -> int:
@@ -79,14 +78,13 @@ class FlatSDC:
         self, q_codes: jax.Array, k: int, block_n: int = BLOCK_N,
         block_q: int = FLAT_BLOCK_Q, block_plan: BlockPlan | None = None,
     ):
-        backend = self.backend or ("interpret" if self.interpret else "pallas")
         return sdc_search_backend(
             q_codes,
             self.codes,
             self.inv_norm,
             n_levels=self.n_levels,
             k=k,
-            backend=resolve_backend(backend),
+            backend=self.backend,
             block_q=block_q,
             block_n=block_n,
             packed=self.packed,
@@ -122,7 +120,7 @@ class BiGranularFlat:
     n_levels: int
     coarse_levels: int
     k_coarse: int
-    backend: str = "xla"
+    backend: str = "auto"
 
     @staticmethod
     def build(
@@ -132,7 +130,7 @@ class BiGranularFlat:
         coarse_levels: int,
         k_coarse: int,
         packed: bool = False,
-        backend: str = "xla",
+        backend: str = "auto",
     ) -> "BiGranularFlat":
         host = isinstance(codes, np.ndarray)
         c_src = jnp.asarray(np.asarray(codes)) if host else codes
@@ -181,7 +179,7 @@ def flat_search_from_snapshot(
     *,
     k: int,
     packed: bool = False,
-    backend: str = "xla",
+    backend: str = "auto",
     block_n: int = BLOCK_N,
     rerank: dict | None = None,
     effort=None,

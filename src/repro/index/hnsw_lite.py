@@ -539,7 +539,7 @@ def hnsw_search_from_snapshot(
     max_hops: int = 64,
     seed: int = 0,
     packed: bool = False,
-    backend: str = "xla",
+    backend: str = "auto",
     effort=None,
     rerank: dict | None = None,
     block_plan=None,

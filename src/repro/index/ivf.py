@@ -32,7 +32,7 @@ from repro.core.binarize_lib import (
 )
 from repro.index.kmeans import kmeans
 from repro.kernels.sdc import ref as sdc_ref
-from repro.kernels.sdc.defaults import plan_for
+from repro.kernels.sdc.defaults import GATHER_CHUNK, plan_for
 from repro.kernels.sdc.gather import sdc_gather_topk, sdc_gather_topk_xla
 from repro.kernels.sdc.ops import resolve_backend
 
@@ -105,6 +105,10 @@ def build_ivf(
     if max_len is None:
         max_len = int(counts.max())
     max_len = max(1, int(np.ceil(max_len * headroom)))
+    if max_len > GATHER_CHUNK:
+        # Whole 128-lane tiles, so the gather kernel scores the list in
+        # bounded chunks (kernels/sdc/gather.list_chunk).
+        max_len = -(-max_len // 128) * 128
     D = codes.shape[1]
 
     dropped = int(np.maximum(counts - max_len, 0).sum())
@@ -120,16 +124,17 @@ def build_ivf(
     ln = np.zeros((nlist, max_len), np.float32)
     li = -np.ones((nlist, max_len), np.int32)
     inv = np.asarray(sdc_ref.doc_inv_norms(codes, n_levels))
-    codes_np = np.asarray(codes)
-    fill = np.zeros(nlist, np.int64)
-    for i in range(n):
-        c = assign[i]
-        p = fill[c]
-        if p < max_len:
-            lc[c, p] = codes_np[i]
-            ln[c, p] = inv[i]
-            li[c, p] = i
-            fill[c] += 1
+    # Each list holds its members in ascending doc id (a stable sort by
+    # list), truncated at max_len.
+    order = np.argsort(assign, kind="stable")
+    lists = assign[order]
+    slot = np.arange(n) - np.searchsorted(lists, lists)
+    keep = slot < max_len
+    order, lists, slot = order[keep], lists[keep], slot[keep]
+    lc[lists, slot] = np.asarray(codes)[order]
+    ln[lists, slot] = inv[order]
+    li[lists, slot] = order
+    fill = np.minimum(counts, max_len)
 
     lists_codes = jnp.asarray(lc)
     if packed:
@@ -163,10 +168,11 @@ def ivf_search(
     k: int,
     n_levels: int,
     coarse_sdc: bool = False,
-    backend: str = "xla",
+    backend: str = "auto",
     packed: bool = False,
 ) -> Tuple[jax.Array, jax.Array]:
     """Search [Q] queries; returns (scores [Q, k], doc ids [Q, k])."""
+    backend = resolve_backend(backend)
     vq = codes_to_values(q_codes, n_levels)  # [Q, D]
 
     # --- coarse layer ---
@@ -285,7 +291,7 @@ def ivf_search_budget(
     k: int,
     n_levels: int,
     coarse_sdc: bool = False,
-    backend: str = "xla",
+    backend: str = "auto",
     packed: bool = False,
 ) -> Tuple[jax.Array, jax.Array]:
     """Budgeted probe search: per-centroid coarse-rank thresholds.
@@ -297,6 +303,7 @@ def ivf_search_budget(
     gather kernel's candidate mask, exactly like HNSW's visited-set
     exclusion, so their lists never enter the running top-k.
     """
+    backend = resolve_backend(backend)
     vq = codes_to_values(q_codes, n_levels)
     if coarse_sdc:
         cv = codes_to_values(index_centroid_codes, n_levels)
@@ -379,7 +386,7 @@ def ivf_search_from_snapshot(
     max_len: int | None = None,
     headroom: float = 1.0,
     packed: bool = False,
-    backend: str = "xla",
+    backend: str = "auto",
     coarse_sdc: bool = False,
     effort=None,
     rerank: dict | None = None,

@@ -21,6 +21,20 @@ def _pairwise_sqdist(x: jax.Array, c: jax.Array) -> jax.Array:
     return x2 + c2[None, :] - 2.0 * (x @ c.T)
 
 
+def _nearest(x: jax.Array, c: jax.Array, chunk: int = 65536) -> jax.Array:
+    """[N] index of each row's nearest centroid, over row chunks, so the
+    [N, K] distance matrix never exists whole (a 2M x 1024 corpus would
+    need 8 GiB for it)."""
+    n, d = x.shape
+    if n <= chunk:
+        return jnp.argmin(_pairwise_sqdist(x, c), axis=-1)
+    xp = jnp.pad(x, ((0, (-n) % chunk), (0, 0))).reshape(-1, chunk, d)
+    nearest = jax.lax.map(
+        lambda xb: jnp.argmin(_pairwise_sqdist(xb, c), axis=-1), xp
+    )
+    return nearest.reshape(-1)[:n]
+
+
 def kmeans_pp_init(key: jax.Array, x: jax.Array, k: int) -> jax.Array:
     """k-means++ seeding (sequential, scan over k picks)."""
     n = x.shape[0]
@@ -56,7 +70,7 @@ def kmeans(
         cents = x[idx]
 
     def step(cents, _):
-        assign = jnp.argmin(_pairwise_sqdist(x, cents), axis=-1)  # [N]
+        assign = _nearest(x, cents)  # [N]
         sums = jax.ops.segment_sum(x, assign, num_segments=k)
         counts = jax.ops.segment_sum(
             jnp.ones((x.shape[0],), x.dtype), assign, num_segments=k
@@ -67,5 +81,5 @@ def kmeans(
         return new, None
 
     cents, _ = jax.lax.scan(step, cents, None, length=iters)
-    assign = jnp.argmin(_pairwise_sqdist(x, cents), axis=-1)
+    assign = _nearest(x, cents)
     return cents, assign

@@ -15,13 +15,14 @@ NamedTuple of scalars; ``kind`` selects which knobs apply:
   * ``scan``   — ``block_q``/``block_n`` are the tile shapes of the
     fused scan+top-k kernel (``ops.sdc_search``).
   * ``gather`` — the gather-then-scan kernel's geometry is fixed by the
-    index layout (one probed list per grid step, the list length is the
-    tile); a plan records provenance but pins the defaults.
+    index layout (one probed list per grid step against a
+    ``GATHER_BLOCK_Q``-row query tile); a plan records provenance but
+    pins the defaults.
   * ``rerank`` — ``block_n`` is the candidate *group* size of the
     host-gather rerank path (``rerank.sdc_rerank_gathered``): survivor
     rows are regrouped into lists of ``block_n`` entries so the gather
     substrate runs ``k'/block_n`` steps per query instead of ``k'``.
-    ``block_q`` is recorded but inert (the gather kernel scores one
+    ``block_q`` is recorded but inert (the gather kernel folds one
     query row per step).
 
 Roofline constants for the hillclimb cost model (``launch/hillclimb.py``)
@@ -64,6 +65,16 @@ BLOCK_N = 512
 # query tile is one f32 sublane.
 FLAT_BLOCK_Q = 8
 
+# Gather kernel query tile: one f32 sublane group. Each (query, probe)
+# grid step scores its list against the whole tile (the MXU pass costs
+# the same for 1 row or 8) and folds only its own row.
+GATHER_BLOCK_Q = 8
+
+# Gather kernel list chunk: a probed list is scored GATHER_CHUNK slots at
+# a time, bounding VMEM temporaries for long IVF lists (build_ivf pads
+# lists longer than this to whole 128-lane tiles).
+GATHER_CHUNK = 1024
+
 # Host-gather rerank: one survivor row per gather step (the pre-plan
 # behavior; grouping is strictly a tuned upgrade).
 RERANK_GROUP = 1
@@ -104,9 +115,11 @@ def plan_for(block_plan, kind: str) -> BlockPlan | None:
     return plan
 
 
-# Roofline constants (single TPU v5e-class core) for the hillclimb cost
-# model. launch/hillclimb.py used to carry its own copies.
-PEAK_FLOPS = 197e12  # int8 MXU peak, ops/s
+# Roofline constants of one TPU v5e chip (JAX device_kind "TPU v5 lite"),
+# from Google Cloud's "TPU v5e" documentation: 393 TOP/s int8 and
+# 197 TFLOP/s bf16 on the MXU, 819 GB/s of HBM. The SDC scan is an int8
+# matmul, so the hillclimb cost model prices it off the int8 peak.
+PEAK_INT8_OPS = 393e12  # ops/s
 HBM_BW = 819e9  # bytes/s
 LINK_BW = 50e9  # bytes/s per ICI link
 N_LINKS = 4

@@ -11,8 +11,9 @@ Mechanics: the probe table [Q, nprobe] is a scalar-prefetch argument
 (``pltpu.PrefetchScalarGridSpec``) so the BlockSpec index maps can DMA
 list ``probes[q, p]`` directly from the [nlist, L, ...] list arrays —
 a data-dependent gather performed by the DMA engine, not by a giant
-XLA gather. The output blocks for all ``p`` map to the same (q, 0) slot,
-giving the same VMEM-resident running-top-k pattern as ``sdc_topk``.
+XLA gather. The output blocks of every step of one query tile
+(``GATHER_BLOCK_Q`` rows) map to the same slot, giving the same
+VMEM-resident running-top-k pattern as ``sdc_topk``.
 
 Supports the nibble-packed int4 list layout (``packed=True``) with the
 same bit-identical guarantee as the flat kernels: scores come from the
@@ -42,39 +43,41 @@ from repro.core.binarize_lib import (
     sdc_affine_epilogue,
     unpack_nibble_planes,
 )
+from repro.kernels.sdc.defaults import GATHER_BLOCK_Q, GATHER_CHUNK
 from repro.kernels.sdc.sdc import (
+    KEY_EMPTY,
+    LANES,
     _check_code_dim,
-    _merge_running_topk,
     _split_queries,
     _tile_scores,
     _tile_scores_packed,
+    any_above_kth,
+    lane_pad,
+    select_topk,
 )
 
 
-def _pad_cols(x: jax.Array, k: int, fill):
-    """Right-pad [1, L] to [1, max(L, k)] so lax.top_k(_, k) is legal."""
-    L = x.shape[1]
-    if k <= L:
-        return x
-    return jnp.concatenate(
-        [x, jnp.full((1, k - L), fill, dtype=x.dtype)], axis=1
+def list_chunk(L: int) -> int:
+    """Slots of one probed list scored per in-kernel step.
+
+    A list is streamed into VMEM whole, but scored in chunks of at most
+    GATHER_CHUNK lane-aligned slots so the temporaries (unpacked nibble
+    planes, the score tile) stay bounded whatever the list length. A
+    list whose length is not a multiple of 128 is scored in one piece.
+    """
+    if L <= GATHER_CHUNK or L % LANES:
+        return L
+    tiles = L // LANES
+    return LANES * max(
+        d for d in range(1, GATHER_CHUNK // LANES + 1) if tiles % d == 0
     )
 
 
-def _gather_topk_step(
-    scores, ids, vals_ref, out_ids_ref, *, p, k: int, mask=None
-):
-    """Common tail of a (query, probe) step: mask pads, fold into top-k."""
-    # List padding carries ids == -1 (and inv == 0, already NEG_INF).
-    scores = jnp.where(ids[None, :] >= 0, scores, SDC_NEG_INF)
-    if mask is not None:
-        # Caller-supplied per-slot exclusion (e.g. HNSW visited bitmap).
-        scores = jnp.where(mask[None, :] > 0, scores, SDC_NEG_INF)
-    scores = _pad_cols(scores, k, SDC_NEG_INF)
-    tile_vals, tile_arg = jax.lax.top_k(scores, k)  # [1, k]
-    padded_ids = _pad_cols(ids[None, :], k, -1)
-    tile_ids = jnp.take_along_axis(padded_ids, tile_arg, axis=1)
-    _merge_running_topk(vals_ref, out_ids_ref, tile_vals, tile_ids, j=p, k=k)
+def _pad_rows(x: jax.Array, rows: int) -> jax.Array:
+    pad = rows - x.shape[0]
+    if pad == 0:
+        return x
+    return jnp.pad(x, [(0, pad)] + [(0, 0)] * (x.ndim - 1))
 
 
 @functools.partial(
@@ -108,82 +111,147 @@ def sdc_gather_topk(
 
     Returns:
       (scores [Q, k], doc ids [Q, k]); empty slots are (SDC_NEG_INF, -1).
+      Ties keep the earlier candidate in (probe, slot) order, the order
+      in which the jnp twin flattens them.
+
+    Grid step (q, p) scores list ``probes[q, p]`` against the
+    GATHER_BLOCK_Q-row query tile holding q (one MXU pass costs the same
+    for 1 row or 8) and folds only row q into the tile's running top-k,
+    which stays resident in VMEM while q walks through its tile.
     """
     Q, D = q_codes.shape
     nlist, L = lists_ids.shape
     nprobe = probes.shape[1]
     Dc = lists_codes.shape[-1]
     _check_code_dim(lists_codes, D, packed)
-    probes = jnp.clip(probes.astype(jnp.int32), 0, nlist - 1)
+    tq = GATHER_BLOCK_Q
+    Qp = -(-Q // tq) * tq
+    kp = lane_pad(k)
+    probes = _pad_rows(jnp.clip(probes.astype(jnp.int32), 0, nlist - 1), Qp)
+    q_codes = _pad_rows(q_codes, Qp)
     has_mask = cand_mask is not None
 
     if packed:
         qe, qo = _split_queries(q_codes)
         q_args = (qe, qo)
         q_specs = [
-            pl.BlockSpec((1, D // 2), lambda q, p, pr: (q, 0)),
-            pl.BlockSpec((1, D // 2), lambda q, p, pr: (q, 0)),
+            pl.BlockSpec((tq, D // 2), lambda q, p, pr: (q // tq, 0)),
+            pl.BlockSpec((tq, D // 2), lambda q, p, pr: (q // tq, 0)),
         ]
     else:
         q_args = (q_codes,)
-        q_specs = [pl.BlockSpec((1, D), lambda q, p, pr: (q, 0))]
+        q_specs = [pl.BlockSpec((tq, D), lambda q, p, pr: (q // tq, 0))]
 
     mask_args = ()
     mask_specs = []
     if has_mask:
-        mask_args = (cand_mask.astype(jnp.float32),)
-        mask_specs = [pl.BlockSpec((1, 1, L), lambda q, p, pr: (q, p, 0))]
+        # [nprobe, Qp, L]: a (1, tq, L) block holds the mask rows of the
+        # whole query tile at probe p, a legal (8, 128)-rule block.
+        mask = _pad_rows(cand_mask.astype(jnp.float32), Qp)
+        mask_args = (jnp.transpose(mask, (1, 0, 2)),)
+        mask_specs = [pl.BlockSpec((1, tq, L), lambda q, p, pr: (p, q // tq, 0))]
+
+    chunk = list_chunk(L)
 
     def kernel(probes_ref, *refs):
         del probes_ref  # consumed by the BlockSpec index maps
+        q = pl.program_id(0)
         p = pl.program_id(1)
         if packed:
             qe_ref, qo_ref, codes_ref, inv_ref, ids_ref, *rest = refs
-            scores = _tile_scores_packed(
-                qe_ref[...], qo_ref[...], codes_ref[0], inv_ref[0],
-                n_levels=n_levels, dim=D,
-            )  # [1, L]
         else:
             q_ref, codes_ref, inv_ref, ids_ref, *rest = refs
-            scores = _tile_scores(
-                q_ref[...], codes_ref[0], inv_ref[0], n_levels=n_levels, dim=D
-            )
         if has_mask:
-            mask_ref, vals_ref, ids_out = rest
-            mask = mask_ref[0, 0]
+            mask_ref, vals_ref, ids_out, keys_ref = rest
         else:
-            vals_ref, ids_out = rest
-            mask = None
-        _gather_topk_step(
-            scores, ids_ref[0], vals_ref, ids_out, p=p, k=k, mask=mask
-        )
+            vals_ref, ids_out, keys_ref = rest
 
+        @pl.when((q % tq == 0) & (p == 0))
+        def _init():
+            vals_ref[...] = jnp.full(vals_ref.shape, -jnp.inf, jnp.float32)
+            ids_out[...] = jnp.full(ids_out.shape, KEY_EMPTY, jnp.int32)
+            keys_ref[...] = jnp.full(keys_ref.shape, KEY_EMPTY, jnp.int32)
+
+        def fold(start, size):
+            """Score list slots [start, start + size) and fold them in."""
+            rows = pl.ds(start, size)
+            inv = inv_ref[0, :, rows]
+            if packed:
+                scores = _tile_scores_packed(
+                    qe_ref[...], qo_ref[...], codes_ref[0, rows, :], inv,
+                    n_levels=n_levels, dim=D,
+                )  # [tq, size]
+            else:
+                scores = _tile_scores(
+                    q_ref[...], codes_ref[0, rows, :], inv,
+                    n_levels=n_levels, dim=D,
+                )
+            ids = jnp.broadcast_to(ids_ref[0, :, rows], scores.shape)
+            # List padding carries ids == -1 (and inv == 0, already NEG_INF).
+            scores = jnp.where(ids >= 0, scores, SDC_NEG_INF)
+            if has_mask:
+                # Caller-supplied per-slot exclusion (HNSW visited bitmap).
+                scores = jnp.where(mask_ref[0, :, rows] > 0, scores,
+                                   SDC_NEG_INF)
+            # Only row q of the query tile probes this list.
+            row = jax.lax.broadcasted_iota(jnp.int32, scores.shape, 0)
+            mine = row == q % tq
+            scores = jnp.where(mine, scores, -jnp.inf)
+            slot = jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1)
+            keys = jnp.where(mine, p * L + start + slot, KEY_EMPTY)
+
+            @pl.when(any_above_kth(vals_ref[...], scores, k))
+            def _merge():
+                v, key, ident = select_topk(
+                    [(vals_ref[...], keys_ref[...], ids_out[...]),
+                     (scores, keys, ids)], k,
+                )
+                vals_ref[...] = v
+                keys_ref[...] = key
+                ids_out[...] = ident
+
+        if chunk == L:
+            fold(0, L)
+        else:
+            def body(c, carry):
+                fold(pl.multiple_of(c * chunk, chunk), chunk)
+                return carry
+
+            jax.lax.fori_loop(0, L // chunk, body, 0)
+
+    meta = lambda x: x.reshape(nlist, 1, L)  # noqa: E731  (1, 1, L) blocks
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(Q, nprobe),
+        grid=(Qp, nprobe),
         in_specs=[
             *q_specs,
             pl.BlockSpec((1, L, Dc), lambda q, p, pr: (pr[q, p], 0, 0)),
-            pl.BlockSpec((1, L), lambda q, p, pr: (pr[q, p], 0)),
-            pl.BlockSpec((1, L), lambda q, p, pr: (pr[q, p], 0)),
+            pl.BlockSpec((1, 1, L), lambda q, p, pr: (pr[q, p], 0, 0)),
+            pl.BlockSpec((1, 1, L), lambda q, p, pr: (pr[q, p], 0, 0)),
             *mask_specs,
         ],
         out_specs=[
-            pl.BlockSpec((1, k), lambda q, p, pr: (q, 0)),
-            pl.BlockSpec((1, k), lambda q, p, pr: (q, 0)),
+            pl.BlockSpec((tq, kp), lambda q, p, pr: (q // tq, 0)),
+            pl.BlockSpec((tq, kp), lambda q, p, pr: (q // tq, 0)),
+            pl.BlockSpec((tq, kp), lambda q, p, pr: (q // tq, 0)),
         ],
     )
-    return pl.pallas_call(
+    vals, ids, _ = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((Q, k), jnp.float32),
-            jax.ShapeDtypeStruct((Q, k), jnp.int32),
+            jax.ShapeDtypeStruct((Qp, kp), jnp.float32),
+            jax.ShapeDtypeStruct((Qp, kp), jnp.int32),
+            jax.ShapeDtypeStruct((Qp, kp), jnp.int32),
         ],
         interpret=interpret,
     )(
-        probes, *q_args, lists_codes, lists_inv_norm, lists_ids, *mask_args
+        probes, *q_args, lists_codes, meta(lists_inv_norm), meta(lists_ids),
+        *mask_args,
     )
+    vals, ids = vals[:Q, :k], ids[:Q, :k]
+    live = vals > SDC_NEG_INF / 2
+    return jnp.where(live, vals, SDC_NEG_INF), jnp.where(live, ids, -1)
 
 
 @functools.partial(jax.jit, static_argnames=("n_levels", "k", "packed"))
@@ -217,7 +285,6 @@ def sdc_gather_topk_xla(
     cq = q_codes.astype(jnp.int32)
     if packed:
         lo, hi = unpack_nibble_planes(cand_codes)
-        lo, hi = lo.astype(jnp.int32), hi.astype(jnp.int32)
         dot = jnp.einsum("qd,qpld->qpl", cq[:, 0::2], lo) + jnp.einsum(
             "qd,qpld->qpl", cq[:, 1::2], hi
         )

@@ -30,13 +30,15 @@ from repro.kernels.sdc.sdc import sdc_scores, sdc_topk
 
 NEG_INF = SDC_NEG_INF
 
+SDC_BACKENDS = ("auto", "pallas", "interpret", "xla")
+
 
 def resolve_backend(backend: str = "auto") -> str:
     """Resolve the scoring backend flag to a concrete implementation."""
+    if backend not in SDC_BACKENDS:
+        raise ValueError(f"unknown SDC backend {backend!r}")
     if backend == "auto":
         return "pallas" if jax.default_backend() == "tpu" else "xla"
-    if backend not in ("pallas", "interpret", "xla"):
-        raise ValueError(f"unknown SDC backend {backend!r}")
     return backend
 
 
@@ -151,7 +153,6 @@ def sdc_search_xla(
     cq = q_codes.astype(jnp.int32)
     if packed:
         lo, hi = unpack_nibble_planes(d_codes)
-        lo, hi = lo.astype(jnp.int32), hi.astype(jnp.int32)
         dot = cq[:, 0::2] @ lo.T + cq[:, 1::2] @ hi.T
         sd = (jnp.sum(lo, -1) + jnp.sum(hi, -1))[None, :]
     else:

@@ -8,12 +8,13 @@ exactly those rows of the full corpus through the shared
 ``sdc_affine_epilogue`` and return the true top-k.
 
 Both implementations reuse the gather-then-scan substrate
-(``kernels/sdc/gather``) by viewing the fine corpus as N inverted lists
-of length 1 and the survivor ids as the probe table — the same
-scalar-prefetched DMA gather that serves the IVF fine layer streams each
-survivor's code row through VMEM, and the jnp twin mirrors it for CPU
-meshes. Because every path folds the identical integer partial sums
-through the one shared epilogue, a rerank is **bit-identical to a
+(``kernels/sdc/gather``): the survivor rows are gathered into one
+candidate list per query (k' rows, ascending id) and the identity probe
+table points each query at its own list — the kernel that serves the
+IVF fine layer scores it in one grid step per query, and the jnp twin
+mirrors it for CPU meshes. Because every path folds the identical
+integer partial sums through the one shared epilogue, a rerank is
+**bit-identical to a
 full-level flat scan restricted to the same candidate ids** (including
 top-k tie-breaking: candidates are presented in ascending-id order, the
 column order of a flat scan).
@@ -74,6 +75,22 @@ def _sort_candidates(cand_ids: jax.Array) -> jax.Array:
     return jnp.where(key == _INT32_MAX, -1, key)
 
 
+def _gathered_lists(fine_codes, fine_inv_norm, cand):
+    """Survivor rows as one candidate list per query.
+
+    ``cand`` is the sorted [Q, k'] table from ``_sort_candidates``.
+    Returns (codes [Q, k', D(/2)], inv [Q, k'], ids [Q, k'], probes
+    [Q, 1]): query q probes list q, whose slots are its survivors in
+    ascending-id order; empty slots carry inv 0 and id -1, so every
+    scoring path excludes them.
+    """
+    N = fine_codes.shape[0]
+    safe = jnp.clip(cand, 0, N - 1)
+    inv = jnp.where(cand >= 0, fine_inv_norm[safe], 0.0)
+    probes = jnp.arange(cand.shape[0], dtype=jnp.int32)[:, None]
+    return fine_codes[safe], inv, cand, probes
+
+
 @functools.partial(
     jax.jit, static_argnames=("n_levels", "k", "interpret", "packed")
 )
@@ -103,22 +120,14 @@ def sdc_rerank(
       (SDC_NEG_INF, -1) — the k' < k degenerate case pads, never reads
       out of range.
 
-    The fine corpus is presented to the gather kernel as N lists of
-    length 1 with the (sorted) survivors as the probe table, so the DMA
-    engine fetches exactly k' code rows per query from HBM. Invalid
-    slots must ride ``cand_mask`` (the kernel clamps probes into range,
-    so id masking alone cannot exclude them).
+    The survivor rows are gathered on device (Q * k' rows, never the
+    corpus) into one list per query, which the gather kernel scores in
+    a single grid step per query.
     """
-    N = fine_codes.shape[0]
-    cand = _sort_candidates(cand_ids)
-    lists_codes = fine_codes.reshape(N, 1, fine_codes.shape[-1])
-    lists_inv = fine_inv_norm.reshape(N, 1)
-    lists_ids = jnp.arange(N, dtype=jnp.int32).reshape(N, 1)
-    mask = (cand >= 0).astype(jnp.float32)[..., None]  # [Q, k', 1]
+    lists = _gathered_lists(fine_codes, fine_inv_norm, _sort_candidates(cand_ids))
     return sdc_gather_topk(
-        q_codes, lists_codes, lists_inv, lists_ids, cand,
-        n_levels=n_levels, k=k, interpret=interpret, packed=packed,
-        cand_mask=mask,
+        q_codes, *lists, n_levels=n_levels, k=k, interpret=interpret,
+        packed=packed,
     )
 
 
@@ -138,15 +147,9 @@ def sdc_rerank_xla(
     Same contract, same scores: identical integer partial sums through
     the shared epilogue, identical ascending-id candidate order.
     """
-    N = fine_codes.shape[0]
-    cand = _sort_candidates(cand_ids)
-    lists_codes = fine_codes.reshape(N, 1, fine_codes.shape[-1])
-    lists_inv = fine_inv_norm.reshape(N, 1)
-    lists_ids = jnp.arange(N, dtype=jnp.int32).reshape(N, 1)
-    mask = (cand >= 0).astype(jnp.float32)[..., None]
+    lists = _gathered_lists(fine_codes, fine_inv_norm, _sort_candidates(cand_ids))
     return sdc_gather_topk_xla(
-        q_codes, lists_codes, lists_inv, lists_ids, cand,
-        n_levels=n_levels, k=k, packed=packed, cand_mask=mask,
+        q_codes, *lists, n_levels=n_levels, k=k, packed=packed,
     )
 
 
@@ -160,7 +163,7 @@ def sdc_rerank_gathered(
     k: int,
     packed: bool = False,
     group: int = RERANK_GROUP,
-    backend: str = "xla",
+    backend: str = "auto",
 ):
     """Cold-tier rerank: host-gather the survivor rows, score on device.
 

@@ -13,6 +13,8 @@ Layout/tiling:
     along N, queries tiled along Q; the code dim D stays whole (D <= 2048
     in all BEBR deployments => a (512, D) int8 tile is <= 1 MiB of VMEM).
   * MXU tiles want multiples of (128, 128); defaults TQ=128, TN=512.
+  * reciprocal norms travel as a [1, N] row so their (1, TN) blocks obey
+    the (8, 128) block rule and broadcast over the (TQ, TN) score tile.
   * int32 accumulation is exact — unlike the paper's saturating int8/16
     adds, the TPU path introduces zero quantisation error.
   * documents with a zero reciprocal norm are "excluded" (padding, drained
@@ -23,12 +25,19 @@ int4 packed code streaming (``packed=True``):
     nibble-packed (2 dims/byte; byte j = dim 2j | dim 2j+1 << 4, see
     ``binarize_lib.pack_codes_nibbles``), halving HBM traffic per scanned
     document — the scan is memory-bound, so this is ~2x effective speedup.
-  * in-kernel unpack is shift+mask on the VPU; queries (tiny) stay
+  * in-kernel unpack is shift+mask on the VPU in int32; queries (tiny) stay
     unpacked and are pre-split into even/odd dim halves so the scan is two
     half-width int8 MXU matmuls (same MAC count as one full-width one):
         c_q . c_d = q_even . lo(d_packed) + q_odd . hi(d_packed).
   * integer partial sums are identical to the int8 path, so packed scores
     are bit-identical to unpacked scores.
+
+Top-k inside a kernel (``select_topk``): Mosaic has no sort or top_k, so
+the running top-k is k rounds of "row max, lowest key among the maxima,
+mask it out" over the running set and the fresh tile. A tile whose every
+score is at or below the running k-th value cannot change the result
+(ties go to the lower key, and the running set always holds the lower
+keys), so such tiles skip the rounds entirely.
 
 Backend selection lives one level up (``ops.resolve_backend``): "pallas"
 (compiled kernel, real TPU), "interpret" (this kernel under the Pallas
@@ -50,6 +59,16 @@ from repro.core.binarize_lib import (
 )
 from repro.kernels.sdc.defaults import BLOCK_N, BLOCK_Q
 
+# Key of an empty running slot: larger than any real key, so a real
+# candidate always wins a tie against it.
+KEY_EMPTY = 2**31 - 1
+LANES = 128
+
+
+def lane_pad(k: int) -> int:
+    """Running top-k width: k rounded up to whole 128-lane vregs."""
+    return -(-k // LANES) * LANES
+
 
 def _check_code_dim(d_codes, D: int, packed: bool) -> None:
     want = D // 2 if packed else D
@@ -69,12 +88,6 @@ def _check_block_tiling(Q: int, N: int, block_q: int, block_n: int) -> None:
         )
 
 
-def _unpack_nibbles_tile(p: jax.Array):
-    """uint8 tile [TN, D//2] -> (lo, hi) int8 tiles holding even/odd dims."""
-    lo, hi = unpack_nibble_planes(p)
-    return lo.astype(jnp.int8), hi.astype(jnp.int8)
-
-
 def _int8_dot(x: jax.Array, y: jax.Array) -> jax.Array:
     """[TQ, D] x [TN, D] -> [TQ, TN] int32 (MXU int8 path, exact)."""
     return jax.lax.dot_general(
@@ -85,18 +98,27 @@ def _int8_dot(x: jax.Array, y: jax.Array) -> jax.Array:
     )
 
 
+def _epilogue(dot, sq, sd, inv, *, n_levels: int, dim: int) -> jax.Array:
+    """Affine epilogue + exclusion of inv == 0 documents.
+
+    sq: [TQ, 1] query code sums; sd: [TN, 1] document code sums;
+    inv: [1, TN] reciprocal norms.
+    """
+    scores = sdc_affine_epilogue(
+        dot, sq + sd.T, dim=dim, n_levels=n_levels, inv_norm=inv
+    )
+    return jnp.where(inv > 0, scores, SDC_NEG_INF)
+
+
 def _tile_scores(q, d, inv, *, n_levels: int, dim: int) -> jax.Array:
     """SDC scores for one (TQ, TN) tile of unpacked int8 codes.
 
-    Excluded documents (inv == 0) come out as SDC_NEG_INF.
+    inv is the [1, TN] row of reciprocal norms; excluded documents
+    (inv == 0) come out as SDC_NEG_INF.
     """
-    dot = _int8_dot(q, d)
-    sq = jnp.sum(q.astype(jnp.int32), axis=-1, keepdims=True)  # [TQ, 1]
-    sd = jnp.sum(d.astype(jnp.int32), axis=-1, keepdims=True).T  # [1, TN]
-    scores = sdc_affine_epilogue(
-        dot, sq + sd, dim=dim, n_levels=n_levels, inv_norm=inv[None, :]
-    )
-    return jnp.where(inv[None, :] > 0, scores, SDC_NEG_INF)
+    sq = jnp.sum(q.astype(jnp.int32), axis=-1, keepdims=True)
+    sd = jnp.sum(d.astype(jnp.int32), axis=-1, keepdims=True)
+    return _epilogue(_int8_dot(q, d), sq, sd, inv, n_levels=n_levels, dim=dim)
 
 
 def _tile_scores_packed(qe, qo, p, inv, *, n_levels: int, dim: int) -> jax.Array:
@@ -107,19 +129,13 @@ def _tile_scores_packed(qe, qo, p, inv, *, n_levels: int, dim: int) -> jax.Array
     The integer partial sums equal the unpacked ones exactly, so scores are
     bit-identical to the int8 path.
     """
-    lo, hi = _unpack_nibbles_tile(p)
-    dot = _int8_dot(qe, lo) + _int8_dot(qo, hi)
+    lo, hi = unpack_nibble_planes(p)  # int32 planes in [0, 16)
+    dot = _int8_dot(qe, lo.astype(jnp.int8)) + _int8_dot(qo, hi.astype(jnp.int8))
     sq = jnp.sum(qe.astype(jnp.int32), -1, keepdims=True) + jnp.sum(
         qo.astype(jnp.int32), -1, keepdims=True
     )
-    sd = (
-        jnp.sum(lo.astype(jnp.int32), -1, keepdims=True)
-        + jnp.sum(hi.astype(jnp.int32), -1, keepdims=True)
-    ).T
-    scores = sdc_affine_epilogue(
-        dot, sq + sd, dim=dim, n_levels=n_levels, inv_norm=inv[None, :]
-    )
-    return jnp.where(inv[None, :] > 0, scores, SDC_NEG_INF)
+    sd = jnp.sum(lo, -1, keepdims=True) + jnp.sum(hi, -1, keepdims=True)
+    return _epilogue(dot, sq, sd, inv, n_levels=n_levels, dim=dim)
 
 
 def _sdc_kernel(q_ref, d_ref, dnorm_ref, out_ref, *, n_levels: int, dim: int):
@@ -142,6 +158,14 @@ def _sdc_kernel_packed(
 def _split_queries(q_codes: jax.Array):
     """[Q, D] int8 -> even/odd dim halves matching the nibble layout."""
     return q_codes[:, 0::2], q_codes[:, 1::2]
+
+
+def _doc_specs(block_n: int, Dc: int):
+    """BlockSpecs of the document codes and their [1, N] norm row."""
+    return [
+        pl.BlockSpec((block_n, Dc), lambda i, j: (j, 0)),
+        pl.BlockSpec((1, block_n), lambda i, j: (0, j)),
+    ]
 
 
 @functools.partial(
@@ -171,13 +195,10 @@ def sdc_scores(
     _check_block_tiling(Q, N, block_q, block_n)
 
     grid = (Q // block_q, N // block_n)
-    Dc = d_codes.shape[1]
     out_spec = pl.BlockSpec((block_q, block_n), lambda i, j: (i, j))
     out_shape = jax.ShapeDtypeStruct((Q, N), jnp.float32)
-    d_specs = [
-        pl.BlockSpec((block_n, Dc), lambda i, j: (j, 0)),
-        pl.BlockSpec((block_n,), lambda i, j: (j,)),
-    ]
+    d_specs = _doc_specs(block_n, d_codes.shape[1])
+    inv_row = d_inv_norm.reshape(1, N)
     if packed:
         qe, qo = _split_queries(q_codes)
         return pl.pallas_call(
@@ -191,7 +212,7 @@ def sdc_scores(
             out_specs=out_spec,
             out_shape=out_shape,
             interpret=interpret,
-        )(qe, qo, d_codes, d_inv_norm)
+        )(qe, qo, d_codes, inv_row)
     return pl.pallas_call(
         functools.partial(_sdc_kernel, n_levels=n_levels, dim=D),
         grid=grid,
@@ -199,43 +220,103 @@ def sdc_scores(
         out_specs=out_spec,
         out_shape=out_shape,
         interpret=interpret,
-    )(q_codes, d_codes, d_inv_norm)
+    )(q_codes, d_codes, inv_row)
 
 
-def _merge_running_topk(vals_ref, idx_ref, tile_vals, tile_idx, *, j, k):
-    """Streaming top-k accumulator shared by the fused scan kernels.
+def select_topk(parts, k: int):
+    """Top-k by (value descending, key ascending) over several arrays.
 
-    Out blocks map to the same (i, 0) slot for every inner grid step, so
-    they persist in VMEM across the reduction. The running entries are
-    concatenated first so ties keep the earliest (lowest-index) document,
-    matching a stable top-k over the full score row.
+    ``parts`` is a sequence of ``(values, keys, ids)`` triples of equal
+    row count; ``ids`` may be None, in which case the key is the id.
+    Runs k rounds of row max -> lowest key among the maxima -> mask it
+    out, all with ops Mosaic lowers. Keys must be unique within a row
+    among the entries that can win (empty slots carry ``KEY_EMPTY`` and
+    -inf). Returns (values, keys, ids) as [rows, lane_pad(k)] arrays
+    whose first k lanes are sorted; ids is None when the parts had none.
+    """
+    rows = parts[0][0].shape[0]
+    kp = lane_pad(k)
+    with_ids = parts[0][2] is not None
+    lane = jax.lax.broadcasted_iota(jnp.int32, (rows, kp), 1)
+    keys = [p[1] for p in parts]
+    ids = [p[2] for p in parts]
+
+    def row_max(xs):
+        return functools.reduce(
+            jnp.maximum, [jnp.max(x, axis=1, keepdims=True) for x in xs]
+        )
+
+    def row_min(xs):
+        return functools.reduce(
+            jnp.minimum, [jnp.min(x, axis=1, keepdims=True) for x in xs]
+        )
+
+    def body(r, carry):
+        vals, out_v, out_k, out_i = carry
+        m = row_max(vals)
+        hit = [v == m for v in vals]
+        key = row_min([jnp.where(h, kk, KEY_EMPTY) for h, kk in zip(hit, keys)])
+        sel = [h & (kk == key) for h, kk in zip(hit, keys)]
+        vals = tuple(jnp.where(s, -jnp.inf, v) for s, v in zip(sel, vals))
+        out_v = jnp.where(lane == r, m, out_v)
+        out_k = jnp.where(lane == r, key, out_k)
+        if with_ids:
+            ident = row_min([jnp.where(s, i, KEY_EMPTY) for s, i in zip(sel, ids)])
+            out_i = jnp.where(lane == r, ident, out_i)
+        return vals, out_v, out_k, out_i
+
+    init = (
+        tuple(p[0] for p in parts),
+        jnp.full((rows, kp), -jnp.inf, jnp.float32),
+        jnp.full((rows, kp), KEY_EMPTY, jnp.int32),
+        jnp.full((rows, kp), KEY_EMPTY, jnp.int32),
+    )
+    _, out_v, out_k, out_i = jax.lax.fori_loop(0, k, body, init)
+    return out_v, out_k, (out_i if with_ids else None)
+
+
+def any_above_kth(run_vals: jax.Array, cand_vals: jax.Array, k: int):
+    """Scalar: does any candidate beat its row's running k-th value?"""
+    lane = jax.lax.broadcasted_iota(jnp.int32, run_vals.shape, 1)
+    kth = jnp.min(jnp.where(lane < k, run_vals, jnp.inf), axis=1, keepdims=True)
+    above = jnp.where(cand_vals > kth, 1.0, 0.0)
+    return jnp.max(jnp.max(above, axis=1, keepdims=True), axis=0, keepdims=True)[0, 0] > 0
+
+
+def _fold_tile(vals_ref, idx_ref, scores, *, j, k: int, block_n: int):
+    """Fold one (TQ, TN) score tile into the running top-k out blocks.
+
+    The out blocks map to the same (i, 0) slot for every inner grid step,
+    so they persist in VMEM across the reduction. Keys are global doc
+    indices, so ties keep the lowest index, matching a stable top-k over
+    the full score row.
     """
 
     @pl.when(j == 0)
     def _init():
-        vals_ref[...] = tile_vals
-        idx_ref[...] = tile_idx
+        vals_ref[...] = jnp.full(vals_ref.shape, -jnp.inf, jnp.float32)
+        idx_ref[...] = jnp.full(idx_ref.shape, KEY_EMPTY, jnp.int32)
 
-    @pl.when(j > 0)
+    keys = j * block_n + jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1)
+
+    @pl.when(any_above_kth(vals_ref[...], scores, k))
     def _merge():
-        cat_v = jnp.concatenate([vals_ref[...], tile_vals], axis=-1)
-        cat_i = jnp.concatenate([idx_ref[...], tile_idx], axis=-1)
-        best_v, best_a = jax.lax.top_k(cat_v, k)
-        vals_ref[...] = best_v
-        idx_ref[...] = jnp.take_along_axis(cat_i, best_a, axis=-1)
+        v, key, _ = select_topk(
+            [(vals_ref[...], idx_ref[...], None), (scores, keys, None)], k
+        )
+        vals_ref[...] = v
+        idx_ref[...] = key
 
 
 def _sdc_topk_kernel(
     q_ref, d_ref, dnorm_ref, vals_ref, idx_ref, *, n_levels, dim, k, block_n
 ):
-    """Fused scan + per-tile top-k (streaming reduction over the N grid)."""
-    j = pl.program_id(1)
+    """Fused scan + running top-k (streaming reduction over the N grid)."""
     scores = _tile_scores(
         q_ref[...], d_ref[...], dnorm_ref[...], n_levels=n_levels, dim=dim
     )
-    tile_vals, tile_arg = jax.lax.top_k(scores, k)  # [TQ, k]
-    tile_idx = (j * block_n + tile_arg).astype(jnp.int32)
-    _merge_running_topk(vals_ref, idx_ref, tile_vals, tile_idx, j=j, k=k)
+    _fold_tile(vals_ref, idx_ref, scores, j=pl.program_id(1), k=k,
+               block_n=block_n)
 
 
 def _sdc_topk_kernel_packed(
@@ -243,14 +324,12 @@ def _sdc_topk_kernel_packed(
     *, n_levels, dim, k, block_n,
 ):
     """Packed-int4 variant of the fused scan+top-k kernel."""
-    j = pl.program_id(1)
     scores = _tile_scores_packed(
         qe_ref[...], qo_ref[...], d_ref[...], dnorm_ref[...],
         n_levels=n_levels, dim=dim,
     )
-    tile_vals, tile_arg = jax.lax.top_k(scores, k)
-    tile_idx = (j * block_n + tile_arg).astype(jnp.int32)
-    _merge_running_topk(vals_ref, idx_ref, tile_vals, tile_idx, j=j, k=k)
+    _fold_tile(vals_ref, idx_ref, scores, j=pl.program_id(1), k=k,
+               block_n=block_n)
 
 
 @functools.partial(
@@ -284,27 +363,23 @@ def sdc_topk(
             "(ops.sdc_search widens the effective block for large k)"
         )
     grid = (Q // block_q, N // block_n)
-    Dc = d_codes.shape[1]
     _check_code_dim(d_codes, D, packed)
-    d_specs = [
-        pl.BlockSpec((block_n, Dc), lambda i, j: (j, 0)),
-        pl.BlockSpec((block_n,), lambda i, j: (j,)),
-    ]
+    d_specs = _doc_specs(block_n, d_codes.shape[1])
+    kp = lane_pad(k)
     out_specs = [
-        pl.BlockSpec((block_q, k), lambda i, j: (i, 0)),
-        pl.BlockSpec((block_q, k), lambda i, j: (i, 0)),
+        pl.BlockSpec((block_q, kp), lambda i, j: (i, 0)),
+        pl.BlockSpec((block_q, kp), lambda i, j: (i, 0)),
     ]
     out_shape = [
-        jax.ShapeDtypeStruct((Q, k), jnp.float32),
-        jax.ShapeDtypeStruct((Q, k), jnp.int32),
+        jax.ShapeDtypeStruct((Q, kp), jnp.float32),
+        jax.ShapeDtypeStruct((Q, kp), jnp.int32),
     ]
+    inv_row = d_inv_norm.reshape(1, N)
+    kw = dict(n_levels=n_levels, dim=D, k=k, block_n=block_n)
     if packed:
         qe, qo = _split_queries(q_codes)
-        return pl.pallas_call(
-            functools.partial(
-                _sdc_topk_kernel_packed, n_levels=n_levels, dim=D, k=k,
-                block_n=block_n,
-            ),
+        vals, idx = pl.pallas_call(
+            functools.partial(_sdc_topk_kernel_packed, **kw),
             grid=grid,
             in_specs=[
                 pl.BlockSpec((block_q, D // 2), lambda i, j: (i, 0)),
@@ -314,14 +389,14 @@ def sdc_topk(
             out_specs=out_specs,
             out_shape=out_shape,
             interpret=interpret,
-        )(qe, qo, d_codes, d_inv_norm)
-    return pl.pallas_call(
-        functools.partial(
-            _sdc_topk_kernel, n_levels=n_levels, dim=D, k=k, block_n=block_n
-        ),
-        grid=grid,
-        in_specs=[pl.BlockSpec((block_q, D), lambda i, j: (i, 0)), *d_specs],
-        out_specs=out_specs,
-        out_shape=out_shape,
-        interpret=interpret,
-    )(q_codes, d_codes, d_inv_norm)
+        )(qe, qo, d_codes, inv_row)
+    else:
+        vals, idx = pl.pallas_call(
+            functools.partial(_sdc_topk_kernel, **kw),
+            grid=grid,
+            in_specs=[pl.BlockSpec((block_q, D), lambda i, j: (i, 0)), *d_specs],
+            out_specs=out_specs,
+            out_shape=out_shape,
+            interpret=interpret,
+        )(q_codes, d_codes, inv_row)
+    return vals[:, :k], idx[:, :k]

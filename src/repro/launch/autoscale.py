@@ -51,6 +51,7 @@ import json
 import threading
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
+from repro.kernels.sdc.ops import SDC_BACKENDS
 from repro.launch import serving
 from repro.launch.clock import SYSTEM_CLOCK, Clock
 from repro.launch.lifecycle import (
@@ -92,13 +93,17 @@ class TierSpec:
     consecutive scaling actions; the sample window resets after every
     action. ``swap_every_s`` is the declared index-swap cadence (0 =
     no periodic swap) — consumed by the serve drivers, recorded here so
-    the whole tier shape lives in one artifact.
+    the whole tier shape lives in one artifact. ``backend`` is the SDC
+    scoring backend every replica's index is built with ("auto" is the
+    Pallas kernel on a TPU, the jnp twin elsewhere); it is a field of its
+    own, never a build param, so no scale-up can drift off it.
     """
 
     min_replicas: int = 1
     max_replicas: int = 1
     index: str = "flat"
     build_params: Dict[str, Any] = dataclasses.field(default_factory=dict)
+    backend: str = "auto"
     router: str = "round-robin"
     policy: str = "shed"
     queue_depth: int = 4
@@ -152,6 +157,12 @@ class TierSpec:
         _require(isinstance(self.build_params, dict),
                  f"build_params must be a dict, got "
                  f"{type(self.build_params).__name__}")
+        _require("backend" not in self.build_params,
+                 "set the scoring backend with the spec's own 'backend' "
+                 "key, not in build_params")
+        _require(self.backend in SDC_BACKENDS,
+                 f"backend must be one of {SDC_BACKENDS}, got "
+                 f"{self.backend!r}")
         # The registry is the source of truth for index kinds and their
         # knobs — a typo'd build param must die at spec load, not after
         # the tier has been serving for an hour and tries to scale up.
@@ -161,8 +172,10 @@ class TierSpec:
             raise InvalidTierSpec(f"index/build_params rejected: {e}") from e
 
     def make_index_builder(self) -> IndexBuilder:
-        """A fresh ``IndexBuilder`` for this spec's index kind/params."""
-        return make_builder(self.index, **self.build_params)
+        """A fresh ``IndexBuilder`` for this spec's index kind/params,
+        scoring on the spec's ``backend``."""
+        return make_builder(self.index, backend=self.backend,
+                            **self.build_params)
 
     @property
     def window_ticks(self) -> int:

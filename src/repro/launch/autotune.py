@@ -28,7 +28,9 @@ Two signatures are never swept:
 
 The sweep always times the default plan alongside the candidates and
 keeps it unless a candidate is strictly faster, so a tuned plan can
-only tie or beat the table on the shapes it was tuned for.
+only tie or beat the table on the shapes it was tuned for. A default
+plan that fails to compile raises (it is what serves untuned); a
+refused non-default candidate is skipped and counted (``skipped``).
 """
 
 from __future__ import annotations
@@ -72,6 +74,7 @@ class TunedPlan(NamedTuple):
     digest: str
     path: str | None
     tuned: bool
+    skipped: int = 0  # non-default candidates the compiler refused
 
 
 def plan_digest(
@@ -246,19 +249,24 @@ def tuned_block_plan(
         sample_q=sample_q, n_levels=n_levels,
     )
     best_blocks, best_t, default_t = base.blocks(), None, None
+    skipped = 0
     for blocks in candidate_grid(
         kind, code_dim=code_dim, n_shard=n_shard, packed=packed, k=k
     ):
-        try:
-            fn = _candidate_timer(
-                kind, blocks, operands, n_levels=n_levels, k=k,
-                packed=packed, backend=resolved,
-            )
-            t = _time_call(fn, reps=reps)
-        except Exception:
-            continue  # illegal candidate for these shapes: skip, never fatal
+        fn = _candidate_timer(
+            kind, blocks, operands, n_levels=n_levels, k=k,
+            packed=packed, backend=resolved,
+        )
         if blocks == base.blocks():
-            default_t = t
+            # The default plan is what serves untuned: if it does not
+            # compile, serving cannot either, so that failure is fatal.
+            t = default_t = _time_call(fn, reps=reps)
+        else:
+            try:
+                t = _time_call(fn, reps=reps)
+            except Exception:
+                skipped += 1  # a candidate the compiler refused
+                continue
         # Strict improvement required to displace the default plan.
         if best_t is None or t < best_t:
             best_blocks, best_t = blocks, t
@@ -275,5 +283,6 @@ def tuned_block_plan(
         "default_blocks": list(base.blocks()),
         "default_ms": None if default_t is None else default_t * 1e3,
         "tuned_ms": None if best_t is None else best_t * 1e3,
+        "skipped": skipped,
     })
-    return TunedPlan(plan, digest, path, True)
+    return TunedPlan(plan, digest, path, True, skipped)

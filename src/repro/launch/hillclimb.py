@@ -29,7 +29,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 # Roofline constants shared with the block-plan autotuner: one table
 # (kernels/sdc/defaults.py) prices kernels for both the cost model here
 # and the launch-shape sweeps.
-from repro.kernels.sdc.defaults import HBM_BW, LINK_BW, N_LINKS, PEAK_FLOPS
+from repro.kernels.sdc.defaults import HBM_BW, LINK_BW, N_LINKS, PEAK_INT8_OPS
 
 
 def _measure(fn, in_shardings, args, mesh, n_dev):
@@ -48,7 +48,7 @@ def _measure(fn, in_shardings, args, mesh, n_dev):
         "bytes": costs["bytes"],
         "wire_bytes": wire,
         "collectives": costs["collectives"],
-        "compute_ms": 1e3 * costs["flops"] / PEAK_FLOPS,
+        "compute_ms": 1e3 * costs["flops"] / PEAK_INT8_OPS,
         "memory_ms": 1e3 * costs["bytes"] / HBM_BW,
         "collective_ms": 1e3 * wire / (N_LINKS * LINK_BW),
         "peak_gib": (ma.argument_size_in_bytes + ma.output_size_in_bytes
@@ -165,7 +165,7 @@ def tt_retrieval_bebr_full(mesh):
 def tt_retrieval_bebr_merge(mesh, code_dim=64, n_levels=4):
     """BEBR + the paper's selection merge: per-leaf top-k under shard_map,
     all-gather only k results (wire: scores array -> k entries/leaf)."""
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     from repro.core.binarize_lib import code_affine_constants
     from repro.configs.registry import get_arch
@@ -204,7 +204,7 @@ def tt_retrieval_bebr_merge(mesh, code_dim=64, n_levels=4):
     leaf_sharded = shard_map(
         leaf, mesh=mesh,
         in_specs=(P(None, None), P(dp, None), P(dp)),
-        out_specs=(P(), P()), check_rep=False)
+        out_specs=(P(), P()), check_vma=False)
 
     def step(params, batch):
         q = tt.query_embed(params, batch["hist_ids"], batch["hist_mask"], cfg)
@@ -312,7 +312,7 @@ def gnn_ogb_partitioned(mesh, gather_dtype=None):
     [2.45M, 128] array per layer; here: 1 all-gather (+ its reduce-scatter
     transpose in backward).
     """
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     import repro.models.gnn as gnn_lib
     from repro.configs import cells as cells_mod
@@ -390,7 +390,7 @@ def gnn_ogb_partitioned(mesh, gather_dtype=None):
         sharded_grads, mesh=mesh,
         in_specs=(P(), P(axes, None), P(axes, None), P(axes), P(axes),
                   P(axes), P(axes, None)),
-        out_specs=(P(), P()), check_rep=False)
+        out_specs=(P(), P()), check_vma=False)
 
     from repro.configs.cells import ADAM as _ADAM
 
@@ -418,7 +418,7 @@ def gnn_ogb_halo(mesh, slack: float = 2.0):
     slack * E_loc / n_shards (uniform senders => Poisson tails; slack=2
     bounds overflow far beyond 6 sigma at these sizes).
     """
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     import repro.models.gnn as gnn_lib
     from repro.configs import cells as cells_mod
@@ -529,7 +529,7 @@ def gnn_ogb_halo(mesh, slack: float = 2.0):
         sharded_grads, mesh=mesh,
         in_specs=(P(), P(axes, None), P(axes, None), P(axes), P(axes),
                   P(axes), P(axes, None)),
-        out_specs=(P(), P()), check_rep=False)
+        out_specs=(P(), P()), check_vma=False)
 
     def step(params, opt_state, batch):
         loss, grads = gfn(params, batch["node_feat"], batch["edge_feat"],
@@ -551,7 +551,7 @@ def gnn_ogb_halo_hostplan(mesh, slack: float = 2.0):
     indices as inputs, so the in-graph work is just the two all-to-alls
     plus gathers — no sorting/scattering on the accelerator.
     """
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     import repro.models.gnn as gnn_lib
     from repro.configs import cells as cells_mod
@@ -650,7 +650,7 @@ def gnn_ogb_halo_hostplan(mesh, slack: float = 2.0):
         sharded_grads, mesh=mesh,
         in_specs=(P(), P(axes, None), P(axes, None), P(axes), P(axes),
                   P(axes, None), P(axes, None), P(axes), P(axes)),
-        out_specs=(P(), P()), check_rep=False)
+        out_specs=(P(), P()), check_vma=False)
 
     def step(params, opt_state, batch):
         fvalid = batch["fetch_valid"].astype(jnp.float32)

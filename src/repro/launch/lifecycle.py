@@ -252,7 +252,7 @@ class FlatBuilder(_SnapshotCachingBuilder):
     kind = "flat"
 
     def __init__(self, *, k: int = 10, packed: bool = False,
-                 backend: str = "xla", block_n: int = 512,
+                 backend: str = "auto", block_n: int = 512,
                  coarse_levels: int = None, k_coarse: int = None,
                  block_plan=None):
         super().__init__()
@@ -283,7 +283,7 @@ class IVFBuilder(_SnapshotCachingBuilder):
 
     def __init__(self, *, k: int = 10, nlist: int = 64, nprobe: int = 32,
                  seed: int = 0, kmeans_iters: int = 20,
-                 packed: bool = False, backend: str = "xla",
+                 packed: bool = False, backend: str = "auto",
                  coarse_levels: int = None, k_coarse: int = None,
                  probe_budget: int = None, block_plan=None):
         super().__init__()
@@ -313,7 +313,7 @@ class HNSWBuilder(_SnapshotCachingBuilder):
     def __init__(self, *, k: int = 10, M: int = 16,
                  ef_construction: int = 64, ef: int = 64, beam: int = 8,
                  max_hops: int = 64, seed: int = 0, packed: bool = False,
-                 backend: str = "xla",
+                 backend: str = "auto",
                  coarse_levels: int = None, k_coarse: int = None,
                  block_plan=None):
         super().__init__()

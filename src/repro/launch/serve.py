@@ -42,11 +42,13 @@ from repro.kernels.sdc import ref as sdc_ref
 from repro.launch import (
     autoscale,
     binarizer_cache,
+    compile_cache,
     faults,
     lifecycle,
     proxy,
     serving,
 )
+from repro.kernels.sdc.ops import SDC_BACKENDS
 
 
 def train_binarizer(docs: np.ndarray, cfg: TrainConfig, steps: int = 300,
@@ -136,8 +138,7 @@ def main():
     ap.add_argument("--packed", action="store_true",
                     help="int4 nibble-packed code storage (2 dims/byte; "
                          "halves scan bandwidth, bit-identical scores)")
-    ap.add_argument("--backend", default="auto",
-                    choices=["auto", "pallas", "interpret", "xla"],
+    ap.add_argument("--backend", default="auto", choices=SDC_BACKENDS,
                     help="SDC scoring backend (auto: Pallas kernel on TPU, "
                          "jnp fallback elsewhere)")
     ap.add_argument("--autotune", action="store_true",
@@ -185,7 +186,7 @@ def main():
                          "build params, router policy, admission policy/"
                          "queue depth, swap cadence, and scale thresholds. "
                          "Overrides --replicas/--router/--queue-depth/"
-                         "--policy/--index, starts the tier at "
+                         "--policy/--index/--backend, starts the tier at "
                          "min_replicas, and runs the shed-pressure "
                          "autoscaler over the stream (scale-up replicas "
                          "are built from the spec's index params, warmed, "
@@ -246,6 +247,12 @@ def main():
     if args.probe_budget and args.index != "ivf":
         ap.error("--probe-budget only applies to --index ivf")
 
+    cache_dir, warm = compile_cache.enable_compile_cache()
+    dev = jax.devices()[0]
+    print(f"[device] platform={dev.platform} kind={dev.device_kind} "
+          f"count={len(jax.devices())}; compile cache {cache_dir} "
+          f"({'warm' if warm else 'cold'})")
+
     # Declarative tier spec: ONE artifact describes the tier's desired
     # state; the flags it covers are overridden so an operator cannot
     # half-apply it. The autoscaler re-applies the same spec as it
@@ -262,7 +269,9 @@ def main():
         args.router = spec.router
         args.queue_depth = spec.queue_depth
         args.policy = spec.policy
+        args.backend = spec.backend
         print(f"[tier-spec] {args.tier_spec}: index={spec.index} "
+              f"backend={spec.backend} "
               f"replicas=[{spec.min_replicas}, {spec.max_replicas}] "
               f"router={spec.router} policy={spec.policy} "
               f"water=({spec.low_water}, {spec.high_water}) "
@@ -583,7 +592,7 @@ def main():
               f"({sum(r is None for r in first)}/{len(first)} first-round "
               "batches missed their deadline)")
     print(f"[serve] sequential: {1e3 * dt_seq / (len(batches) * args.rounds):.1f} "
-          f"ms/batch ({n_q / dt_seq:.0f} QPS single-host CPU, warmed)")
+          f"ms/batch ({n_q / dt_seq:.0f} QPS, warmed)")
     n_q_routed = sum(
         getattr(b, "n_queries", None) or b.shape[0] for b in stream
     )

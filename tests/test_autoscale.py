@@ -158,6 +158,14 @@ def test_tier_spec_defaults_validate_and_round_trip():
     assert spec.window_ticks == 3  # 3.0s window / 1.0s tick
 
 
+def test_tier_spec_backend_reaches_every_builder():
+    # "auto" resolves to the Pallas kernel on a TPU; a spec never falls
+    # back to a builder default of its own.
+    assert TierSpec().make_index_builder().params["backend"] == "auto"
+    spec = TierSpec(index="ivf", backend="xla", build_params={"nlist": 8})
+    assert spec.make_index_builder().params["backend"] == "xla"
+
+
 def test_tier_spec_window_ticks_rounds_and_floors_at_one():
     assert TierSpec(window_s=0.1, tick_s=0.05).window_ticks == 2
     assert TierSpec(window_s=1.0, tick_s=1.0).window_ticks == 1
@@ -181,6 +189,8 @@ def test_tier_spec_window_ticks_rounds_and_floors_at_one():
     dict(build_params=[("k", 5)]),           # not a dict
     dict(index="pq"),                        # unknown index kind
     dict(index="flat", build_params={"nlist": 8}),  # flat has no nlist
+    dict(backend="tpu"),                     # not an SDC backend
+    dict(build_params={"backend": "xla"}),   # backend is the spec's own key
 ])
 def test_tier_spec_rejects_malformed_fields_with_typed_error(bad):
     with pytest.raises(InvalidTierSpec):

@@ -150,6 +150,42 @@ def test_sweep_payload_records_paired_timings(tmp_path):
     assert payload["default_blocks"] == list(default_plan("scan").blocks())
 
 
+def _refusing(refused):
+    """A candidate timer whose launch raises for the ``refused`` blocks,
+    as the chip's compiler does for an illegal tile."""
+    real = autotune._candidate_timer
+
+    def timer(kind, blocks, *args, **kw):
+        if blocks in refused:
+            def launch():
+                raise RuntimeError(f"compiler refused {blocks}")
+            return launch
+        return real(kind, blocks, *args, **kw)
+
+    return timer
+
+
+def test_refused_candidates_are_skipped_and_counted(tmp_path, monkeypatch):
+    grid = autotune.candidate_grid("scan", code_dim=M, n_shard=N,
+                                   packed=False, k=4)
+    refused = set(grid[1:3])
+    monkeypatch.setattr(autotune, "_candidate_timer", _refusing(refused))
+    tp = _tune(cache_dir=str(tmp_path))
+    assert tp.tuned and tp.skipped == len(refused)
+    assert tp.plan.blocks() not in refused
+    with open(tp.path) as f:
+        assert json.load(f)["skipped"] == len(refused)
+
+
+def test_default_plan_compile_failure_raises(tmp_path, monkeypatch):
+    # The default plan is what serves untuned: a sweep must not hide its
+    # failure behind a "tuned" plan nothing ever timed.
+    monkeypatch.setattr(autotune, "_candidate_timer",
+                        _refusing({default_plan("scan").blocks()}))
+    with pytest.raises(RuntimeError, match="compiler refused"):
+        _tune(cache_dir=str(tmp_path))
+
+
 def test_candidate_grid_leads_with_the_default():
     for kind in ("scan", "rerank", "gather"):
         grid = autotune.candidate_grid(kind, code_dim=M, n_shard=N,
@@ -173,7 +209,7 @@ def test_any_plan_is_bit_identical_through_the_scan(tmp_path):
 
 def test_plan_is_bit_identical_through_flat_index():
     cd, cq = _codes(seed=3)
-    index = FlatSDC.build(cd, n_levels=LEVELS)
+    index = FlatSDC.build(cd, n_levels=LEVELS, backend="interpret")
     ref_s, ref_i = index.search(cq, 4)
     s, i = index.search(cq, 4,
                         block_plan=BlockPlan("scan", 8, 128, "tuned"))

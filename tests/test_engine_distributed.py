@@ -80,7 +80,7 @@ def test_sharded_lm_train_step_runs():
 def test_compressed_psum_inside_shard_map():
     stdout = _run("""
         import jax, jax.numpy as jnp, numpy as np
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
         from repro.train import compression as comp
 
@@ -93,7 +93,7 @@ def test_compressed_psum_inside_shard_map():
             return mean["g"], new_e["g"]
 
         f = shard_map(sync, mesh=mesh, in_specs=(P("data"), P("data")),
-                      out_specs=(P(), P("data")), check_rep=False)
+                      out_specs=(P(), P("data")), check_vma=False)
         with mesh:
             mean, new_e = f(grads, err)
         true_mean = jnp.mean(grads, axis=0)

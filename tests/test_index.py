@@ -37,6 +37,19 @@ def test_kmeans_reduces_quantisation_error():
     assert int(a25.max()) < 16
 
 
+def test_kmeans_chunked_assignment_equals_whole():
+    """Row-chunked nearest-centroid search (a ragged last chunk included)
+    equals the argmin over the whole distance matrix."""
+    from repro.index.kmeans import _nearest, _pairwise_sqdist
+
+    key = jax.random.PRNGKey(2)
+    x = jax.random.normal(key, (100, 8))
+    c = jax.random.normal(jax.random.fold_in(key, 1), (16, 8))
+    want = jnp.argmin(_pairwise_sqdist(x, c), axis=-1)
+    np.testing.assert_array_equal(np.asarray(_nearest(x, c, chunk=32)),
+                                  np.asarray(want))
+
+
 def test_ivf_exact_when_probing_all_lists():
     d_codes, q_codes, _ = _codes_from_corpus()
     index = ivf_lib.build_ivf(jax.random.PRNGKey(1), d_codes, n_levels=4,
@@ -66,7 +79,7 @@ def test_ivf_partial_probe_recall_reasonable():
 
 def test_flat_sdc_equals_flat_bitwise_ranking():
     d_codes, q_codes, _ = _codes_from_corpus(n=500, q=8)
-    sdc = FlatSDC.build(d_codes, 4)
+    sdc = FlatSDC.build(d_codes, 4, backend="interpret")
     bitw = FlatBitwise.build(d_codes, 4)
     _, ids_s = sdc.search(q_codes, 5)
     _, ids_b = bitw.search(q_codes, 5)
@@ -85,7 +98,7 @@ def test_index_bytes_compression_vs_float():
     cfg = BinarizerConfig(input_dim=256, code_dim=128, n_levels=4, hidden_dim=0)
     p, s = init_binarizer(jax.random.PRNGKey(0), cfg)
     bits, _, _ = binarize(p, s, jnp.asarray(docs), cfg)
-    sdc = FlatSDC.build(pack_codes(bits), 4)
+    sdc = FlatSDC.build(pack_codes(bits), 4, backend="interpret")
     # 256 f32 dims = 8192 bits -> 512 bits + norm: ~14x smaller
     assert sdc.nbytes() < f.nbytes() / 10
 

@@ -12,6 +12,7 @@ import pytest
 
 from repro.core.binarize_lib import (
     SDC_NEG_INF,
+    codes_to_values,
     pack_codes_nibbles,
     unpack_codes_nibbles,
 )
@@ -178,6 +179,27 @@ def test_gather_topk_matches_oracle(packed):
                                       cand[np.asarray(ea)])
 
 
+@pytest.mark.parametrize("packed", [False, True])
+def test_gather_topk_long_lists_score_in_chunks(packed):
+    """Lists longer than GATHER_CHUNK are scored chunk by chunk inside
+    the kernel; the result must equal the jnp twin's exactly."""
+    from repro.kernels.sdc.defaults import GATHER_CHUNK
+    from repro.kernels.sdc.gather import list_chunk, sdc_gather_topk_xla
+
+    nlist, L, D, k = 3, GATHER_CHUNK + 128, 32, 12
+    assert list_chunk(L) < L  # 9 lane tiles -> chunks of 3
+    codes, _, inv, ids = _lists(29, nlist, L, D, n_pad=40)
+    q = jax.random.randint(jax.random.PRNGKey(3), (3, D), 0, 16).astype(jnp.int8)
+    probes = jnp.asarray([[0, 2], [1, 0], [2, 1]], jnp.int32)
+    lists_arg = pack_codes_nibbles(codes) if packed else codes
+    got = sdc_gather_topk(q, lists_arg, inv, ids, probes, n_levels=4, k=k,
+                          interpret=True, packed=packed)
+    want = sdc_gather_topk_xla(q, lists_arg, inv, ids, probes, n_levels=4,
+                               k=k, packed=packed)
+    np.testing.assert_array_equal(np.asarray(got[1]), np.asarray(want[1]))
+    np.testing.assert_array_equal(np.asarray(got[0]), np.asarray(want[0]))
+
+
 def test_gather_topk_k_exceeds_list_len():
     nlist, L, D = 4, 8, 32
     codes, flat, inv, ids = _lists(23, nlist, L, D, n_pad=2)
@@ -240,3 +262,29 @@ def test_build_ivf_overflow_warns_and_headroom_prevents():
                                    nlist=4, max_len=30, headroom=20.0)
         assert not [x for x in w if "dropped" in str(x.message)]
     assert int(jnp.sum(index2.lists_ids >= 0)) == 400
+
+
+@pytest.mark.parametrize("max_len", [None, 30])
+def test_build_ivf_lists_match_loop_reference(max_len):
+    """The vectorised list fill equals the one-doc-at-a-time loop: each
+    list holds its members in ascending doc id, truncated at max_len."""
+    codes = jax.random.randint(jax.random.PRNGKey(0), (400, 32), 0,
+                               16).astype(jnp.int8)
+    with warnings.catch_warnings():
+        warnings.simplefilter("ignore")
+        index = ivf_lib.build_ivf(jax.random.PRNGKey(1), codes, n_levels=4,
+                                  nlist=4, max_len=max_len)
+    _, assign = ivf_lib.kmeans(jax.random.PRNGKey(1),
+                               codes_to_values(codes, 4), k=4, iters=20)
+    ids = np.asarray(index.lists_ids)
+    want = -np.ones_like(ids)
+    fill = np.zeros(4, np.int64)
+    for i, c in enumerate(np.asarray(assign)):
+        if fill[c] < ids.shape[1]:
+            want[c, fill[c]] = i
+            fill[c] += 1
+    np.testing.assert_array_equal(ids, want)
+    np.testing.assert_array_equal(index.list_occupancy, fill)
+    live = ids >= 0
+    np.testing.assert_array_equal(np.asarray(index.lists_codes)[live],
+                                  np.asarray(codes)[ids[live]])

@@ -74,7 +74,7 @@ def test_bebr_end_to_end_recall():
     state, cfg = _train_binarizer(docs)
     d_codes = _encode(state, cfg, docs)
     q_codes = _encode(state, cfg, queries)
-    index = FlatSDC.build(d_codes, LEVELS)
+    index = FlatSDC.build(d_codes, LEVELS, backend="interpret")
     _, idx_b = index.search(q_codes, 10)
     r_ours = _recall_at(idx_b, gt, 10)
 
@@ -82,7 +82,7 @@ def test_bebr_end_to_end_recall():
     state1, cfg1 = _train_binarizer(docs, n_levels=1, seed=3)
     d1 = _encode(state1, cfg1, docs)
     q1 = _encode(state1, cfg1, queries)
-    index1 = FlatSDC.build(d1, 1)
+    index1 = FlatSDC.build(d1, 1, backend="interpret")
     _, idx_h = index1.search(q1, 10)
     r_hash = _recall_at(idx_h, gt, 10)
 
