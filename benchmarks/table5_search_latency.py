@@ -212,8 +212,8 @@ def _autotune_rows(n_docs: int, queries: int, levels: int, m: int,
     every challenger — so ``tuned_ms <= default_ms`` holds by
     construction (the tuner keeps the default unless a candidate is
     strictly faster), and the gated ratio cannot flake on host noise.
-    Un-sweepable kinds (gather: corpus-fixed geometry) emit the default
-    plan with a ratio of exactly 1.0 and no timings.
+    Un-sweepable kinds (gather, rerank: layout-fixed geometry) emit the
+    default plan with a ratio of exactly 1.0 and no timings.
 
     The sweep persists its winner in the tune cache (``cache_dir`` /
     ``$REPRO_BEBR_CACHE``): a re-run of the bench is a cache hit and
@@ -223,11 +223,10 @@ def _autotune_rows(n_docs: int, queries: int, levels: int, m: int,
     from repro.launch.autotune import tuned_block_plan
 
     kb = "pallas" if jax.default_backend() == "tpu" else "interpret"
-    kp = min(64, n_docs)  # rerank signature: survivors rescored per query
     rows = []
     for kind in ("scan", "gather", "rerank"):
         tp = tuned_block_plan(
-            kind, code_dim=m, n_shard=n_docs, k=(k if kind == "scan" else kp),
+            kind, code_dim=m, n_shard=n_docs, k=k,
             n_levels=levels, backend=kb, cache_dir=cache_dir,
             sample_q=max(1, min(8, queries)),
         )

@@ -25,10 +25,11 @@ in its top 10, beside the exact float search's) to the phase's floor,
 and checks that the compiled serving program holds a Pallas kernel
 (``tpu_custom_call``), so no interpreter or twin stood in.
 
-``--four-chips`` serves 2**23 documents (four one-chip shards) through
-``lifecycle.EngineBuilder`` over a 4-device mesh and through a
-two-replica router over two 2-chip submeshes, and compares both with a
-single-device Pallas scan of the same corpus.
+``--four-chips`` serves 2**23 documents (four one-chip shards), drawn
+from one generator on the device, through ``lifecycle.EngineBuilder``
+over a 4-device mesh and through a two-replica router over two 2-chip
+submeshes, and compares both with a single-device Pallas scan of the
+same corpus, whose answers must reach every shard.
 
 The script exits non-zero, with no ``ok`` line, when JAX finds no TPU,
 when any phase raises, or when any check fails. Its last line is one
@@ -61,17 +62,18 @@ BATCHES = (8, 128)
 TRAIN_STEPS = 300
 
 # Recall@10 (planted positive in the top 10) per phase, at this seed.
-# CPU rehearsals (jnp backend, 300 training steps) measured 0.586 at
-# 65,536 docs and 0.484 at 262,144 docs for every flat/IVF phase, and
-# 0.602 for HNSW at 16,384; on a v5e chip the flat phase measured 0.184
-# at 2**21. A broken path scores ~1e-5 (10 of 2**21 at random), so the
-# floors catch it with room for the binarizer's run-to-run spread.
+# Flat search at 2**21 docs after 300 training steps measured 0.203 on
+# a CPU (jnp backend, f32 throughout), and on a v5e chip 0.184 with the
+# binarizer's matmuls at default precision, 0.188 at HIGHEST. The 128-d
+# codes, not the chip, set that level. HNSW at 16,384 docs measured
+# 0.535 on the chip. A broken path scores ~1e-5 (10 of 2**21 at random);
+# each floor sits below its readings by more than their spread.
 RECALL_FLOORS = {
-    "flat": 0.1,
-    "flat-packed": 0.1,
-    "ivf": 0.1,
-    "bigranular": 0.1,
-    "hnsw": 0.3,
+    "flat": 0.15,
+    "flat-packed": 0.15,
+    "ivf": 0.15,
+    "bigranular": 0.15,
+    "hnsw": 0.45,
 }
 
 
@@ -305,6 +307,64 @@ def one_chip() -> None:
         )
 
 
+def web_corpus_on_device(seed: int, n_docs: int, n_queries: int,
+                         chunk: int = 2**20):
+    """The generative model of ``synthetic.clustered_corpus`` at the web
+    product's widths, drawn on the device in chunks of ``chunk`` rows.
+
+    One set of cluster centres and one anisotropic scale + rotation hold
+    for every row, so a query's same-cluster documents are spread over
+    the whole row range. Returns (emb, queries [n_queries, dim], gt):
+    ``emb(i)`` gives rows [i * chunk, (i + 1) * chunk) as unit vectors on
+    the device, redrawn identically on every call, and queries are noisy
+    views of their planted rows ``gt``, drawn over all ``n_docs`` rows.
+    """
+    from benchmarks.common import PRODUCTS
+
+    web = PRODUCTS["web"]
+    dim = web["dim"]
+    rng = np.random.default_rng(seed)
+    centers = jnp.asarray(rng.normal(size=(web["clusters"], dim)), jnp.float32)
+    scales = jnp.asarray(1.0 / (1.0 + np.arange(dim)) ** web["spectrum"],
+                         jnp.float32)
+    rot = jnp.asarray(np.linalg.qr(rng.normal(size=(dim, dim)))[0], jnp.float32)
+    gt = rng.choice(n_docs, size=n_queries, replace=False)
+    key = jax.random.PRNGKey(seed)
+
+    def unit(raw):
+        x = jnp.dot(raw * scales, rot, precision=jax.lax.Precision.HIGHEST)
+        return x / (jnp.linalg.norm(x, axis=-1, keepdims=True) + 1e-12)
+
+    @jax.jit
+    def raw(i):
+        ka, kn = jax.random.split(jax.random.fold_in(key, i))
+        assign = jax.random.randint(ka, (chunk,), 0, web["clusters"])
+        return centers[assign] + web["noise"] * jax.random.normal(
+            kn, (chunk, dim))
+
+    emb = jax.jit(lambda i: unit(raw(i)))
+    q_raw = np.zeros((n_queries, dim), np.float32)
+    for i in range(n_docs // chunk):
+        mine = gt // chunk == i
+        if mine.any():
+            q_raw[mine] = np.asarray(raw(i)[gt[mine] - i * chunk])
+    q_noise = jax.random.normal(jax.random.fold_in(key, n_docs // chunk),
+                                (n_queries, dim))
+    queries = np.asarray(unit(jnp.asarray(q_raw) + web["qnoise"] * q_noise))
+    return emb, queries, gt
+
+
+def placed_corpus(search, q_codes, n_rows: int):
+    """The device-placed corpus array the serving program closes over: the
+    one [n_rows, ...] constant of its traced program."""
+    consts = [c for c in jax.make_jaxpr(search)(q_codes).consts
+              if isinstance(c, jax.Array) and c.ndim == 2
+              and c.shape[0] == n_rows]
+    check(len(consts) == 1, f"{len(consts)} corpus-sized arrays in the "
+                            f"serving program, want 1")
+    return consts[0]
+
+
 def four_chips() -> None:
     from repro.kernels.sdc import ref as sdc_ref
     from repro.kernels.sdc.ops import sdc_search_backend
@@ -314,18 +374,15 @@ def four_chips() -> None:
     check(len(jax.devices()) == 4, f"--four-chips needs 4 devices, "
                                    f"found {len(jax.devices())}")
     bcfg, tcfg = web_configs()
-    shard = N_DOCS_FOUR_CHIPS // 4
+    n, chunk, shard = N_DOCS_FOUR_CHIPS, 2**20, N_DOCS_FOUR_CHIPS // 4
     t0 = time.perf_counter()
-    docs, queries, _ = web_corpus(SEED, shard, N_QUERIES)
-    encode = train_encoder(docs, tcfg)
-    parts = [encode_corpus(encode, docs)]
-    for i in range(1, 4):
-        more, _, _ = web_corpus(SEED + i, shard, 0)
-        parts.append(encode_corpus(encode, more))
-    codes = jnp.concatenate(parts, 0)
-    del docs, more, parts
-    log(f"[data] {N_DOCS_FOUR_CHIPS} docs (4 web-corpus shards of {shard}) "
-        f"encoded in {time.perf_counter() - t0:.1f} s")
+    emb, queries, gt = web_corpus_on_device(SEED, n, N_QUERIES, chunk)
+    encode = train_encoder(np.asarray(emb(0)), tcfg)
+    codes = jnp.concatenate(
+        [encode_corpus(encode, emb(i)) for i in range(n // chunk)], 0)
+    log(f"[data] {n} web-corpus docs (one generator, drawn on the device) "
+        f"encoded in {time.perf_counter() - t0:.1f} s; planted rows per "
+        f"shard: {np.bincount(gt // shard, minlength=4).tolist()}")
     snapshot = lifecycle.CorpusSnapshot(codes=codes, n_levels=bcfg.n_levels)
     q_codes = encode(queries)
 
@@ -334,8 +391,14 @@ def four_chips() -> None:
     ref = jax.device_get(sdc_search_backend(
         q_codes, codes, inv, n_levels=bcfg.n_levels, k=K, backend="pallas"
     ))
-    log(f"[reference] single-device pallas scan of {N_DOCS_FOUR_CHIPS} docs on "
+    log(f"[reference] single-device pallas scan of {n} docs on "
         f"{codes.devices()}")
+    # Every shard must hold reference answers, or a leaf that returns
+    # nothing (or a wrong global-id offset) would go unseen.
+    per_shard = np.bincount(ref[1].ravel() // shard, minlength=4).tolist()
+    log(f"[reference] top-{K} ids per shard of {shard} rows: {per_shard}")
+    check(len(per_shard) == 4 and min(per_shard) > 0,
+          f"reference top-{K} ids do not reach every shard: {per_shard}")
 
     log("=== phase engine: 4-device mesh ===")
     mesh = make_host_mesh((2, 2))
@@ -343,8 +406,9 @@ def four_chips() -> None:
         [mesh], index="flat", n_levels=bcfg.n_levels, k=K, backend="pallas"
     )
     search = engine.build(snapshot)
-    shard_devices = [str(s.device) for s in search.placed[0].addressable_shards]
-    for s in search.placed[0].addressable_shards:
+    placed = placed_corpus(search, q_codes[:BATCHES[0]], n)
+    shard_devices = [str(s.device) for s in placed.addressable_shards]
+    for s in placed.addressable_shards:
         log(f"[engine] shard rows {s.index[0].start}:{s.index[0].stop} on "
             f"{s.device}")
     check(len(set(shard_devices)) == 4,
@@ -369,8 +433,11 @@ def four_chips() -> None:
     )
     fns = [(encode, replicas.build(snapshot, replica=i)) for i in range(2)]
     for i, (_, fn) in enumerate(fns):
-        devs = sorted({str(s.device) for s in fn.placed[0].addressable_shards})
+        placed = placed_corpus(fn, q_codes[:BATCHES[0]], n)
+        devs = sorted({str(s.device) for s in placed.addressable_shards})
         log(f"[replicas] replica {i} shards on {devs}")
+        check(len(devs) == 2, f"replica {i} shards sit on {devs}, want 2 "
+                              f"devices")
     for b in BATCHES:
         batches = [queries[i:i + b] for i in range(0, N_QUERIES, b)]
         serving.warmup_replicas(fns, batches[:1])

@@ -80,7 +80,7 @@ def main():
                          "$REPRO_BEBR_CACHE, else ~/.cache/repro-bebr)")
     ap.add_argument("--autotune", action="store_true",
                     help="sweep (block_q, block_n) launch shapes for the "
-                         "per-leaf scan (and bi-granular rerank) on the "
+                         "per-leaf scan on the "
                          "live shard sizes before serving; winners "
                          "persist in the tune cache "
                          "($REPRO_BEBR_CACHE), so every replica and "
@@ -187,24 +187,22 @@ def main():
     # per snapshot digest and shared across replicas (same leaf layout).
     snapshot = lifecycle.CorpusSnapshot(codes=np.asarray(d_codes),
                                         n_levels=levels)
-    # Tuned launch shapes for the per-leaf scan (and the post-merge
-    # rerank in bi-granular mode), keyed on the PER-LEAF shard size —
-    # that is the corpus each kernel launch actually sees. Plans never
-    # change scores; the agreement check below holds either way.
+    # Tuned launch shapes for the per-leaf scan, keyed on the PER-LEAF
+    # shard size — that is the corpus each kernel launch actually sees.
+    # Plans never change scores; the agreement check below holds either
+    # way.
     block_plan = None
     if args.autotune:
         from repro.launch import autotune
 
         n_shard = -(-d_codes.shape[0] // per)  # rows per leaf, padded up
-        block_plan = {}
-        for kind in ("scan", "rerank"):
-            tp = autotune.tuned_block_plan(
-                kind, code_dim=code, n_shard=n_shard,
-                k=(args.k_coarse or 10), n_levels=levels,
-            )
-            block_plan[kind] = tp.plan
-            print(f"tune {kind}: block_q={tp.plan.block_q} "
-                  f"block_n={tp.plan.block_n} ({tp.plan.source})")
+        tp = autotune.tuned_block_plan(
+            "scan", code_dim=code, n_shard=n_shard,
+            k=(args.k_coarse or 10), n_levels=levels,
+        )
+        block_plan = {"scan": tp.plan}
+        print(f"tune scan: block_q={tp.plan.block_q} "
+              f"block_n={tp.plan.block_n} ({tp.plan.source})")
     builder = lifecycle.EngineBuilder(
         meshes, index=args.index, n_levels=levels, k=10,
         M=16, ef_construction=48, ef=64, beam=16,

@@ -384,9 +384,9 @@ def engine_search_from_snapshot(
 
     ``block_plan`` — a single ``BlockPlan`` or a ``{kind: plan}``
     mapping (``launch/autotune``) — sets the per-leaf scan tiles
-    (kind "scan" overrides ``block_q``/``block_n``) and, in bi-granular
-    mode, the post-merge rerank group size (kind "rerank"). Plans never
-    change scores, only launch shapes.
+    (kind "scan" overrides ``block_q``/``block_n``); in bi-granular
+    mode they are the coarse scan's tiles. Plans never change scores,
+    only launch shapes.
     """
     from repro.index._snapshot import (
         resolve_rerank_args,
@@ -399,7 +399,6 @@ def engine_search_from_snapshot(
     scan_plan = plan_for(block_plan, "scan")
     if scan_plan is not None:
         block_q, block_n = scan_plan.block_q, scan_plan.block_n
-    rerank_plan = plan_for(block_plan, "rerank")
     if rr is None:
         if prepared is None:
             prepared = flat_engine_inputs_from_snapshot(codes, n_levels,
@@ -414,7 +413,6 @@ def engine_search_from_snapshot(
         def snapshot_search(q_codes):
             return search(jax.device_put(q_codes, qspec), *ins)
 
-        snapshot_search.placed = tuple(ins)  # the device-placed shards
         return snapshot_search
 
     import numpy as np
@@ -447,13 +445,12 @@ def engine_search_from_snapshot(
             cand = cand[:, :kc_eff]
         return sdc_rerank_backend(
             q, fine_codes, fine_inv, cand, n_levels=n_levels, k=k,
-            backend=backend, block_plan=rerank_plan,
+            backend=backend,
         )
 
     if effort is not None:
         snapshot_search.effort = effort
     snapshot_search.reranked = True
-    snapshot_search.placed = tuple(ins)
     return snapshot_search
 
 
@@ -550,5 +547,4 @@ def hnsw_engine_search_from_snapshot(
     def snapshot_search(q_codes):
         return search(jax.device_put(q_codes, qspec), *ins)
 
-    snapshot_search.placed = tuple(ins)
     return snapshot_search

@@ -149,7 +149,6 @@ class BiGranularFlat:
         self, q_codes: jax.Array, k: int, block_n: int = BLOCK_N,
         k_coarse: int | None = None,
         scan_plan: BlockPlan | None = None,
-        rerank_plan: BlockPlan | None = None,
     ) -> Tuple[jax.Array, jax.Array]:
         kc = self.k_coarse if k_coarse is None else k_coarse
         kc = min(kc, self.fine_codes.shape[0])
@@ -160,7 +159,6 @@ class BiGranularFlat:
         return sdc_rerank_backend(
             q, self.fine_codes, self.fine_inv_norm, cand,
             n_levels=self.n_levels, k=k, backend=self.backend,
-            block_plan=rerank_plan,
         )
 
     def coarse_nbytes(self) -> int:
@@ -212,9 +210,8 @@ def flat_search_from_snapshot(
     without ``rerank`` is ignored.
 
     ``block_plan`` — a single ``BlockPlan`` or a ``{kind: plan}``
-    mapping (``launch/autotune``) — sets the scan tiles and, in
-    bi-granular mode, the rerank group size. Plans never change scores,
-    only launch shapes.
+    mapping (``launch/autotune``) — sets the scan tiles (the coarse scan
+    in bi-granular mode). Plans never change scores, only launch shapes.
     """
     from repro.index._snapshot import (
         resolve_rerank_args,
@@ -225,7 +222,6 @@ def flat_search_from_snapshot(
     codes, n_levels = resolve_snapshot_args(codes, n_levels)
     rr = resolve_rerank_args(rerank, n_levels)
     scan_plan = plan_for(block_plan, "scan")
-    rerank_plan = plan_for(block_plan, "rerank")
     if rr is None:
         index = FlatSDC.build(
             jnp.asarray(codes), n_levels, packed=packed, backend=backend
@@ -241,14 +237,13 @@ def flat_search_from_snapshot(
     if effort is None:
         fn = lambda q: bigr.search(  # noqa: E731
             q, k, block_n=block_n, scan_plan=scan_plan,
-            rerank_plan=rerank_plan,
         )
     else:
         def fn(q):
             kc_eff, _ = split_effort(effort.level, k=k, k_coarse=k_coarse)
             return bigr.search(
                 q, k, block_n=block_n, k_coarse=kc_eff,
-                scan_plan=scan_plan, rerank_plan=rerank_plan,
+                scan_plan=scan_plan,
             )
 
         fn.effort = effort

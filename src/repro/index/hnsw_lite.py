@@ -576,10 +576,10 @@ def hnsw_search_from_snapshot(
     ``k_coarse`` (floored at k) and only residual levels halve ef/beam.
 
     ``block_plan`` — a single ``BlockPlan`` or a ``{kind: plan}``
-    mapping (``launch/autotune``) — only the "rerank" plan applies here
-    (the survivor group size of the bi-granular rerank); the graph
-    walk's gather geometry is fixed by the beam/neighborhood layout, so
-    scan/gather plans are inert. Plans never change scores.
+    mapping (``launch/autotune``) — is accepted for signature parity
+    and inert: the graph walk and the bi-granular rerank both run on
+    the gather substrate, whose geometry is fixed by the
+    beam/neighborhood and survivor layouts.
     """
     from repro.index._snapshot import (
         resolve_rerank_args,
@@ -592,7 +592,7 @@ def hnsw_search_from_snapshot(
 
     codes, n_levels = resolve_snapshot_args(codes, n_levels)
     rr = resolve_rerank_args(rerank, n_levels)
-    rerank_plan = plan_for(block_plan, "rerank")
+    plan_for(block_plan, "rerank")  # validate mapping keys early
     if rr is None:
         codes = np.asarray(codes)
         inv = np.asarray(_ref.doc_inv_norms(jnp.asarray(codes), n_levels))
@@ -649,7 +649,7 @@ def hnsw_search_from_snapshot(
         )
         return sdc_rerank_backend(
             q, fine_codes, fine_inv, cand, n_levels=n_levels, k=k,
-            backend=backend, block_plan=rerank_plan,
+            backend=backend,
         )
 
     if effort is not None:

@@ -32,8 +32,11 @@ from repro.core.binarize_lib import (
 )
 from repro.index.kmeans import kmeans
 from repro.kernels.sdc import ref as sdc_ref
-from repro.kernels.sdc.defaults import GATHER_CHUNK, plan_for
-from repro.kernels.sdc.gather import sdc_gather_topk, sdc_gather_topk_xla
+from repro.kernels.sdc.gather import (
+    gather_list_len,
+    sdc_gather_topk,
+    sdc_gather_topk_xla,
+)
 from repro.kernels.sdc.ops import resolve_backend
 
 
@@ -104,11 +107,7 @@ def build_ivf(
     counts = np.bincount(assign, minlength=nlist)
     if max_len is None:
         max_len = int(counts.max())
-    max_len = max(1, int(np.ceil(max_len * headroom)))
-    if max_len > GATHER_CHUNK:
-        # Whole 128-lane tiles, so the gather kernel scores the list in
-        # bounded chunks (kernels/sdc/gather.list_chunk).
-        max_len = -(-max_len // 128) * 128
+    max_len = gather_list_len(max(1, int(np.ceil(max_len * headroom))))
     D = codes.shape[1]
 
     dropped = int(np.maximum(counts - max_len, 0).sum())
@@ -435,9 +434,9 @@ def ivf_search_from_snapshot(
     while a budget is set.
 
     ``block_plan`` (``kernels.sdc.defaults.BlockPlan``, e.g. from
-    ``launch/autotune``) reaches the bi-granular rerank stage; the IVF
-    scan itself runs on the gather substrate, whose geometry is fixed
-    by the list layout.
+    ``launch/autotune``) is accepted for signature parity with the other
+    entry points: the IVF scan and the bi-granular rerank both run on
+    the gather substrate, whose geometry is fixed by the list layout.
     """
     from repro.index._snapshot import (
         resolve_rerank_args,
@@ -519,7 +518,7 @@ def ivf_search_from_snapshot(
             )
         return sdc_rerank_backend(
             q, codes, fine_inv, cand, n_levels=n_levels, k=k,
-            backend=backend, block_plan=plan_for(block_plan, "rerank"),
+            backend=backend,
         )
 
     if effort is not None:

@@ -18,12 +18,10 @@ NamedTuple of scalars; ``kind`` selects which knobs apply:
     index layout (one probed list per grid step against a
     ``GATHER_BLOCK_Q``-row query tile); a plan records provenance but
     pins the defaults.
-  * ``rerank`` — ``block_n`` is the candidate *group* size of the
-    host-gather rerank path (``rerank.sdc_rerank_gathered``): survivor
-    rows are regrouped into lists of ``block_n`` entries so the gather
-    substrate runs ``k'/block_n`` steps per query instead of ``k'``.
-    ``block_q`` is recorded but inert (the gather kernel folds one
-    query row per step).
+  * ``rerank`` — the fine rerank gathers each query's survivors into
+    one candidate list (``rerank._gathered_lists``) and scores it on the
+    gather kernel, so like ``gather`` its geometry is fixed and a plan
+    records provenance only.
 
 Roofline constants for the hillclimb cost model (``launch/hillclimb.py``)
 live here too, so the tt_retrieval variants and the autotuner price
@@ -41,7 +39,7 @@ class BlockPlan(NamedTuple):
     ``source`` is provenance only (never part of equality-for-execution):
     "default" (this table), "tuned" (fresh sweep), "cache" (reloaded from
     the tune cache), "inert-backend" (xla — blocks don't reach the
-    kernel), "fixed-geometry" (gather — nothing to sweep).
+    kernel), "fixed-geometry" (gather, rerank — nothing to sweep).
     """
 
     kind: str
@@ -71,18 +69,14 @@ FLAT_BLOCK_Q = 8
 GATHER_BLOCK_Q = 8
 
 # Gather kernel list chunk: a probed list is scored GATHER_CHUNK slots at
-# a time, bounding VMEM temporaries for long IVF lists (build_ivf pads
-# lists longer than this to whole 128-lane tiles).
+# a time, bounding VMEM temporaries for long lists (gather.gather_list_len
+# pads lists longer than this to whole 128-lane tiles).
 GATHER_CHUNK = 1024
-
-# Host-gather rerank: one survivor row per gather step (the pre-plan
-# behavior; grouping is strictly a tuned upgrade).
-RERANK_GROUP = 1
 
 DEFAULT_PLANS = {
     "scan": BlockPlan("scan", BLOCK_Q, BLOCK_N, "default"),
     "gather": BlockPlan("gather", 1, 0, "default"),
-    "rerank": BlockPlan("rerank", 1, RERANK_GROUP, "default"),
+    "rerank": BlockPlan("rerank", 1, 0, "default"),
 }
 
 KERNEL_KINDS = tuple(DEFAULT_PLANS)

@@ -57,15 +57,30 @@ from repro.kernels.sdc.sdc import (
 )
 
 
+def gather_list_len(n: int) -> int:
+    """The list length that holds ``n`` slots in the kernel's layout.
+
+    Up to GATHER_CHUNK slots a list is scored in one piece and keeps its
+    length; a longer list is padded to whole 128-lane tiles, so that it
+    splits into lane-aligned chunks (``list_chunk``). Builders of list
+    tables size them with this and pad the extra slots with id -1.
+    """
+    return n if n <= GATHER_CHUNK else -(-n // LANES) * LANES
+
+
 def list_chunk(L: int) -> int:
     """Slots of one probed list scored per in-kernel step.
 
     A list is streamed into VMEM whole, but scored in chunks of at most
     GATHER_CHUNK lane-aligned slots so the temporaries (unpacked nibble
-    planes, the score tile) stay bounded whatever the list length. A
-    list whose length is not a multiple of 128 is scored in one piece.
+    planes, the score tile) stay bounded whatever the list length.
     """
-    if L <= GATHER_CHUNK or L % LANES:
+    if L != gather_list_len(L):
+        raise ValueError(
+            f"list length {L} exceeds GATHER_CHUNK={GATHER_CHUNK} and is "
+            f"not a multiple of {LANES}; size lists with gather_list_len"
+        )
+    if L <= GATHER_CHUNK:
         return L
     tiles = L // LANES
     return LANES * max(

@@ -33,8 +33,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.kernels.sdc.defaults import RERANK_GROUP, BlockPlan
-from repro.kernels.sdc.gather import sdc_gather_topk, sdc_gather_topk_xla
+from repro.kernels.sdc.gather import (
+    gather_list_len,
+    sdc_gather_topk,
+    sdc_gather_topk_xla,
+)
 from repro.kernels.sdc.ops import resolve_backend
 
 _INT32_MAX = np.iinfo(np.int32).max
@@ -60,7 +63,7 @@ def fine_inv_norms(codes, n_levels: int, chunk: int = 65536):
     return out
 
 
-def _sort_candidates(cand_ids: jax.Array) -> jax.Array:
+def _sort_candidates(cand_ids):
     """Ascending-id candidate order, invalid (< 0) slots pushed last.
 
     A flat scan scores documents in id order, so ``lax.top_k`` breaks
@@ -68,26 +71,33 @@ def _sort_candidates(cand_ids: jax.Array) -> jax.Array:
     the same order is what makes the rerank bit-identical to a
     restricted flat scan even through ties. Candidate ids must be
     distinct (coarse top-k' guarantees it); invalid slots come back -1.
+    Numpy ids (the host-gather path) stay on the host.
     """
-    ids = jnp.asarray(cand_ids, jnp.int32)
-    key = jnp.where(ids < 0, _INT32_MAX, ids)
-    key = jnp.sort(key, axis=-1)
-    return jnp.where(key == _INT32_MAX, -1, key)
+    xp = np if isinstance(cand_ids, np.ndarray) else jnp
+    ids = xp.asarray(cand_ids, xp.int32)
+    key = xp.sort(xp.where(ids < 0, _INT32_MAX, ids), axis=-1)
+    return xp.where(key == _INT32_MAX, -1, key)
 
 
 def _gathered_lists(fine_codes, fine_inv_norm, cand):
     """Survivor rows as one candidate list per query.
 
     ``cand`` is the sorted [Q, k'] table from ``_sort_candidates``.
-    Returns (codes [Q, k', D(/2)], inv [Q, k'], ids [Q, k'], probes
-    [Q, 1]): query q probes list q, whose slots are its survivors in
-    ascending-id order; empty slots carry inv 0 and id -1, so every
-    scoring path excludes them.
+    Returns (codes [Q, L, D(/2)], inv [Q, L], ids [Q, L], probes [Q, 1])
+    with L = ``gather_list_len(k')``: query q probes list q, whose slots
+    are its survivors in ascending-id order; empty and padding slots
+    carry inv 0 and id -1, so every scoring path excludes them. Device
+    arrays are gathered on the device; a numpy (or memory-mapped) fine
+    tier is gathered on the host, reading only the survivor rows.
     """
-    N = fine_codes.shape[0]
-    safe = jnp.clip(cand, 0, N - 1)
-    inv = jnp.where(cand >= 0, fine_inv_norm[safe], 0.0)
-    probes = jnp.arange(cand.shape[0], dtype=jnp.int32)[:, None]
+    xp = np if isinstance(fine_codes, np.ndarray) else jnp
+    Q, kp = cand.shape
+    pad = gather_list_len(kp) - kp
+    if pad:
+        cand = xp.concatenate([cand, xp.full((Q, pad), -1, xp.int32)], axis=1)
+    safe = xp.clip(cand, 0, fine_codes.shape[0] - 1)
+    inv = xp.where(cand >= 0, fine_inv_norm[safe], 0.0).astype(xp.float32)
+    probes = xp.arange(Q, dtype=xp.int32)[:, None]
     return fine_codes[safe], inv, cand, probes
 
 
@@ -162,54 +172,21 @@ def sdc_rerank_gathered(
     n_levels: int,
     k: int,
     packed: bool = False,
-    group: int = RERANK_GROUP,
     backend: str = "auto",
 ):
     """Cold-tier rerank: host-gather the survivor rows, score on device.
 
     For a memory-mapped fine tier (``np.memmap``), this is the only
     path that touches k' rows per query instead of paging the whole
-    corpus through ``jnp.asarray``. The gathered block is scored as
-    fixed-width candidate lists with an identity probe table, so the
-    float op order — and therefore every score and tie-break — matches
-    ``sdc_rerank`` / ``sdc_rerank_xla`` exactly.
-
-    ``group`` (the rerank axis of a ``BlockPlan``; default 1) is the
-    number of gathered survivor rows per list: the gather substrate
-    then runs ceil(k'/group) steps per query instead of k'. Because
-    scores are elementwise per (query, candidate) and the running
-    top-k merge is a stable selection over ascending-id candidates,
-    every group size returns bit-identical results — the knob only
-    moves launch overhead, which is what the autotuner sweeps.
-    Grouping also gives the kernel backends sublane-aligned tiles, so
-    ``backend="pallas"/"interpret"`` routes the grouped layout through
-    ``sdc_gather_topk`` instead of the jnp twin.
+    corpus through ``jnp.asarray``. The gathered rows form the same one
+    list per query as ``sdc_rerank`` / ``sdc_rerank_xla`` build on the
+    device (``_gathered_lists``), so the float op order — and therefore
+    every score and tie-break — matches theirs exactly.
     """
-    cand = np.asarray(cand_ids, np.int32)
-    key = np.sort(np.where(cand < 0, _INT32_MAX, cand), axis=-1)
-    cand = np.where(key == _INT32_MAX, -1, key)
-    Q, kp = cand.shape
-    g = max(1, min(int(group), kp))
-    pad = (-kp) % g
-    if pad:
-        cand = np.concatenate([cand, -np.ones((Q, pad), np.int32)], axis=1)
-        kp += pad
-    N = fine_codes.shape[0]
-    safe = np.clip(cand, 0, N - 1)
-    g_codes = np.asarray(fine_codes)[safe]  # [Q, k', D(/2)] cold-tier reads
-    g_inv = np.where(
-        cand >= 0, np.asarray(fine_inv_norm)[safe], 0.0
-    ).astype(np.float32)
-    n_lists = Q * kp // g
-    lists_codes = g_codes.reshape(n_lists, g, g_codes.shape[-1])
-    lists_inv = g_inv.reshape(n_lists, g)
-    lists_ids = cand.reshape(n_lists, g)
-    probes = np.arange(n_lists, dtype=np.int32).reshape(Q, kp // g)
+    cand = _sort_candidates(np.asarray(cand_ids, np.int32))
+    lists = _gathered_lists(fine_codes, np.asarray(fine_inv_norm), cand)
+    args = (jnp.asarray(q_codes), *(jnp.asarray(a) for a in lists))
     backend = resolve_backend(backend)
-    args = (
-        jnp.asarray(q_codes), jnp.asarray(lists_codes),
-        jnp.asarray(lists_inv), jnp.asarray(lists_ids), jnp.asarray(probes),
-    )
     if backend in ("pallas", "interpret"):
         return sdc_gather_topk(
             *args, n_levels=n_levels, k=k,
@@ -228,32 +205,20 @@ def sdc_rerank_backend(
     k: int,
     backend: str = "auto",
     packed: bool = False,
-    block_plan: BlockPlan | None = None,
 ):
     """Dispatch a fine rerank to the resolved backend.
 
     A numpy fine tier (the cold, possibly memory-mapped layout) always
     takes the host-gather path regardless of backend — moving the whole
     corpus on device would defeat the tiering. Device-resident fine
-    codes go through the Pallas gather kernel or its jnp twin.
-
-    ``block_plan`` (kind "rerank") sets the host-gather candidate group
-    size; results are bit-identical across plans (see
-    ``sdc_rerank_gathered``). Device-resident fine tiers gather by DMA
-    index map — there is no regrouping to tune — so the plan is inert
-    for them.
+    codes are gathered on the device into the same one list per query
+    and go through the Pallas gather kernel or its jnp twin.
     """
     backend = resolve_backend(backend)
     if isinstance(fine_codes, np.ndarray):
-        group = (
-            block_plan.block_n
-            if block_plan is not None and block_plan.kind == "rerank"
-            else RERANK_GROUP
-        )
         return sdc_rerank_gathered(
             q_codes, fine_codes, fine_inv_norm, cand_ids,
-            n_levels=n_levels, k=k, packed=packed, group=group,
-            backend=backend,
+            n_levels=n_levels, k=k, packed=packed, backend=backend,
         )
     if backend == "xla":
         return sdc_rerank_xla(

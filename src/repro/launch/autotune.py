@@ -1,6 +1,6 @@
 """Persistent block-plan autotuner for the SDC kernel family.
 
-Every Pallas scan/gather/rerank launch used to run with the hand-picked
+Every Pallas scan launch used to run with the hand-picked
 tiles from ``kernels/sdc/defaults.py`` regardless of the live corpus
 shape. This module closes that gap: on first use of a kernel signature
 ``(kind, code_dim, n_shard, packed, k, backend)`` it sweeps a small
@@ -22,9 +22,10 @@ Two signatures are never swept:
 
   * the "xla" backend has no tiles — blocks are inert, so the default
     plan comes back immediately (source "inert-backend");
-  * kind "gather" has corpus-fixed geometry (one probed list per grid
-    step; the list length is the tile), so there is nothing to sweep
-    (source "fixed-geometry").
+  * kinds "gather" and "rerank" have layout-fixed geometry (one probed
+    list per grid step; the list length is the tile — an inverted list,
+    or one query's survivors), so there is nothing to sweep (source
+    "fixed-geometry").
 
 The sweep always times the default plan alongside the candidates and
 keeps it unless a candidate is strictly faster, so a tuned plan can
@@ -47,7 +48,6 @@ import numpy as np
 
 from repro.kernels.sdc.defaults import BlockPlan, default_plan
 from repro.kernels.sdc.ops import resolve_backend, sdc_search_backend
-from repro.kernels.sdc.rerank import sdc_rerank_gathered
 from repro.launch.binarizer_cache import CACHE_ENV, resolve_cache_dir
 
 __all__ = [
@@ -60,6 +60,9 @@ __all__ = [
 ]
 
 _SCHEMA = 1
+
+# Kernel kinds whose launch geometry the list layout fixes.
+FIXED_GEOMETRY = ("gather", "rerank")
 
 
 class TunedPlan(NamedTuple):
@@ -96,15 +99,12 @@ def candidate_grid(
     Deliberately small — the sweep runs on a live serving path. Scan
     candidates stay sublane/lane aligned (block_q multiple of 8,
     block_n multiple of 128) and never exceed the padded corpus, so
-    every candidate is a legal launch. Rerank candidates are survivor
-    group sizes for the host-gather path.
+    every candidate is a legal launch. Fixed-geometry kinds have only
+    the default.
     """
     base = default_plan(kind)
-    if kind == "gather":
+    if kind in FIXED_GEOMETRY:
         return [base.blocks()]
-    if kind == "rerank":
-        groups = [g for g in (1, 8, 32) if g <= max(1, k)]
-        return [(base.block_q, g) for g in (groups or [1])]
     # kind == "scan"
     cands: list[tuple[int, int]] = [base.blocks()]
     for bq in (8, 32, 128):
@@ -153,20 +153,6 @@ def _candidate_timer(
 ):
     """A zero-arg callable running one launch with the given blocks."""
     q, d, inv = operands
-    if kind == "rerank":
-        # Host-gather rerank of k survivors per query against the shard.
-        n = int(d.shape[0])
-        fine = np.asarray(d)
-        fine_inv = np.asarray(inv)
-        rng = np.random.default_rng(1)
-        cand = np.stack([
-            rng.choice(n, size=min(k, n), replace=False)
-            for _ in range(q.shape[0])
-        ]).astype(np.int32)
-        return lambda: sdc_rerank_gathered(
-            q, fine, fine_inv, cand, n_levels=n_levels, k=min(k, n),
-            packed=packed, group=blocks[1], backend=backend,
-        )
     bq, bn = blocks
     return lambda: sdc_search_backend(
         q, d, inv, n_levels=n_levels, k=k, backend=backend,
@@ -222,10 +208,10 @@ def tuned_block_plan(
     """
     resolved = resolve_backend(backend)
     base = default_plan(kind)
-    if resolved == "xla" and kind != "rerank":
+    if resolved == "xla":
         # No kernel tiles on the jnp path; nothing to sweep or cache.
         return TunedPlan(base._replace(source="inert-backend"), "", None, False)
-    if kind == "gather":
+    if kind in FIXED_GEOMETRY:
         return TunedPlan(base._replace(source="fixed-geometry"), "", None, False)
 
     signature = {

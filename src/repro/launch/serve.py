@@ -313,24 +313,23 @@ def main():
     cl = args.coarse_levels or None
     kc = args.k_coarse or None
 
-    # Adaptive execution: tune (or reload) a block plan per kernel kind
-    # for the live corpus shapes. Plans only move launch geometry —
-    # every score below is bit-identical with block_plan=None.
+    # Adaptive execution: tune (or reload) the scan's block plan for the
+    # live corpus shape (the gather and rerank kernels have layout-fixed
+    # geometry). Plans only move launch geometry — every score below is
+    # bit-identical with block_plan=None.
     block_plan = None
     if args.autotune:
         from repro.launch import autotune
 
-        block_plan = {}
-        for kind in ("scan", "rerank"):
-            tp = autotune.tuned_block_plan(
-                kind, code_dim=args.code_dim, n_shard=args.docs,
-                packed=args.packed, k=(kc or args.k), n_levels=args.levels,
-                backend=args.backend, cache_dir=args.tune_cache,
-            )
-            block_plan[kind] = tp.plan
-            print(f"[tune] {kind}: block_q={tp.plan.block_q} "
-                  f"block_n={tp.plan.block_n} ({tp.plan.source}"
-                  f"{', swept now' if tp.tuned else ''})")
+        tp = autotune.tuned_block_plan(
+            "scan", code_dim=args.code_dim, n_shard=args.docs,
+            packed=args.packed, k=(kc or args.k), n_levels=args.levels,
+            backend=args.backend, cache_dir=args.tune_cache,
+        )
+        block_plan = {"scan": tp.plan}
+        print(f"[tune] scan: block_q={tp.plan.block_q} "
+              f"block_n={tp.plan.block_n} ({tp.plan.source}"
+              f"{', swept now' if tp.tuned else ''})")
 
     if spec is not None:
         # The spec's build params are the single source of truth; the

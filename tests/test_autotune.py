@@ -21,7 +21,6 @@ from repro.kernels.sdc.defaults import (
     plan_for,
 )
 from repro.kernels.sdc.ops import sdc_search_backend
-from repro.kernels.sdc.rerank import sdc_rerank_gathered
 from repro.launch import autotune
 
 M, N, LEVELS = 16, 64, 2
@@ -217,19 +216,15 @@ def test_plan_is_bit_identical_through_flat_index():
     np.testing.assert_array_equal(np.asarray(i), np.asarray(ref_i))
 
 
-def test_rerank_grouping_is_bit_identical():
-    cd, cq = _codes(seed=5)
-    inv = jnp.ones(N, jnp.float32)
-    rng = np.random.default_rng(7)
-    cand = np.stack([
-        rng.choice(N, size=8, replace=False) for _ in range(cq.shape[0])
-    ]).astype(np.int32)
-    ref_s, ref_i = sdc_rerank_gathered(cq, np.asarray(cd), np.asarray(inv),
-                                       cand, n_levels=LEVELS, k=4, group=1)
-    s, i = sdc_rerank_gathered(cq, np.asarray(cd), np.asarray(inv), cand,
-                               n_levels=LEVELS, k=4, group=4)
-    np.testing.assert_array_equal(np.asarray(s), np.asarray(ref_s))
-    np.testing.assert_array_equal(np.asarray(i), np.asarray(ref_i))
+def test_rerank_geometry_is_fixed(tmp_path):
+    # The rerank scores one survivor list per query on the gather
+    # kernel: nothing to sweep, nothing written to the cache.
+    assert autotune.candidate_grid("rerank", code_dim=M, n_shard=N,
+                                   packed=False, k=4) == [
+        default_plan("rerank").blocks()]
+    tp = _tune("rerank", cache_dir=str(tmp_path))
+    assert tp.plan.source == "fixed-geometry" and tp.path is None
+    assert not list(tmp_path.iterdir())
 
 
 def test_plan_for_selects_by_kind():

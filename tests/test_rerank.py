@@ -159,6 +159,30 @@ def test_invalid_slots_are_masked_not_clamped():
                             n_levels=LEVELS, k=k), ref)
 
 
+@pytest.mark.parametrize("path", ["interpret", "xla", "gathered"])
+def test_long_survivor_lists_pad_to_lane_tiles(path):
+    """k' above GATHER_CHUNK and off the 128-lane grid: every path pads
+    the one survivor list per query to whole lane tiles (id -1 slots)
+    and still equals the restricted scan bit-for-bit."""
+    from repro.kernels.sdc.defaults import GATHER_CHUNK
+
+    kp = GATHER_CHUNK + 60
+    cd, cq, inv = _world(5, n=kp + 100, q=2)
+    cand = _candidates(5, cd.shape[0], cq.shape[0], kp, n_invalid=7)
+    k = 6
+    ref = _restricted_scan(cq, cd, inv, cand, k)
+    if path == "gathered":
+        got = sdc_rerank_gathered(cq, np.asarray(cd), np.asarray(inv), cand,
+                                  n_levels=LEVELS, k=k, backend="interpret")
+    elif path == "xla":
+        got = sdc_rerank_xla(cq, cd, inv, jnp.asarray(cand),
+                             n_levels=LEVELS, k=k)
+    else:
+        got = sdc_rerank(cq, cd, inv, jnp.asarray(cand), n_levels=LEVELS,
+                         k=k, interpret=True)
+    _assert_same(got, ref)
+
+
 def test_backend_dispatch_memmap_cold_tier(tmp_path):
     """A memory-mapped fine tier takes the host-gather path and still
     matches the restricted scan bit-for-bit; fine_inv_norms streams the

@@ -200,6 +200,27 @@ def test_gather_topk_long_lists_score_in_chunks(packed):
     np.testing.assert_array_equal(np.asarray(got[0]), np.asarray(want[0]))
 
 
+def test_gather_topk_refuses_long_unaligned_lists():
+    """A list longer than GATHER_CHUNK must come in whole 128-lane tiles
+    (``gather_list_len``); the kernel refuses any other length rather
+    than score it in one unbounded piece."""
+    from repro.kernels.sdc.defaults import GATHER_CHUNK
+    from repro.kernels.sdc.gather import gather_list_len, list_chunk
+
+    assert gather_list_len(GATHER_CHUNK) == GATHER_CHUNK
+    assert gather_list_len(100) == 100
+    assert gather_list_len(GATHER_CHUNK + 1) == GATHER_CHUNK + 128
+    L = GATHER_CHUNK + 60
+    with pytest.raises(ValueError, match="gather_list_len"):
+        list_chunk(L)
+    codes, _, inv, ids = _lists(31, 2, L, 32, n_pad=4)
+    q = jnp.zeros((2, 32), jnp.int8)
+    probes = jnp.zeros((2, 1), jnp.int32)
+    with pytest.raises(ValueError, match="gather_list_len"):
+        sdc_gather_topk(q, codes, inv, ids, probes, n_levels=4, k=4,
+                        interpret=True)
+
+
 def test_gather_topk_k_exceeds_list_len():
     nlist, L, D = 4, 8, 32
     codes, flat, inv, ids = _lists(23, nlist, L, D, n_pad=2)
