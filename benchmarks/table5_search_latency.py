@@ -1317,7 +1317,7 @@ def emit_serving_json(path: str = BENCH_SERVING_JSON, n_docs: int = 50_000,
          "ms_per_batch": 1e3 * n_q / (pipe_best * n_batches),
          "latency_p50_ms": best_stats.get("latency_p50_ms"),
          "latency_p99_ms": best_stats.get("latency_p99_ms"),
-         "device_idle_frac": best_stats.get("device_idle_frac")},
+         "scan_input_wait_frac": best_stats.get("scan_input_wait_frac")},
     ]
     for n in replica_sweep:
         s = repl_stats[n]
@@ -1332,12 +1332,12 @@ def emit_serving_json(path: str = BENCH_SERVING_JSON, n_docs: int = 50_000,
             "ms_per_batch": 1e3 * n_q / (repl_best[n] * n_batches),
             "latency_p50_ms": s.get("latency_p50_ms"),
             "latency_p99_ms": s.get("latency_p99_ms"),
-            "device_idle_frac": s.get("device_idle_frac"),
+            "scan_input_wait_frac": s.get("scan_input_wait_frac"),
             "shed": s.get("shed"), "failovers": s.get("failovers"),
             "per_replica": [
                 {"replica": pr["replica"], "requests": pr["requests"],
                  "queries": pr["queries"], "shed": pr["shed"],
-                 "device_idle_frac": pr["device_idle_frac"],
+                 "scan_input_wait_frac": pr["scan_input_wait_frac"],
                  "generation": pr["generation"]}
                 for pr in s.get("per_replica", [])
             ],
@@ -1385,7 +1385,8 @@ def emit_serving_json(path: str = BENCH_SERVING_JSON, n_docs: int = 50_000,
           f"best-paired-trial ({pipe_best/seq_best:.3f} best-of; "
           f"p50 {best_stats.get('latency_p50_ms', 0):.1f} ms, "
           f"p99 {best_stats.get('latency_p99_ms', 0):.1f} ms, "
-          f"device idle {100*best_stats.get('device_idle_frac', 0):.0f}%)")
+          f"scan stage waiting for input "
+          f"{100*best_stats.get('scan_input_wait_frac', 0):.0f}%)")
     for n in replica_sweep:
         if n == 1:
             continue

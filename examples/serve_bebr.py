@@ -293,12 +293,12 @@ def main():
     print(f"sequential (1 replica): {n_q/dt_seq:.0f} QPS | routed "
           f"({args.replicas} replicas): {n_q/dt:.0f} QPS on {n_devices} "
           f"{devices[0].platform} leaves (p50 {stats['latency_p50_ms']:.1f} ms, "
-          f"p99 {stats['latency_p99_ms']:.1f} ms, device idle "
-          f"{100*stats['device_idle_frac']:.0f}%)")
+          f"p99 {stats['latency_p99_ms']:.1f} ms, scan stage waiting for "
+          f"input {100*stats['scan_input_wait_frac']:.0f}%)")
     for srep in stats["per_replica"]:
         print(f"  replica {srep['replica']}: {srep['requests']} req "
-              f"({srep['queries']} queries), device idle "
-              f"{100*srep['device_idle_frac']:.0f}%, "
+              f"({srep['queries']} queries), scan stage waiting for input "
+              f"{100*srep['scan_input_wait_frac']:.0f}%, "
               f"generation {srep['generation']}")
     if swap_report is not None:
         rep = swap_report

@@ -142,11 +142,11 @@ def _row_bytes(row: dict):
 # fail the gate, not silently pass with holes.
 REPLICATED_ROW_KEYS = (
     "replicas", "router", "qps", "qps_ratio_vs_single", "ms_per_batch",
-    "latency_p50_ms", "latency_p99_ms", "device_idle_frac",
+    "latency_p50_ms", "latency_p99_ms", "scan_input_wait_frac",
     "shed", "failovers", "per_replica",
 )
 PER_REPLICA_KEYS = ("replica", "requests", "queries", "shed",
-                    "device_idle_frac", "generation")
+                    "scan_input_wait_frac", "generation")
 
 # Live index lifecycle row (added with launch/lifecycle.py): a rolling
 # per-replica swap under continuous traffic plus a canary revival. The

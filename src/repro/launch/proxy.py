@@ -91,6 +91,7 @@ On top of routing and failover sits the robustness layer:
 from __future__ import annotations
 
 import dataclasses
+import itertools
 import logging
 import random
 import threading
@@ -100,6 +101,7 @@ from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple, Union
 import numpy as np
 
 from repro.launch.clock import SYSTEM_CLOCK, Clock
+from repro.launch.spans import Span
 from repro.launch.serving import (
     Array,
     DeadlineExpired,
@@ -499,7 +501,8 @@ class QueryRouter:
         # shrinks — drains complete the instant the last ticket lands,
         # not on the next poll tick.
         self._cond = threading.Condition(self._lock)
-        self._seq = 0
+        # Sequence numbers: a request's id in its tickets and its spans.
+        self._seq = itertools.count()
         self._closed = False
         # Set first thing in close(): any clock.wait parked on a retry
         # backoff (submit_with_retry, run_stream_with_swap's shed retry)
@@ -616,7 +619,26 @@ class QueryRouter:
         healthy replicas that serve the wrong version with no compat
         path raise ``IncompatibleVersion`` — terminal, like
         ``AllReplicasDown``, unlike ``RequestShed``.
+
+        Profiler spans: ``proxy.submit`` covers this call, and
+        ``proxy.request`` runs from here until the ticket resolves (or
+        this call raises). Both carry the sequence number as ``req``, as
+        do the replica's spans of this request.
         """
+        seq = next(self._seq)
+        root = Span("proxy.request", seq)
+        span = Span("proxy.submit", seq)
+        try:
+            ticket = self._submit(seq, root, queries, deadline)
+        except BaseException:
+            span.close()
+            root.close()
+            raise
+        span.close()
+        return ticket
+
+    def _submit(self, seq: int, root: Span, queries: Any,
+                deadline: Optional[float]) -> ProxyTicket:
         req = as_search_request(queries, deadline=deadline)
         deadline = req.deadline
         if deadline is not None and self.clock.now() >= deadline:
@@ -653,9 +675,8 @@ class QueryRouter:
                     f"{sorted(str(self._route_version(i)) for i in self._healthy)}, "
                     f"compat pairs: {self.compat.pairs()})"
                 )
-            seq = self._seq
-            self._seq += 1
         ticket = ProxyTicket(seq, req, deadline=deadline)
+        ticket.span = root
         shed_error: Optional[RequestShed] = None
         for attempt in (0, 1):
             for replica in order:
@@ -832,7 +853,8 @@ class QueryRouter:
         )
         try:
             inner = pipe.submit(inner_req, force_block=force,
-                                deadline=ticket.deadline)  # may shed
+                                deadline=ticket.deadline,
+                                trace_id=ticket.seq)  # may shed
         except BaseException:
             with self._lock:
                 self._outstanding[replica].discard(ticket)
@@ -1429,9 +1451,10 @@ class QueryRouter:
     def stats(self) -> dict:
         """One proxy-level report over the whole tier.
 
-        Aggregates each replica's totals and merges their latency
-        windows for tier-wide percentiles; per-replica breakdowns ride
-        along under ``per_replica``.
+        Aggregates each replica's totals; the percentiles come from the
+        router's own window of proxy enqueue -> reply latencies.
+        Per-replica breakdowns (stage totals included) ride along under
+        ``per_replica``.
         """
         with self._lock:  # one snapshot: per-replica flags must agree
             shed_proxy = self.shed_count
@@ -1462,11 +1485,11 @@ class QueryRouter:
             per.append(s)
         n_req, n_q, lat = self._stats.snapshot()
         lat.sort()
-        # Averages (idle) and the headline count cover only live slots;
-        # retired pipelines are closed and would skew both.
+        # Averages (input wait) and the headline count cover only live
+        # slots; retired pipelines are closed and would skew both.
         live = [s for s in per if s["state"] != "retired"]
-        idle = (
-            sum(s["device_idle_frac"] for s in live) / len(live)
+        input_wait = (
+            sum(s["scan_input_wait_frac"] for s in live) / len(live)
             if live else 0.0
         )
         return {
@@ -1501,7 +1524,7 @@ class QueryRouter:
             # wait + failover re-dispatches included).
             "latency_p50_ms": 1e3 * _percentile(lat, 0.50),
             "latency_p99_ms": 1e3 * _percentile(lat, 0.99),
-            "device_idle_frac": idle,
+            "scan_input_wait_frac": input_wait,
             "per_replica": per,
         }
 

@@ -600,13 +600,13 @@ def main():
           f"{1e3 * dt_pipe / len(stream):.1f} ms/batch "
           f"({n_q_routed / dt_pipe:.0f} QPS; "
           f"p50={stats['latency_p50_ms']:.1f} ms "
-          f"p99={stats['latency_p99_ms']:.1f} ms, device idle "
-          f"{100 * stats['device_idle_frac']:.0f}%{shed})")
+          f"p99={stats['latency_p99_ms']:.1f} ms, scan stage waiting "
+          f"for input {100 * stats['scan_input_wait_frac']:.0f}%{shed})")
     if args.replicas > 1:
         for s in stats["per_replica"]:
             print(f"[serve]   replica {s['replica']}: {s['requests']} req "
-                  f"({s['queries']} queries), shed {s['shed']}, device idle "
-                  f"{100 * s['device_idle_frac']:.0f}%")
+                  f"({s['queries']} queries), shed {s['shed']}, scan stage "
+                  f"waiting for input {100 * s['scan_input_wait_frac']:.0f}%")
     if swap_report is not None:
         rep = swap_report
         print(f"[swap] rolling swap -> {rep.version.tag}: {rep.swapped} "

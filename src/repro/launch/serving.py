@@ -29,6 +29,14 @@ Single encode thread, single scan thread, FIFO queues throughout:
 results come back in submission order and are bit-identical to a
 sequential encode+search loop (no cross-batch state anywhere).
 
+Each stage boundary writes a profiler span (``launch/spans.py``), in the
+order a request meets them: ``serving.admit`` (the put, back-pressure
+included), ``serving.queued`` (admitted -> taken by the encode stage),
+``serving.encode``, ``serving.handoff`` (encoded -> dispatch starts),
+``serving.dispatch``, ``serving.await``, ``serving.resolve``; the stages'
+blocked waits are ``serving.encode_idle`` and ``serving.scan_idle``.
+``stats()["stages"]`` totals the same boundaries per generation.
+
 ``SearchFn`` is any ``codes -> (scores [Q, k], ids [Q, k])`` callable —
 ``FlatSDC.search`` closures, ``ivf.search`` closures,
 ``hnsw_lite.search_hnsw_batched`` closures, and the distributed
@@ -76,6 +84,8 @@ import time
 from typing import Any, Callable, List, Optional, Protocol, Sequence, Tuple
 
 import jax
+
+from repro.launch.spans import Span, StageTimes
 
 Array = Any
 
@@ -264,13 +274,20 @@ class Ticket:
     ``deadline`` is an absolute ``time.perf_counter()`` instant (None =
     no deadline): a stage that dequeues the batch after it has passed
     sheds the ticket with ``DeadlineExpired`` instead of scanning it.
+
+    ``trace_id`` is the ``req`` argument of the request's profiler spans
+    (the router's sequence number; ``seq`` when None). ``span``, when
+    set, is closed by the winning resolve.
     """
 
     def __init__(self, seq: int, n_queries: int,
-                 deadline: Optional[float] = None):
+                 deadline: Optional[float] = None,
+                 trace_id: Optional[int] = None):
         self.seq = seq
+        self.trace_id = seq if trace_id is None else trace_id
         self.n_queries = n_queries
         self.deadline = deadline
+        self.span: Optional[Span] = None
         self.t_enqueue = time.perf_counter()
         self.t_reply: Optional[float] = None
         # The typed request this ticket was admitted with (None for a
@@ -315,6 +332,8 @@ class Ticket:
             self.request = None
             self._done.set()
             callbacks, self._callbacks = self._callbacks, []
+        if self.span is not None:
+            self.span.close()
         # Outside the lock: a callback may re-enter ticket/router state
         # (the proxy's failover re-dispatch does). Shielded: _resolve
         # runs on stage threads, and a raising callback would otherwise
@@ -385,6 +404,20 @@ class Ticket:
 _SENTINEL = object()
 
 
+class _AdmissionFifo(queue.Queue):
+    """The admission FIFO of ``(ticket, payload)`` items, which come out
+    as ``(ticket, payload, span)``: the ``serving.queued`` span opens in
+    ``_put``, under the queue's own lock as the item goes in, so no
+    consumer can take the item before its span is open, a request shed
+    at a full queue opens none, and a back-pressure wait stays in
+    ``serving.admit``. Whoever takes the item out closes the span."""
+
+    def _put(self, item):
+        if item is not _SENTINEL:
+            item = (*item, Span("serving.queued", item[0].trace_id))
+        super()._put(item)
+
+
 class AdmissionQueue:
     """Bounded admission front: FIFO + block/shed policy + ticket minting.
 
@@ -395,8 +428,9 @@ class AdmissionQueue:
 
     ``admit`` mints a ``Ticket`` (seq number, enqueue timestamp) and
     enqueues ``(ticket, payload)``. Consumers drain with ``get`` /
-    ``get_nowait``; ``close`` marks the queue closed and pushes a
-    sentinel so a consumer loop can terminate; ``sweep`` fails every
+    ``get_nowait``, which return ``(ticket, payload, queued_span)`` (the
+    taker closes the span); ``close`` marks the queue closed and pushes
+    a sentinel so a consumer loop can terminate; ``sweep`` fails every
     still-queued ticket with ``PipelineClosed``.
     """
 
@@ -405,7 +439,7 @@ class AdmissionQueue:
             raise ValueError(f"policy must be block|shed, got {policy!r}")
         self.depth = depth
         self.policy = policy
-        self._q: "queue.Queue" = queue.Queue(maxsize=depth)
+        self._q: "queue.Queue" = _AdmissionFifo(maxsize=depth)
         self._lock = threading.Lock()
         self._seq = 0
         self._closed = False
@@ -416,7 +450,8 @@ class AdmissionQueue:
         return self._closed
 
     def admit(self, payload: Any, *, force_block: bool = False,
-              deadline: Optional[float] = None) -> Ticket:
+              deadline: Optional[float] = None,
+              trace_id: Optional[int] = None) -> Ticket:
         """Admit one payload; returns its ``Ticket``.
 
         block policy: waits for queue space (back-pressure).
@@ -425,6 +460,7 @@ class AdmissionQueue:
         not drop a ticket that was already admitted once).
         ``deadline``: absolute perf_counter instant after which the
         stages shed the batch at dequeue instead of serving it.
+        ``trace_id``: the ``req`` of the ticket's spans (see ``Ticket``).
         """
         with self._lock:
             if self._closed:
@@ -435,21 +471,25 @@ class AdmissionQueue:
             n = payload.n_queries
         else:
             n = int(getattr(payload, "shape", (1,))[0])
-        ticket = Ticket(seq, n, deadline=deadline)
+        ticket = Ticket(seq, n, deadline=deadline, trace_id=trace_id)
         if isinstance(payload, SearchRequest):
             ticket.request = payload
         item = (ticket, payload)
-        if self.policy == "shed" and not force_block:
-            try:
-                self._q.put_nowait(item)
-            except queue.Full:
-                with self._lock:
-                    self.shed_count += 1
-                raise RequestShed(
-                    f"admission queue full (depth={self.depth})"
-                ) from None
-        else:
-            self._q.put(item)
+        span = Span("serving.admit", ticket.trace_id)
+        try:
+            if self.policy == "shed" and not force_block:
+                try:
+                    self._q.put_nowait(item)
+                except queue.Full:
+                    with self._lock:
+                        self.shed_count += 1
+                    raise RequestShed(
+                        f"admission queue full (depth={self.depth})"
+                    ) from None
+            else:
+                self._q.put(item)
+        finally:
+            span.close()
         return ticket
 
     def get(self):
@@ -482,6 +522,7 @@ class AdmissionQueue:
             while True:
                 item = self._q.get_nowait()
                 if item is not _SENTINEL:
+                    item[2].close()
                     item[0]._resolve(error=PipelineClosed("pipeline closed"))
         except queue.Empty:
             pass
@@ -493,8 +534,7 @@ class LatencyStats:
     Retaining whole tickets (and their result arrays) would grow without
     bound on a long-running pipeline, so completions are folded into
     running counters plus a sliding window of recent latencies for
-    percentiles. ``window()`` exposes the raw window so the proxy tier
-    can merge replicas into one report.
+    percentiles.
     """
 
     def __init__(self, window: int = 4096):
@@ -512,10 +552,6 @@ class LatencyStats:
     def snapshot(self) -> Tuple[int, int, List[float]]:
         with self._lock:
             return self.n_completed, self.n_queries, list(self._latencies)
-
-    def window(self) -> List[float]:
-        with self._lock:
-            return list(self._latencies)
 
 
 class ServingPipeline:
@@ -590,10 +626,9 @@ class ServingPipeline:
         self._watchdog_thread: Optional[threading.Thread] = None
         self._watchdog_stop = threading.Event()
         self.watchdog_stalls = 0
-        # device-idle accounting (scan thread): time spent waiting for an
-        # encoded batch = the device had nothing to do.
-        self._scan_idle_s = 0.0
-        self._scan_busy_s = 0.0
+        # Seconds and counts of each stage this generation, timed at the
+        # boundaries of the stages' profiler spans (``launch/spans.py``).
+        self._times = StageTimes()
         self._encode_thread = threading.Thread(
             target=self._encode_loop, name="serving-encode", daemon=True
         )
@@ -612,7 +647,8 @@ class ServingPipeline:
         return self._admission.shed_count
 
     def submit(self, queries: Any, *, force_block: bool = False,
-               deadline: Optional[float] = None) -> Ticket:
+               deadline: Optional[float] = None,
+               trace_id: Optional[int] = None) -> Ticket:
         """Admit one query batch; returns a ``Ticket``.
 
         block policy: waits for queue space (back-pressure).
@@ -629,6 +665,9 @@ class ServingPipeline:
         pre-``SearchRequest`` path) or a ``SearchRequest`` (typed path:
         codes bypass the encode stage, ``k`` truncates, the request's
         own deadline applies when the kwarg is None).
+
+        ``trace_id``: the ``req`` of the request's profiler spans (the
+        router passes its sequence number; the ticket's own by default).
         """
         if isinstance(queries, SearchRequest) and deadline is None:
             deadline = queries.deadline
@@ -640,7 +679,8 @@ class ServingPipeline:
             self._inflight_n += 1
         try:
             ticket = self._admission.admit(
-                queries, force_block=force_block, deadline=deadline
+                queries, force_block=force_block, deadline=deadline,
+                trace_id=trace_id,
             )
         except BaseException:
             with self._idle_cond:
@@ -725,9 +765,9 @@ class ServingPipeline:
 
         A revived replica's throughput and latency must not be conflated
         with its pre-death run — completed counters fold into lifetime
-        totals and the window/idle accounting resets. Call only on a
-        quiesced pipeline (the scan thread also writes the idle/busy
-        clocks). Returns the new generation number.
+        totals and the window and stage totals reset. Call only on a
+        quiesced pipeline (the stage threads also write the stage
+        totals). Returns the new generation number.
         """
         with self._record_lock:
             n_req, n_q, _ = self._stats.snapshot()
@@ -737,8 +777,7 @@ class ServingPipeline:
             self._lifetime_deadline_expired += self._deadline_expired
             self._deadline_expired = 0
             self._stats = LatencyStats()
-            self._scan_idle_s = 0.0
-            self._scan_busy_s = 0.0
+            self._times.reset()
             self.generation += 1
             return self.generation
 
@@ -863,12 +902,16 @@ class ServingPipeline:
     # ------------------------------------------------------------------
 
     def _encode_loop(self):
+        times = self._times
         while True:
+            idle = Span("serving.encode_idle")
             item = self._admission.get()
+            idle.close()
             if item is _SENTINEL:
                 self._encoded.put(_SENTINEL)
                 return
-            ticket, queries = item
+            ticket, queries, queued = item
+            times.add("admission_wait", queued.close())
             if ticket.expired():
                 # Shed at dequeue: an expired batch is never encoded —
                 # the client's budget is spent, and the stage time would
@@ -889,37 +932,48 @@ class ServingPipeline:
                         if req.encode_override is not None:
                             enc = req.encode_override
                         src = req.queries
-                    codes = enc(src)
+                    span = Span("serving.encode", ticket.trace_id)
+                    try:
+                        codes = enc(src)
+                    finally:
+                        times.add("encode", span.close())
             except BaseException as e:  # surfaced on the ticket
                 ticket._resolve(error=e)
                 continue
-            self._encoded.put((ticket, codes))
+            # The hand-off span opens before the put: a full queue's wait
+            # is the scan stage holding this request back.
+            self._encoded.put(
+                (ticket, codes, Span("serving.handoff", ticket.trace_id)))
 
     def _scan_loop(self):
         inflight: "collections.deque" = collections.deque()
+        times = self._times
+
+        def resolve(ticket, value=None, error=None):
+            # The resolve and its done callbacks (the proxy's, which end
+            # the request) run in one span. Stage time is booked inside
+            # the lock: the resolve wakes quiesce(), and a generation
+            # rollover must not slip in before the booking.
+            span = Span("serving.resolve", ticket.trace_id)
+            with self._record_lock:
+                if ticket._resolve(value=value, error=error) \
+                        and error is None:
+                    self._stats.record(ticket)
+                times.add("resolve", span.close())
 
         def await_oldest():
             ticket, vals, ids = inflight.popleft()
-            t0 = time.perf_counter()
+            span = Span("serving.await", ticket.trace_id)
             try:
                 vals, ids = jax.block_until_ready((vals, ids))
             except BaseException as e:
                 self._watch_end(ticket.seq)
-                # Busy-clock write BEFORE the resolve and inside the
-                # lock: the resolve wakes quiesce(), and a generation
-                # rollover must not reset the clock between them.
-                with self._record_lock:
-                    self._scan_busy_s += time.perf_counter() - t0
-                    ticket._resolve(error=e)
+                times.add("await", span.close())
+                resolve(ticket, error=e)
                 return
             self._watch_end(ticket.seq)
-            self._scan_busy_s += time.perf_counter() - t0
-            # One critical section for resolve + record: the resolve is
-            # what wakes quiesce(), so a generation rollover waiting on
-            # _record_lock cannot slip in before the record.
-            with self._record_lock:
-                if ticket._resolve(value=(vals, ids)):
-                    self._stats.record(ticket)
+            times.add("await", span.close())
+            resolve(ticket, value=(vals, ids))
 
         while True:
             try:
@@ -931,22 +985,24 @@ class ServingPipeline:
                 if inflight:
                     await_oldest()
                     continue
-                t0 = time.perf_counter()
                 gen0 = self.generation
+                idle = Span("serving.scan_idle")
                 item = self._encoded.get()
-                # An idle wait that spans a new_generation() (the blocked
-                # get sat through a drain/rebuild window) belongs to no
+                waited = idle.close()
+                # A wait that spans a new_generation() (the blocked get
+                # sat through a drain/rebuild window) belongs to no
                 # generation: adding it would book the whole swap as the
-                # NEW generation's device idle time.
+                # NEW generation's wait for input.
                 if self.generation == gen0:
-                    self._scan_idle_s += time.perf_counter() - t0
+                    times.add("scan_input_wait", waited)
             if item is _SENTINEL:
                 break
-            ticket, codes = item
+            ticket, codes, handoff = item
             if ticket.expired():
                 # Shed at dequeue (same as the encode stage): the scan
                 # is the expensive step — expired work must never reach
                 # the device.
+                handoff.close()
                 self._shed_expired(ticket)
                 continue
             # Provenance at dispatch (single scan thread; the only
@@ -966,11 +1022,12 @@ class ServingPipeline:
             # anyway and a deeper window just hides dispatch latency).
             while len(inflight) >= self.config.dispatch_ahead:
                 await_oldest()
+            times.add("handoff", handoff.close())
             # Watchdog clock starts at dispatch: a hung search_fn blocks
             # right here, where this thread can no longer observe it.
             self._watch_begin(ticket.seq)
+            span = Span("serving.dispatch", ticket.trace_id)
             try:
-                t0 = time.perf_counter()
                 if self._scan_gate is not None:
                     # Co-located replicas take turns. JAX dispatch is
                     # async, so serialising the dispatch alone would
@@ -982,11 +1039,14 @@ class ServingPipeline:
                         vals, ids = jax.block_until_ready((vals, ids))
                 else:
                     vals, ids = self.search_fn(codes)  # async dispatch
-                self._scan_busy_s += time.perf_counter() - t0
             except BaseException as e:
+                times.add("dispatch", span.close())
                 self._watch_end(ticket.seq)
+                span = Span("serving.resolve", ticket.trace_id)
                 ticket._resolve(error=e)
+                times.add("resolve", span.close())
                 continue
+            times.add("dispatch", span.close())
             if req is not None and req.k is not None:
                 # Per-request truncation of the index's top-k (a lazy
                 # slice on the async result — no extra device sync).
@@ -999,17 +1059,15 @@ class ServingPipeline:
     # monitoring
     # ------------------------------------------------------------------
 
-    def latency_window(self) -> List[float]:
-        """Recent enqueue->reply latencies (seconds, bounded window) —
-        raw material for cross-replica percentile aggregation."""
-        return self._stats.window()
-
     def stats(self) -> dict:
-        """Throughput/latency/idle summary over completed requests.
+        """Throughput/latency summary over completed requests, and the
+        seconds and count of each stage (``spans.STAGES``).
 
         Percentiles come from a sliding window of the most recent
         completions (the counters are exact totals) so a long-running
-        pipeline's accounting stays O(1) in memory.
+        pipeline's accounting stays O(1) in memory. Stage totals cover
+        the current generation and are timed at the boundaries of the
+        stages' profiler spans.
         """
         with self._record_lock:  # one snapshot: a concurrent generation
             # rollover must not fold the window we just read into
@@ -1025,9 +1083,10 @@ class ServingPipeline:
             )
             watchdog_stalls = self.watchdog_stalls
             generation = self.generation
-            wall = self._scan_idle_s + self._scan_busy_s
-            idle = self._scan_idle_s
+            stages = self._times.snapshot()
         lat = sorted(lat)
+        scan = sum(stages[k]["seconds"]
+                   for k in ("scan_input_wait", "dispatch", "await"))
         return {
             # Scoped to the CURRENT index generation (post last swap or
             # revival); pre-swap totals live under lifetime_*.
@@ -1045,9 +1104,15 @@ class ServingPipeline:
             "watchdog_stalls": watchdog_stalls,
             "latency_p50_ms": 1e3 * _percentile(lat, 0.50),
             "latency_p99_ms": 1e3 * _percentile(lat, 0.99),
-            "device_idle_frac": idle / wall if wall > 0 else 0.0,
+            # Share of the scan stage's time (waiting for input,
+            # dispatching, awaiting results) it sat waiting for an
+            # encoded batch. Not device idle: the device may still be
+            # running an earlier dispatch, or idle while this thread
+            # dispatches.
+            "scan_input_wait_frac": (stages["scan_input_wait"]["seconds"]
+                                     / scan if scan > 0 else 0.0),
+            "stages": stages,
         }
-
 
 def _percentile(sorted_vals: List[float], p: float) -> float:
     if not sorted_vals:

@@ -349,10 +349,10 @@ def _replicated_row(replicas=2, paired_ratio=0.95, **overrides):
         "mode": "replicated", "replicas": replicas, "router": "round-robin",
         "qps": 950.0, "qps_ratio_vs_single": paired_ratio,
         "ms_per_batch": 1.0, "latency_p50_ms": 5.0, "latency_p99_ms": 9.0,
-        "device_idle_frac": 0.1, "shed": 0, "failovers": 0,
+        "scan_input_wait_frac": 0.1, "shed": 0, "failovers": 0,
         "per_replica": [
             {"replica": i, "requests": 10, "queries": 100, "shed": 0,
-             "device_idle_frac": 0.1, "generation": 0}
+             "scan_input_wait_frac": 0.1, "generation": 0}
             for i in range(replicas)
         ],
     }
@@ -488,7 +488,7 @@ def test_serving_gate_fails_on_missing_failover_count(tmp_path):
 
 def test_serving_gate_fails_on_incomplete_per_replica_entry(tmp_path):
     bench = _serving_bench(1.2)
-    del bench["rows"][3]["per_replica"][1]["device_idle_frac"]
+    del bench["rows"][3]["per_replica"][1]["scan_input_wait_frac"]
     out = _run_gate(tmp_path, bench)
     assert out.returncode != 0
     assert "per_replica[1]" in out.stderr

@@ -303,7 +303,7 @@ def test_stats_aggregate_per_replica_rows():
     assert sum(s["requests"] for s in stats["per_replica"]) == 8
     for s in stats["per_replica"]:
         for key in ("replica", "healthy", "requests", "queries", "shed",
-                    "device_idle_frac"):
+                    "scan_input_wait_frac", "stages"):
             assert key in s
     assert stats["latency_p99_ms"] >= stats["latency_p50_ms"]
 
