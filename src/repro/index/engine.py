@@ -38,7 +38,7 @@ from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.index.hnsw_lite import ShardedHNSW, hnsw_frontier_search
-from repro.kernels.sdc.defaults import BLOCK_N, BLOCK_Q, plan_for
+from repro.kernels.sdc.defaults import BLOCK_N, plan_for
 from repro.kernels.sdc.ops import resolve_backend, sdc_search, sdc_search_xla
 
 
@@ -52,14 +52,16 @@ def _leaf_scan(
     k: int,
     backend: str = "auto",
     packed: bool = False,
-    block_q: int = BLOCK_Q,
+    block_q: int | None = None,
     block_n: int = BLOCK_N,
 ) -> Tuple[jax.Array, jax.Array]:
     """Local exhaustive SDC scan + top-k on one leaf.
 
     Dispatches to the fused Pallas kernel (no [Q, shard_N] score matrix
     materialised) or the jnp fallback; both treat shard_inv == 0 entries
-    as excluded (drained docs) and surface empty slots as -inf.
+    as excluded (drained docs) and surface empty slots as -inf. The
+    kernel's query tile follows the request's row count
+    (``defaults.scan_block_q``) unless ``block_q`` is given.
     """
     backend = resolve_backend(backend)
     if backend in ("pallas", "interpret"):
@@ -95,7 +97,7 @@ def _make_search(
     shard_axes: Tuple[str, ...],
     backend: str,
     packed: bool,
-    block_q: int,
+    block_q: int | None,
     block_n: int,
     failover: bool,
 ):
@@ -146,7 +148,7 @@ def make_distributed_search(
     shard_axes: Tuple[str, ...] = ("data", "model"),
     backend: str = "auto",
     packed: bool = False,
-    block_q: int = BLOCK_Q,
+    block_q: int | None = None,
     block_n: int = BLOCK_N,
     block_plan=None,
 ):
@@ -158,9 +160,11 @@ def make_distributed_search(
       axis 0 across shard_axes, d_inv [N] f32 (same sharding).
     Output: (scores [Q, k], global ids [Q, k]) replicated.
 
-    ``block_plan`` (kind "scan", from ``launch/autotune``) overrides
-    ``block_q``/``block_n`` for every leaf's fused scan — tuned once
-    for the per-leaf shard size, applied mesh-wide.
+    ``block_q`` None sizes every leaf's query tile from the request
+    (``defaults.scan_block_q``). ``block_plan`` (kind "scan", from
+    ``launch/autotune``) overrides ``block_q``/``block_n`` for every
+    leaf's fused scan — tuned once for the per-leaf shard size, applied
+    mesh-wide.
     """
     plan = plan_for(block_plan, "scan")
     if plan is not None:
@@ -189,7 +193,7 @@ def make_failover_search(
     shard_axes: Tuple[str, ...] = ("data", "model"),
     backend: str = "auto",
     packed: bool = False,
-    block_q: int = BLOCK_Q,
+    block_q: int | None = None,
     block_n: int = BLOCK_N,
     block_plan=None,
 ):
@@ -348,7 +352,7 @@ def engine_search_from_snapshot(
     shard_axes: Tuple[str, ...] = ("data", "model"),
     backend: str = "auto",
     packed: bool = False,
-    block_q: int = BLOCK_Q,
+    block_q: int | None = None,
     block_n: int = BLOCK_N,
     prepared: Tuple[jax.Array, jax.Array] = None,
     rerank: dict | None = None,

@@ -23,7 +23,7 @@ from repro.core.binarize_lib import (
 )
 from repro.kernels.binary_dot.ops import binary_dot_search
 from repro.kernels.sdc import ref as sdc_ref
-from repro.kernels.sdc.defaults import BLOCK_N, FLAT_BLOCK_Q, BlockPlan, plan_for
+from repro.kernels.sdc.defaults import BLOCK_N, BlockPlan, plan_for
 from repro.kernels.sdc.ops import sdc_search_backend
 from repro.kernels.sdc.rerank import fine_inv_norms, sdc_rerank_backend
 
@@ -76,8 +76,11 @@ class FlatSDC:
 
     def search(
         self, q_codes: jax.Array, k: int, block_n: int = BLOCK_N,
-        block_q: int = FLAT_BLOCK_Q, block_plan: BlockPlan | None = None,
+        block_q: int | None = None, block_plan: BlockPlan | None = None,
     ):
+        """Top-k of ``q_codes`` over the corpus. The query tile follows
+        the request's row count (``defaults.scan_block_q``) unless
+        ``block_q`` or a scan ``block_plan`` sets it."""
         return sdc_search_backend(
             q_codes,
             self.codes,

@@ -1,12 +1,18 @@
 """Single source of truth for SDC kernel launch-shape defaults.
 
-Before this table existed the block sizes had quietly diverged:
-``sdc.py`` scanned with ``block_n=512`` while the fused ``sdc_topk``
-defaulted to 1024, and ``FlatSDC`` hard-coded a ``block_q=8`` query
-tile. Every un-tuned path now reads the same constants from here, and
-the block-plan autotuner (``launch/autotune.py``) uses this table as
-its fallback plan — a kernel signature that has never been tuned runs
-with exactly these shapes.
+Every un-tuned path reads its launch shapes from here, and the
+block-plan autotuner (``launch/autotune.py``) uses this table as its
+fallback plan — a kernel signature that has never been tuned runs with
+exactly these shapes.
+
+The fused scan's query tile is derived, not fixed: ``scan_block_q``
+sizes it from the request's row count (a static shape at trace time) as
+the smallest multiple of 8 sublanes that holds the request, capped at
+``BLOCK_Q`` rows. An 8-query request scans with an 8-row tile, and a
+128-query request with one 128-row tile, so the corpus streams through
+VMEM ``ceil(Q / BLOCK_Q)`` times a request. ``FlatSDC`` and the
+distributed engine's leaves both use the rule; an explicit ``block_q``
+or a tuned scan plan still wins.
 
 ``BlockPlan`` lives here (not in ``launch/``) so the kernel layer can
 accept plans without importing the launch layer. A plan is a plain
@@ -55,13 +61,21 @@ class BlockPlan(NamedTuple):
 # lanes); TQ=128, TN=512 keeps a (TN, D<=2048) int8 tile under 1 MiB of
 # VMEM. The fused top-k kernel historically defaulted to TN=1024 — that
 # divergence is gone; anything wanting 1024 now asks the autotuner.
+# BLOCK_Q is also the cap of the derived query tile (``scan_block_q``).
 BLOCK_Q = 128
 BLOCK_N = 512
 
-# FlatSDC serves small online query batches; a full 128-row query tile
-# would be >90% padding at serving batch sizes, so its per-call default
-# query tile is one f32 sublane.
-FLAT_BLOCK_Q = 8
+
+def scan_block_q(q_rows: int) -> int:
+    """The fused scan's query tile for a request of ``q_rows`` queries.
+
+    The smallest multiple of 8 (one f32 sublane group) that holds the
+    request, capped at ``BLOCK_Q``: the corpus streams through VMEM once
+    per query tile, so fewer, taller tiles save whole corpus passes,
+    while a tile taller than the request only pads it.
+    """
+    return min(-(-max(q_rows, 1) // 8) * 8, BLOCK_Q)
+
 
 # Gather kernel query tile: one f32 sublane group. Each (query, probe)
 # grid step scores its list against the whole tile (the MXU pass costs

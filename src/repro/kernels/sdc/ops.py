@@ -25,7 +25,7 @@ from repro.core.binarize_lib import (
     unpack_nibble_planes,
 )
 from repro.kernels.sdc import ref as sdc_ref_mod
-from repro.kernels.sdc.defaults import BLOCK_N, BLOCK_Q, BlockPlan
+from repro.kernels.sdc.defaults import BLOCK_N, BlockPlan, scan_block_q
 from repro.kernels.sdc.sdc import sdc_scores, sdc_topk
 
 NEG_INF = SDC_NEG_INF
@@ -69,7 +69,7 @@ def sdc_search(
     *,
     n_levels: int,
     k: int,
-    block_q: int = BLOCK_Q,
+    block_q: int | None = None,
     block_n: int = BLOCK_N,
     interpret: bool = False,
     fused: bool = True,
@@ -82,6 +82,8 @@ def sdc_search(
       d_codes: [N, D] int8 codes of documents, or nibble-packed uint8
         [N, D//2] when ``packed=True``.
       d_inv_norm: [N] f32 reciprocal doc-value norms (0 => excluded).
+      block_q: query tile; None derives it from Q
+        (``defaults.scan_block_q``).
       fused: use the fused scan+top-k kernel (no [Q, N] materialisation).
 
     Returns:
@@ -89,6 +91,8 @@ def sdc_search(
       (padding, excluded docs, k > N) come back as (SDC_NEG_INF, -1).
     """
     Q0 = q_codes.shape[0]
+    if block_q is None:
+        block_q = scan_block_q(Q0)
     # The fused kernel tiles the running top-k against its N block, so the
     # effective block must hold k entries; keep it a multiple of block_n so
     # lane alignment survives. N is padded against the same effective block
@@ -175,16 +179,18 @@ def sdc_search_xla(
 
 def sdc_search_backend(
     q_codes, d_codes, d_inv_norm, *, n_levels, k, backend="auto",
-    block_q=BLOCK_Q, block_n=BLOCK_N, packed=False,
+    block_q=None, block_n=BLOCK_N, packed=False,
     block_plan: BlockPlan | None = None,
 ):
     """Dispatch a top-k SDC search to the resolved backend.
 
-    ``block_plan`` (a ``defaults.BlockPlan``, e.g. from the
-    ``launch/autotune`` sweep) overrides ``block_q``/``block_n`` when
-    given. Blocks only shape the kernel launch — scores and ids are
-    bit-identical across every block choice — so a plan is always safe
-    to apply. The "xla" backend has no tiles; plans are inert there.
+    ``block_q`` None sizes the query tile from the request
+    (``defaults.scan_block_q``). ``block_plan`` (a ``defaults.BlockPlan``,
+    e.g. from the ``launch/autotune`` sweep) overrides
+    ``block_q``/``block_n`` when given. Blocks only shape the kernel
+    launch — scores and ids are bit-identical across every block choice —
+    so a plan is always safe to apply. The "xla" backend has no tiles;
+    plans are inert there.
     """
     backend = resolve_backend(backend)
     if block_plan is not None:

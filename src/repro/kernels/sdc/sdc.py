@@ -376,11 +376,15 @@ def sdc_topk(
     ]
     inv_row = d_inv_norm.reshape(1, N)
     kw = dict(n_levels=n_levels, dim=D, k=k, block_n=block_n)
+    # Named by its query tile, so a device trace shows the tile it ran:
+    # the corpus streams Q // block_q times a call.
+    name = f"sdc_topk_q{block_q}"
     if packed:
         qe, qo = _split_queries(q_codes)
         vals, idx = pl.pallas_call(
             functools.partial(_sdc_topk_kernel_packed, **kw),
             grid=grid,
+            name=name,
             in_specs=[
                 pl.BlockSpec((block_q, D // 2), lambda i, j: (i, 0)),
                 pl.BlockSpec((block_q, D // 2), lambda i, j: (i, 0)),
@@ -394,6 +398,7 @@ def sdc_topk(
         vals, idx = pl.pallas_call(
             functools.partial(_sdc_topk_kernel, **kw),
             grid=grid,
+            name=name,
             in_specs=[pl.BlockSpec((block_q, D), lambda i, j: (i, 0)), *d_specs],
             out_specs=out_specs,
             out_shape=out_shape,
