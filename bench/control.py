@@ -8,8 +8,10 @@ reference computed one precision step below the configuration's (the
 encoder's float32 matmuls at ``high``, three bfloat16 passes, instead of
 ``highest``; scores rounded to bfloat16 before ranking, instead of
 float32), and prints the numbers the check compares, one JSON line a
-seed. Every limit sits below what the control reads: the control has to
-come out not correct. The benchmark's own runs never run this.
+seed. The reference answers as the index family promises: its own
+``refs/<family>.py`` where it has one, else the whole corpus's top-k.
+Every limit sits below what the control reads: the control has to come
+out not correct. The benchmark's own runs never run this.
 """
 
 from __future__ import annotations
@@ -47,10 +49,14 @@ def control_numbers(name: str, seed: int, overrides=None) -> dict:
     queries = np.concatenate([r.queries for r in picked])
     codes = np.asarray(reference.encode(params, state, jnp.asarray(queries),
                                         precision="high"))
-    none = -np.ones((codes.shape[0], cfg["k"]), np.int64)
-    scores, ids, _ = reference.exact_search(
-        codes, none, corpus.chunks(), n_levels=b["n_levels"], k=cfg["k"],
-        n_docs=cfg["n_docs"], round_bf16=True)
+    family = registry.reference(cell.family)
+    if family is not None:
+        scores, ids = family.expected(cfg, codes, corpus, round_bf16=True)
+    else:
+        none = -np.ones((codes.shape[0], cfg["k"]), np.int64)
+        scores, ids, _ = reference.exact_search(
+            codes, none, corpus.chunks(), n_levels=b["n_levels"], k=cfg["k"],
+            n_docs=cfg["n_docs"], round_bf16=True)
     numbers, _, parts = harness.compare(cell, (params, state), corpus,
                                         queries, codes, scores, ids)
     limits = cfg["limits"]

@@ -1,7 +1,9 @@
 """One run of one cell: set-up, the measured window, the check, the result.
 
 Set-up draws the binarizer weights and the corpus from the seed on the
-device, builds the index with the configuration's ``lifecycle`` builder,
+device, hands the corpus snapshot to the configuration's ``lifecycle``
+builder on the device or, with ``"snapshot": "host"``, as a read-only
+memmap in a directory that lives as long as the run, builds the index,
 places the served encoder and search behind a one-replica
 ``proxy.QueryRouter`` at the program's default ``ServingConfig``, and
 warms the cell's own batch shape. The window offers the cell's traffic
@@ -14,6 +16,7 @@ from __future__ import annotations
 import dataclasses
 import gc
 import json
+import os
 import shutil
 import sys
 import tempfile
@@ -123,18 +126,43 @@ def deployment(cfg: dict):
     return params, state, data.Corpus(seed, cfg, params, state)
 
 
-def setup(cell, seed: int, backend: str) -> Served:
+def spill_codes(corpus, code_dim: int, path: str) -> np.ndarray:
+    """The corpus codes in a read-only memmap at ``path``, written chunk
+    by chunk: no copy of the whole corpus is ever on the device."""
+    shape = (corpus.n_docs, code_dim)
+    out = np.memmap(path, dtype=np.int8, mode="w+", shape=shape)
+    for start, codes in corpus.chunks():
+        out[start:start + codes.shape[0]] = np.asarray(codes)
+    out.flush()
+    del out
+    return np.memmap(path, dtype=np.int8, mode="r", shape=shape)
+
+
+def setup(cell, seed: int, backend: str, spill_dir: str) -> Served:
+    """Draw and build the cell's deployment; a host snapshot is written
+    under ``spill_dir``, which the caller removes when the run ends."""
     import jax
 
     from repro.core import binarize_lib
     from repro.launch.lifecycle import CorpusSnapshot
 
     cfg = cell.config
+    where = cfg.get("snapshot", "device")
+    if where not in ("device", "host"):
+        raise ValueError(f"{cell.name}: snapshot {where!r} is neither "
+                         f"'device' nor 'host'")
     t = time.perf_counter()
     params, state, corpus = deployment(cfg)
-    codes = jax.block_until_ready(corpus.all_codes())
+    if where == "device":
+        codes = jax.block_until_ready(corpus.all_codes())
+    else:
+        # A deployment writes its cold tier at every restart, so the
+        # spill is set-up.
+        codes = spill_codes(corpus, cfg["binarizer"]["code_dim"],
+                            os.path.join(spill_dir, "codes.int8"))
     log(f"[setup] weights and {corpus.n_docs} corpus codes drawn on the "
-        f"device in {time.perf_counter() - t:.2f} s")
+        f"device, snapshot on the {where}, in "
+        f"{time.perf_counter() - t:.2f} s")
     t = time.perf_counter()
     snapshot = CorpusSnapshot(codes=codes, n_levels=cfg["binarizer"]["n_levels"])
     search = make_search(cfg, snapshot, backend)
@@ -160,14 +188,26 @@ def setup(cell, seed: int, backend: str) -> Served:
                   weights=(params, state), corpus=corpus, pool=pool)
 
 
-def device_bytes(search, q_codes) -> float:
+class UntraceableSearch(RuntimeError):
+    """The served search is not one program that JAX can trace."""
+
+
+def device_bytes(search, q_codes, cell_name: str) -> float:
     """Bytes on the chips of the served search at this batch shape: the
     device arrays it closes over plus the temporaries of its compiled
     program on every chip."""
     import jax
     from jax.extend import core as jex_core
 
-    closed = jax.make_jaxpr(search)(q_codes)
+    try:
+        closed = jax.make_jaxpr(search)(q_codes)
+    except (jax.errors.JAXTypeError, jax.errors.JAXIndexError) as e:
+        raise UntraceableSearch(
+            f"{cell_name}: the served search cannot be traced "
+            f"({type(e).__name__}), so its device bytes cannot be counted. "
+            f"The served search must be one traceable program, with its "
+            f"host reads inside it (for example a host callback, "
+            f"jax.pure_callback).") from e
     arrays = [c for c in closed.consts if isinstance(c, jax.Array)]
     resident = sum(s.data.nbytes for a in arrays for s in a.addressable_shards)
 
@@ -227,13 +267,22 @@ def compare(cell, weights, corpus, queries, codes, scores, ids):
     from bench import reference
 
     cfg = cell.config
+    limits = cfg["limits"]
     n_levels = cfg["binarizer"]["n_levels"]
     margin = reference.code_margin(*weights, queries, codes, n_levels)
     top_v, top_i, ref_served = reference.exact_search(
         codes, ids, corpus.chunks(), n_levels=n_levels, k=cfg["k"],
         n_docs=cfg["n_docs"])
+    # The limits name what the configuration promises. A family with a
+    # reference of its own promises its top-k; every served score, the
+    # order and the recall stay against the whole corpus.
+    family = registry.reference(cell.family)
+    promised = None
+    if family is not None and "rank_gap" in limits:
+        promised, _ = family.expected(cfg, codes, corpus)
     found = reference.compare(scores, ids, top_v, ref_served,
-                              exact=cell.family != "ivf")
+                              rank="rank_gap" in limits,
+                              order="order_gap" in limits, promised=promised)
     parts = {"code_margin": margin, "score_err": found.pop("score_err")}
     # One number for the precision of an answer: a code bit on the wrong
     # side of zero and a score off the reference's are both relative
@@ -241,8 +290,9 @@ def compare(cell, weights, corpus, queries, codes, scores, ids):
     numbers = {"answer_err": max(parts.values()), **found}
     recall = float(np.mean([len(set(a) & set(b)) / len(a)
                             for a, b in zip(top_i.tolist(), ids.tolist())]))
-    if cell.family == "ivf":
-        # Which lists were probed shows only in what the answers miss.
+    if "recall_miss" in limits:
+        # Which documents were searched shows only in what the answers
+        # miss.
         numbers["recall_miss"] = 1.0 - recall
     return numbers, recall, parts
 
@@ -350,24 +400,36 @@ def run(name: str, seed: int, seconds: float, traced: bool, *,
     """
     import jax
 
-    from bench import traffic
-    from repro.launch import proxy, serving
-
     cell = registry.cell(name)
     cell.config.update(overrides or {})
-    cfg = cell.config
     compiles = CompileCounter()
     devices = jax.devices()
     kind = devices[0].device_kind
     peaks = registry.peaks(kind) if traced else None
 
-    served = setup(cell, seed, backend)
+    with tempfile.TemporaryDirectory(prefix="bench-snapshot-") as spill_dir:
+        served = setup(cell, seed, backend, spill_dir)
+        return _serve(cell, served, seed, seconds, traced, t_start=t_start,
+                      compiles=compiles, devices=devices, peaks=peaks,
+                      wrap_search=wrap_search, emit=emit)
+
+
+def _serve(cell, served: Served, seed: int, seconds: float, traced: bool, *,
+           t_start: float, compiles: CompileCounter, devices, peaks,
+           wrap_search: Optional[Callable], emit: Callable[[str], None]):
+    """The rest of a run after set-up has drawn and built ``served``."""
+    from bench import traffic
+    from repro.launch import proxy, serving
+
+    cfg = cell.config
+    kind = devices[0].device_kind
     search = wrap_search(served.search) if wrap_search else served.search
     batch = cell.mix["batch"]
     warm = [served.pool[:batch]]
     serving.warmup_replicas([(served.encode, search)], warm)
     q_codes = served.encode(warm[0])
-    bytes_per_doc = device_bytes(served.search, q_codes) / cfg["n_docs"]
+    bytes_per_doc = (device_bytes(served.search, q_codes, cell.name)
+                     / cfg["n_docs"])
     served.codes_of.clear()
     router = proxy.QueryRouter(proxy.ReplicaSet([(served.encode, search)]))
     # What set-up left live is never garbage again: a full collection over

@@ -15,6 +15,10 @@ It follows the published semantics of BEBR (arXiv:2302.08714, §3.2.1 and
 * the comparison of every checked answer with those, reduced to the
   numbers that decide ``correct``.
 
+An index family whose promise is not the whole corpus's exact top-k
+(a two-tier index reranks only its coarse scan's survivors) states its
+own in ``refs/<family>.py``, built on the functions here.
+
 The corpus codes are data the benchmark makes from the seed (``data.py``
 encodes them with ``encode`` below), never codes the program made.
 """
@@ -248,14 +252,18 @@ def exact_search(q_codes, served_ids, corpus_chunks, *, n_levels, k,
 # ---------------------------------------------------------------------------
 
 
-def compare(served_scores, served_ids, ref_top, ref_served, *, exact: bool):
+def compare(served_scores, served_ids, ref_top, ref_served, *,
+            rank: bool, order: bool, promised=None):
     """The numbers compared with their limits, from one set of answers.
 
     served_scores / served_ids [Q, k]: what the program answered.
     ref_top [Q, k]: the reference's exact top-k scores, descending.
     ref_served [Q, k]: the reference's exact score of each served id.
-    exact: the index promises the exact top-k (flat, sharded flat); an
-    approximate index (IVF) promises exact scores in descending order.
+    rank: the configuration promises a top-k (``rank_gap``); order: it
+    promises exact scores in descending order (``order_gap``).
+    promised [Q, k]: the exact scores, descending, of the top-k that the
+    index family promises (its ``refs/<family>.py``), where that is not
+    the whole corpus's; ``rank_gap`` is then taken against it.
 
     Returns {name: value}; every value is a share of the query's best
     reference score, so it does not depend on the scale of the scores.
@@ -274,12 +282,23 @@ def compare(served_scores, served_ids, ref_top, ref_served, *, exact: bool):
                    / scale)
     out = {"score_err": float(err.max())}
     rs = np.where(bad, -np.inf, ref_served)
-    if exact:
-        worst = np.min(rs, axis=1)
-        gap = (ref_top[:, -1] - worst) / scale[:, 0]
+    if rank:
+        if promised is None:
+            # Nothing lies above the whole corpus's top-k: an answer can
+            # only fall short of it.
+            gap = (ref_top[:, -1] - np.min(rs, axis=1)) / scale[:, 0]
+        else:
+            # A family's top-k is taken over part of the corpus (the
+            # survivors of a coarse scan): an answer from outside that
+            # part breaks the promise as much as one below it, so the
+            # served scores must be the promised ones, rank by rank.
+            got = -np.sort(-rs, axis=1)
+            want = np.asarray(promised, np.float64)
+            off = np.where(got == want, 0.0, np.abs(got - want))
+            gap = np.max(off, axis=1) / scale[:, 0]
         gap = np.where(np.isfinite(gap), gap, 1.0)
         out["rank_gap"] = max(0.0, float(np.max(gap)))
-    else:
+    if order:
         step = rs[:, 1:] - rs[:, :-1]
         step = np.where(np.isfinite(step), step, 1.0)
         out["order_gap"] = max(0.0, float(np.max(step / scale)))

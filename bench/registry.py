@@ -4,13 +4,24 @@ Adding a configuration, a traffic mix, a cell or a per-layer metric means
 adding files, never editing one:
 
 * ``configs/<config>.json``: one deployment (``BENCHMARK.json`` names the
-  file of each configuration);
+  file of each configuration). Its ``limits`` name the numbers the check
+  compares, and so what the deployment promises: ``rank_gap`` the exact
+  top-k, ``order_gap`` exact scores in descending order, ``recall_miss``
+  the share of the whole corpus's top-k that the answers miss. Its
+  ``snapshot`` says where the corpus snapshot handed to the builder
+  lives: ``"device"`` (the default) or ``"host"``, a read-only memmap;
 * ``traffic/<mix>.json``: one traffic mix, read by ``traffic.py``, with
   an open loop's fixed rate;
 * ``metrics/<metric>.py``: the reader of one per-layer metric, with a
   ``read(ctx)`` function; a metric named ``<base>.<suffix>`` uses
   ``metrics/<base>.py`` when it has no file of its own;
 * ``work/<family>.py``: the least work of one request of an index family;
+* ``refs/<family>.py``: where an index family promises another top-k
+  than the whole corpus's, that promise: ``expected(cfg, q_codes, corpus,
+  *, round_bf16=False) -> (scores [Q, k], ids [Q, k])`` in plain numpy
+  and jax.numpy, importing nothing of the program. ``rank_gap`` and the
+  lower-precision control take it; without the file the family promises
+  the whole corpus's exact top-k;
 * ``peaks.json``: the peak rates of each device kind.
 """
 
@@ -100,6 +111,15 @@ def work(family: str, root: str = ROOT):
     if not os.path.exists(path):
         raise KeyError(f"no work module for index family {family!r}")
     return _load(path, "bench_work_" + family)
+
+
+def reference(family: str, root: str = ROOT):
+    """The module of ``refs/<family>.py`` (``expected``), or None where the
+    family has no reference of its own."""
+    path = os.path.join(root, "bench", "refs", family + ".py")
+    if not os.path.exists(path):
+        return None
+    return _load(path, "bench_ref_" + family)
 
 
 def peaks(device_kind: str, root: str = ROOT) -> Dict[str, float]:

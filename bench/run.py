@@ -49,8 +49,12 @@ def main(argv=None) -> int:
     harness.log(f"[device] platform={devices[0].platform} "
                 f"kind={devices[0].device_kind} count={len(devices)}")
     harness.log(f"[compile-cache] {harness.enable_compile_cache()}")
-    harness.run(args.workload, args.seed, args.seconds, bool(args.trace),
-                t_start=T_START)
+    try:
+        harness.run(args.workload, args.seed, args.seconds, bool(args.trace),
+                    t_start=T_START)
+    except harness.UntraceableSearch as e:
+        harness.log(f"bench: {e}")
+        return 1
     return 0
 
 

@@ -18,6 +18,7 @@ import gc
 import json
 import os
 import sys
+import tempfile
 import time
 
 T_START = time.perf_counter()
@@ -46,29 +47,30 @@ def main(argv=None) -> int:
         return 2
     harness.enable_compile_cache()
     cell = registry.cell(args.workload)
-    served = harness.setup(cell, args.seed, "pallas")
-    warm = [served.pool[:cell.mix["batch"]]]
-    serving.warmup_replicas([(served.encode, served.search)], warm)
-    gc.collect()
-    gc.freeze()  # as a run does at the end of its set-up
-    for rate in args.rates:
-        router = proxy.QueryRouter(
-            proxy.ReplicaSet([(served.encode, served.search)]))
-        reqs, late, _ = traffic.run_open(router, served.pool, cell.mix, rate,
-                                         args.seconds, args.seed)
-        router.close()
-        served.codes_of.clear()
-        lat = np.array([r.latency for r in reqs if r.done is not None])
-        fifth = max(1, len(lat) // 5)
-        print(json.dumps({
-            "rate_per_s": rate, "requests": len(reqs),
-            "answered": int(len(lat)),
-            "p50_ms": 1e3 * float(np.percentile(lat, 50)),
-            "p95_ms": 1e3 * harness.percentile(list(lat), 95),
-            "max_ms": 1e3 * float(lat.max()),
-            "backlog_growth_ms": 1e3 * float(lat[-fifth:].mean()
-                                             - lat[:fifth].mean()),
-            "generator_late_ms": 1e3 * late}), flush=True)
+    with tempfile.TemporaryDirectory(prefix="bench-snapshot-") as spill_dir:
+        served = harness.setup(cell, args.seed, "pallas", spill_dir)
+        warm = [served.pool[:cell.mix["batch"]]]
+        serving.warmup_replicas([(served.encode, served.search)], warm)
+        gc.collect()
+        gc.freeze()  # as a run does at the end of its set-up
+        for rate in args.rates:
+            router = proxy.QueryRouter(
+                proxy.ReplicaSet([(served.encode, served.search)]))
+            reqs, late, _ = traffic.run_open(router, served.pool, cell.mix,
+                                             rate, args.seconds, args.seed)
+            router.close()
+            served.codes_of.clear()
+            lat = np.array([r.latency for r in reqs if r.done is not None])
+            fifth = max(1, len(lat) // 5)
+            print(json.dumps({
+                "rate_per_s": rate, "requests": len(reqs),
+                "answered": int(len(lat)),
+                "p50_ms": 1e3 * float(np.percentile(lat, 50)),
+                "p95_ms": 1e3 * harness.percentile(list(lat), 95),
+                "max_ms": 1e3 * float(lat.max()),
+                "backlog_growth_ms": 1e3 * float(lat[-fifth:].mean()
+                                                 - lat[:fifth].mean()),
+                "generator_late_ms": 1e3 * late}), flush=True)
     return 0
 
 

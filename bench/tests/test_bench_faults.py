@@ -114,6 +114,21 @@ def test_a_broken_ivf_search_is_not_correct(fault):
     assert not res["correct"], res["checks"]
 
 
+def test_a_sound_ivf_online_run_is_correct():
+    res = _run("web-ivf.online", overrides=IVF_SMALL)
+    assert res["correct"], res["checks"]
+    assert res["failed"] == 0 and res["attempted"] > 0
+    assert "recall_miss" in res["checks"]
+
+
+@pytest.mark.parametrize("fault", [altered_answer, half_batch, stale_state,
+                                   fails_a_request])
+def test_a_broken_ivf_online_search_is_not_correct(fault):
+    # The gather kernel's 8-query requests, the faults of the flat cell.
+    res = _run("web-ivf.online", wrap=fault, overrides=IVF_SMALL)
+    assert not res["correct"], res["checks"]
+
+
 @pytest.mark.parametrize("cell,overrides", [("web-flat.online", SMALL),
                                             ("web-ivf.bulk", IVF_SMALL)])
 def test_the_lower_precision_control_is_not_correct(cell, overrides):

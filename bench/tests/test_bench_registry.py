@@ -30,6 +30,17 @@ def test_every_cell_of_the_benchmark_resolves():
             registry.metric_reader(m["name"])
         if cell.mix["loop"] == "open":
             assert cell.mix["rate_per_s"] > 0
+    # The online IVF cell reports the online latency and every per-layer
+    # metric of an online cell, and not the recall of the bulk IVF cell.
+    ivf = registry.cell("web-ivf.online")
+    assert (ivf.family, ivf.chips, ivf.mix["batch"]) == ("ivf", 1, 8)
+    assert {m["name"] for m in ivf.end_to_end} == {
+        "latency_p95_ms", "device_bytes_per_doc", "setup_s"}
+    assert {m["name"] for m in ivf.per_layer} == {
+        n + ".online" for n in (
+            "search_roofline", "device_idle", "encode_ms",
+            "admission_wait_ms", "router_ms", "encode_host_ms", "handoff_ms",
+            "dispatch_ms", "resolve_ms")}
 
 
 def test_peaks_know_the_v5e_and_refuse_other_devices():
