@@ -370,16 +370,20 @@ def layer_context(cell, served, tr, reqs, t0, seconds, peaks):
 
 
 def breakdown(ctx) -> dict:
+    """The ops that took most device time, under names that hold from
+    compile to compile, and the longest idle gaps by what the host did."""
     from bench import trace
 
     lo, hi = ctx.window
-    by_name = trace.time_by_name(ctx.trace.ops, lo, hi)
+    by_name: Dict[str, float] = {}
+    for name, s in trace.time_by_name(ctx.trace.ops, lo, hi).items():
+        k = trace.base(name)
+        by_name[k] = by_name.get(k, 0.0) + s
     top = sorted(by_name.items(), key=lambda kv: -kv[1])[:10]
     idle = trace.gaps([iv for b in ctx.busy.values() for iv in b], lo, hi)
-    host = [h for h in ctx.trace.host if h.name.startswith("bench.")
-            and h.name != trace.SYNC]
+    gaps = trace.label_gaps(idle, ctx.trace.host)
     return {"device_ops": [[k, v] for k, v in top],
-            "idle_gaps": [[k, v] for k, v in trace.label_gaps(idle, host)]}
+            "idle_gaps": [[k, v] for k, v in gaps]}
 
 
 # ---------------------------------------------------------------------------

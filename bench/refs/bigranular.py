@@ -1,7 +1,6 @@
 """What a two-tier flat index promises, as a family's own reference.
 
-The tests plant this file as ``bench/refs/bigranular.py`` under a
-temporary root. A two-tier index (BEBR arXiv:2302.08714 §3.2.1, a level
+A two-tier index (BEBR arXiv:2302.08714 §3.2.1, a level
 prefix of a code is a code at fewer bits; Xiao et al. arXiv:2201.05409)
 scans the corpus at ``coarse_levels`` levels for ``k_coarse`` survivors
 and reranks them at full levels: its answer is the full-level top-k of

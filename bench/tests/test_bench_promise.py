@@ -196,22 +196,71 @@ def _run(overrides, wrap=None, emit=None):
                        emit=emit or (lambda line: None))
 
 
-def _plant_bigranular(monkeypatch, tmp_path):
-    with open(os.path.join(ROOT, "bench", "tests", "bigranular_ref.py")) as f:
-        _plant(monkeypatch, tmp_path, "bigranular", f.read())
-
-
 @pytest.mark.parametrize("broken", [False, True])
-def test_a_two_tier_run_is_checked_against_its_own_reference(
-        tmp_path, monkeypatch, broken):
+def test_a_two_tier_run_is_checked_against_its_own_reference(broken):
     from bench.tests.test_bench_faults import altered_answer
 
-    _plant_bigranular(monkeypatch, tmp_path)
+    assert registry.reference("bigranular") is not None
     res = _run(BIGRANULAR, wrap=altered_answer if broken else None)
     assert res["correct"] is (not broken), res["checks"]
     if not broken:
         assert res["failed"] == 0
         assert res["checks"]["rank_gap"]["value"] == 0.0
+
+
+class _Codes:
+    """A corpus of given codes, yielded in chunks of rows as
+    ``data.Corpus.chunks`` yields them."""
+
+    def __init__(self, codes, rows):
+        self.codes, self.rows = codes, rows
+
+    def chunks(self):
+        for start in range(0, len(self.codes), self.rows):
+            yield start, self.codes[start:start + self.rows]
+
+
+def _brute_two_tier(q_codes, docs, n_levels, coarse_levels, k_coarse, k):
+    """Each query's coarse top-``k_coarse`` at ``coarse_levels`` levels,
+    reranked at full levels, by sorting every score: highest score first,
+    lowest id first among equal scores."""
+    def scores(qc, dc, levels):
+        a, beta = 2.0 ** (2 - levels), -(2.0 - 2.0 ** (1 - levels))
+        qv, dv = qc * a + beta, dc * a + beta
+        # Grid values are multiples of 1/8: the sums are exact, and the
+        # float32 rounding is the reference's.
+        norm = np.sqrt(np.sum(dv * dv, axis=1).astype(np.float32))
+        return (qv @ dv.T).astype(np.float32) / norm
+
+    q_codes, docs = q_codes.astype(np.int64), docs.astype(np.int64)
+    shift = n_levels - coarse_levels
+    coarse = scores(q_codes >> shift, docs >> shift, coarse_levels)
+    out_v, out_i = [], []
+    for q, row in zip(q_codes, coarse):
+        survivors = sorted(range(len(docs)), key=lambda d: (-row[d], d))
+        survivors = survivors[:k_coarse]
+        fine = scores(q[None], docs[survivors], n_levels)[0]
+        best = sorted(zip(survivors, fine), key=lambda t: (-t[1], t[0]))[:k]
+        out_i.append([d for d, _ in best])
+        out_v.append([v for _, v in best])
+    return np.array(out_v, np.float32), np.array(out_i)
+
+
+def test_the_bigranular_reference_is_a_brute_force_two_tier_ranking():
+    rng = np.random.default_rng(SEED)
+    docs = rng.integers(0, 16, (96, 8)).astype(np.int8)
+    # Every document twice, the copy at a higher id: ties at every rank.
+    docs[48:] = docs[:48]
+    q_codes = rng.integers(0, 16, (6, 8)).astype(np.int8)
+    cfg = {"binarizer": {"n_levels": 4}, "k": 7, "n_docs": 96,
+           "index": {"params": {"coarse_levels": 2, "k_coarse": 20}}}
+    ref = registry.reference("bigranular")
+    v, i = ref.expected(cfg, q_codes, _Codes(docs, 32))
+    want_v, want_i = _brute_two_tier(q_codes, docs, 4, 2, 20, 7)
+    np.testing.assert_array_equal(i, want_i)
+    np.testing.assert_array_equal(v, want_v)
+    # The ties reached the answers.
+    assert any(len(set(row)) < len(row) for row in v.tolist())
 
 
 def _record_snapshots(monkeypatch):
@@ -247,16 +296,27 @@ def test_a_host_snapshot_is_a_memmap_of_the_same_corpus(monkeypatch):
     assert not isinstance(seen[0].codes, np.ndarray)
 
 
-def test_an_untraceable_search_fails_the_run_with_no_result(
-        tmp_path, monkeypatch):
-    # A two-tier index over a host snapshot gathers its fine tier on the
-    # host between two device programs.
-    _plant_bigranular(monkeypatch, tmp_path)
+def test_an_untraceable_search_fails_the_run_with_no_result(monkeypatch):
+    import jax.numpy as jnp
+
     seen = _record_snapshots(monkeypatch)
+    build = harness.make_search
+
+    def untraceable(cfg, snapshot, backend):
+        # The configuration's own search, with its ids read back to the
+        # host between two device programs.
+        search = build(cfg, snapshot, backend)
+
+        def fn(q):
+            scores, ids = search(q)
+            return scores, jnp.asarray(np.asarray(ids))
+        return fn
+
+    monkeypatch.setattr(harness, "make_search", untraceable)
     printed = []
     with pytest.raises(harness.UntraceableSearch,
                        match="web-flat.online.*one traceable program"):
-        _run(dict(BIGRANULAR, snapshot="host"), emit=printed.append)
+        _run(dict(SMALL, snapshot="host"), emit=printed.append)
     assert printed == []
     assert not os.path.exists(os.path.dirname(seen[0].codes.filename))
 

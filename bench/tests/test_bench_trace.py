@@ -10,7 +10,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__)))))
 
 from bench import registry, trace  # noqa: E402
-from bench.harness import LayerContext  # noqa: E402
+from bench.harness import LayerContext, breakdown  # noqa: E402
 
 Op = trace.Op
 
@@ -38,8 +38,9 @@ def test_modules_are_assigned_by_execution():
     assert ops[1].run == 2.0 and ops[3].run == 2.0
 
 
-def _ctx(ops, modules, inflight, window, n_requests, least_s=0.0, chips=1):
-    tr = trace.Trace(ops=ops, modules=modules, host=[], offset=0.0)
+def _ctx(ops, modules, inflight, window, n_requests, least_s=0.0, chips=1,
+         host=()):
+    tr = trace.Trace(ops=ops, modules=modules, host=list(host), offset=0.0)
     trace.assign_modules(tr.ops, tr.modules)
     busy = trace.busy_by_device(tr.ops, *window)
     busy_s = sum(trace.length(b) for b in busy.values()) / max(1, len(busy))
@@ -132,3 +133,53 @@ def test_op_names_lose_their_hlo_text():
            Op(0, 1.0, 1.25, "fusion.2")]
     assert trace.time_by_name(ops, 0.0, 2.0) == {"copy.4": 1.5,
                                                  "fusion.2": 0.25}
+
+
+def test_the_breakdown_sums_the_ops_of_one_name_whatever_their_number():
+    ops = [Op(0, 0.0, 1.0, "%copy.4 = u8[8,64]{1,0} copy(u8[8,64] %d)"),
+           Op(0, 1.0, 1.5, "copy.27"),
+           Op(0, 2.0, 4.0, "sdc_topk_q128.1"),
+           Op(0, 4.0, 4.25, "fusion"), Op(0, 4.25, 4.375, "fusion.38")]
+    ctx = _ctx(ops, [], [(0.0, 5.0)], (0.0, 5.0), n_requests=1)
+    assert breakdown(ctx)["device_ops"] == [
+        ["sdc_topk_q128", 2.0], ["copy", 1.5], ["fusion", 0.375]]
+
+
+def test_idle_gaps_are_named_by_the_program_span_over_them():
+    # The device runs [0, 2], [3, 5], [6, 7] and [9, 10]: idle 2..3, 5..6
+    # and 7..9.
+    ops = [Op(0, 0.0, 2.0, "scan"), Op(0, 3.0, 5.0, "scan"),
+           Op(0, 6.0, 7.0, "scan"), Op(0, 9.0, 10.0, "scan")]
+    span = Op
+    host = [
+        # One request over [0, 6], queued until 2 and dispatched at 2.
+        span(-1, 0.0, 6.0, "proxy.request"),
+        span(-1, 0.0, 6.0, "bench.await"),
+        span(-1, 1.0, 2.0, "serving.queued"),
+        span(-1, 2.0, 3.0, "serving.dispatch"),
+        # Runtime events are not the program's spans.
+        span(-1, 2.0, 3.0, "PjitFunction(search)"),
+        span(-1, 5.0, 6.0, "dot_general.1"),
+        span(-1, 6.0, 10.0, "bench.sleep"),
+        span(-1, 0.0, 10.0, "bench.sync"),
+    ]
+    ctx = _ctx(ops, [], [(0.0, 6.0)], (0.0, 10.0), n_requests=1, host=host)
+    assert breakdown(ctx)["idle_gaps"] == [
+        # Under no program span: the client's.
+        ["bench.sleep", 2.0],
+        # Under the dispatch, inside the whole request: the dispatch.
+        ["serving.dispatch", 1.0],
+        # Under the whole request alone: the client's.
+        ["bench.await", 1.0]]
+    assert trace.label_gaps([(20.0, 21.0)], host) == [("-", 1.0)]
+
+
+def test_a_gap_tie_goes_to_the_shorter_program_span():
+    host = [Op(-1, 0.0, 4.0, "serving.encode_idle"),
+            Op(-1, 1.0, 3.0, "serving.scan_idle"),
+            Op(-1, 0.0, 4.0, "bench.sleep")]
+    assert trace.label_gaps([(1.5, 2.5)], host) == [("serving.scan_idle",
+                                                      1.0)]
+    # Overlap, not length, comes first.
+    assert trace.label_gaps([(0.5, 2.5)], host) == [("serving.encode_idle",
+                                                      2.0)]

@@ -72,3 +72,31 @@ def test_a_list_probed_by_many_queries_is_read_once():
     w = registry.work("ivf").least(cfg, q, obs)
     assert w["int8_ops"] == 2 * 2 * 7 * 3
     assert w["bytes"] == 7 * (1 + 8) + 2 * 2 * 4
+
+
+@pytest.mark.parametrize("packed", [True, False])
+def test_bigranular_reads_coarse_bits_and_its_survivors_full_rows(packed):
+    # The web product's two tiers: 2 of 4 levels scanned for 100
+    # survivors a query, whatever width a program stores the coarse tier.
+    cfg = _cfg("bigranular", packed, n_docs=2**24, coarse_levels=2,
+               k_coarse=100)
+    w = registry.work("bigranular").least(cfg, np.zeros((8, 128), np.int8),
+                                          None)
+    coarse, rerank = w["parts"]["coarse"], w["parts"]["rerank"]
+    assert coarse == {"int8_ops": 2 * 8 * 2**24 * 128,
+                      "bytes": 2**24 * (32 + 4)}
+    assert rerank == {"int8_ops": 2 * 8 * 100 * 128,
+                      "bytes": 8 * 100 * (64 + 4)}
+    assert w["int8_ops"] == coarse["int8_ops"] + rerank["int8_ops"]
+    assert w["bytes"] == coarse["bytes"] + rerank["bytes"] == 604_034_176
+    assert w["flops"] == 8 * WEB_ENCODER_FLOPS_PER_QUERY
+    assert w["bytes"] / 819e9 == pytest.approx(0.7375e-3, rel=1e-3)
+
+
+def test_bigranular_reranks_no_more_survivors_than_documents():
+    cfg = _cfg("bigranular", True, n_docs=50, coarse_levels=3, k_coarse=100)
+    w = registry.work("bigranular").least(cfg, np.zeros((2, 128), np.int8),
+                                          None)
+    assert w["parts"]["coarse"]["bytes"] == 50 * (48 + 4)
+    assert w["parts"]["rerank"] == {"int8_ops": 2 * 2 * 50 * 128,
+                                    "bytes": 2 * 50 * (64 + 4)}

@@ -28,6 +28,12 @@ DEVICE_PLANE = re.compile(r"^/device:TPU:(\d+)$")
 OPS_LINE = "XLA Ops"
 MODULES_LINE = "XLA Modules"
 SYNC = "bench.sync"
+CLIENT = "bench."  # the client's own spans (``traffic.py``)
+# The program's spans (``src/repro/launch/spans.py``) are named
+# ``<layer>.<stage>`` in lower case: ``serving.dispatch``, ``proxy.submit``.
+# The runtime's host events are not (``PjitFunction(f)``, ``$file:line f``).
+PROGRAM_SPAN = re.compile(r"^[a-z][a-z0-9_]*(\.[a-z][a-z0-9_]*)+$")
+WHOLE_REQUEST = "proxy.request"
 
 
 @dataclasses.dataclass
@@ -116,6 +122,13 @@ def short(name: str) -> str:
     return name.split(" = ", 1)[0].lstrip("%")
 
 
+def base(name: str) -> str:
+    """An instruction name without the ``.N`` that XLA numbers it with,
+    which changes from compile to compile: ``copy.4`` -> ``copy``,
+    ``sdc_topk_q128.1`` -> ``sdc_topk_q128``."""
+    return re.sub(r"\.\d+$", "", name)
+
+
 def time_by_name(ops: Iterable[Op], lo: float, hi: float):
     """{op instruction name: seconds of device time inside [lo, hi]},
     summed over chips and calls."""
@@ -128,18 +141,29 @@ def time_by_name(ops: Iterable[Op], lo: float, hi: float):
     return out
 
 
+def _most_over(s: float, e: float, spans: List[Op]) -> Optional[str]:
+    """The span that overlaps [s, e] most, the shorter on a tie."""
+    best = max(((min(e, h.end) - max(s, h.start), h.start - h.end, h.name)
+                for h in spans), default=None)
+    return best[2] if best is not None and best[0] > 0 else None
+
+
 def label_gaps(idle: List[Interval], host: List[Op], top: int = 10):
-    """The ``top`` longest idle stretches, each named after the host span
-    that overlaps it most (``-`` where none does)."""
-    out = []
-    for s, e in sorted(idle, key=lambda g: g[0] - g[1])[:top]:
-        best, name = 0.0, "-"
-        for h in host:
-            ov = min(e, h.end) - max(s, h.start)
-            if ov > best:
-                best, name = ov, h.name
-        out.append((name, e - s))
-    return out
+    """The ``top`` longest idle stretches, each named after the program
+    span that overlaps it most, the shorter on a tie (``proxy.request``,
+    which holds a whole request, names none); where no program span
+    overlaps it, after the benchmark client's span that does; else
+    ``-``."""
+    program, client = [], []
+    for h in host:
+        if h.name.startswith(CLIENT):
+            if h.name != SYNC:
+                client.append(h)
+        elif h.name != WHOLE_REQUEST and PROGRAM_SPAN.match(h.name):
+            program.append(h)
+    return [(_most_over(s, e, program) or _most_over(s, e, client) or "-",
+             e - s)
+            for s, e in sorted(idle, key=lambda g: g[0] - g[1])[:top]]
 
 
 # ---------------------------------------------------------------------------
