@@ -26,13 +26,12 @@ from benchmarks.common import timeit
 from repro.core.binarize_lib import (
     coarse_codes,
     pack_bitplanes,
-    pack_codes_nibbles,
     sdc_affine_epilogue,
     unpack_codes,
 )
 from repro.index import ivf as ivf_lib
 from repro.kernels.sdc import ref as R
-from repro.kernels.sdc.ops import sdc_search_xla
+from repro.kernels.sdc.ops import pack_scan_corpus, sdc_search_xla
 
 
 N, Q, M = 100_000, 16, 64  # corpus, queries, code dim (256 bits at u=4)
@@ -183,7 +182,7 @@ def _bits_sweep_rows(n_docs: int, queries: int, m: int, k: int = 10,
         cd = values_to_codes(jnp.clip(emb_d * scale, lo, hi), levels)
         cq = values_to_codes(jnp.clip(emb_q * scale, lo, hi), levels)
         inv = R.doc_inv_norms(cd, levels)
-        cd_packed = pack_codes_nibbles(cd)
+        cd_packed = pack_scan_corpus(cd)
         for packed in (False, True):
             d = cd_packed if packed else cd
             t, out = timeit(lambda: sdc_search_xla(
@@ -362,7 +361,7 @@ def emit_sdc_scan_json(path: str = BENCH_JSON, n_docs: int = 50_000,
     cq = jax.random.randint(jax.random.fold_in(key, 1), (queries, m), 0,
                             2**levels).astype(jnp.int8)
     inv = R.doc_inv_norms(cd, levels)
-    cd_packed = pack_codes_nibbles(cd)
+    cd_packed = pack_scan_corpus(cd)
 
     rows = []
 

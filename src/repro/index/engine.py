@@ -15,8 +15,9 @@ and IVF. ``backend="pallas"`` runs the fused scan+top-k Pallas kernel on
 each leaf (no [Q, shard_N] score matrix in HBM); ``backend="xla"`` is the
 jnp fallback for CPU meshes (identical scores, shared epilogue);
 ``backend="interpret"`` exercises the kernel under the Pallas interpreter
-in tests. ``packed=True`` shards a nibble-packed uint8 [N, D//2] corpus,
-halving per-leaf scan bandwidth.
+in tests. ``packed=True`` shards a nibble-packed uint8 [D//2, N] corpus
+(documents along lanes, ``ops.pack_scan_corpus``) on its document axis,
+the last, halving per-leaf scan bandwidth.
 
 Three first-class leaf index types share the proxy/merge skeleton:
   * flat  — exhaustive leaf scan (``make_distributed_search``);
@@ -39,7 +40,12 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.index.hnsw_lite import ShardedHNSW, hnsw_frontier_search
 from repro.kernels.sdc.defaults import BLOCK_N, plan_for
-from repro.kernels.sdc.ops import resolve_backend, sdc_search, sdc_search_xla
+from repro.kernels.sdc.ops import (
+    pack_scan_corpus,
+    resolve_backend,
+    sdc_search,
+    sdc_search_xla,
+)
 
 
 def _leaf_scan(
@@ -89,6 +95,12 @@ def _leaf_scan(
     return vals, jnp.where(idx >= 0, idx + shard_base, -1)
 
 
+def _codes_spec(axes: Tuple[str, ...], packed: bool) -> P:
+    """The corpus shards on its document axis: the first of an unpacked
+    [N, D] corpus, the last of a packed [D//2, N] one."""
+    return P(None, axes) if packed else P(axes)
+
+
 def _make_search(
     mesh: Mesh,
     *,
@@ -106,7 +118,7 @@ def _make_search(
     backend = resolve_backend(backend)
 
     def search(q_codes, d_codes, d_inv, *rest):
-        shard_n = d_codes.shape[0]  # per-leaf rows under shard_map
+        shard_n = d_inv.shape[0]  # per-leaf documents under shard_map
         # Leaf rank: linearised index over the sharded axes.
         rank = jnp.zeros((), jnp.int32)
         for ax in axes:
@@ -132,7 +144,8 @@ def _make_search(
         merged_ids = jnp.take_along_axis(all_ids, pos, axis=-1)
         return merged_vals, merged_ids
 
-    in_specs = (P(), P(axes), P(axes)) + ((P(),) if failover else ())
+    in_specs = (P(), _codes_spec(axes, packed), P(axes))
+    in_specs += (P(),) if failover else ()
     fn = shard_map(
         search, mesh=mesh, in_specs=in_specs, out_specs=(P(), P()),
         check_vma=False,
@@ -155,9 +168,11 @@ def make_distributed_search(
     """Build a pjit-able global search fn over a mesh.
 
     Inputs (global shapes):
-      q_codes [Q, D] int8 (replicated), d_codes [N, D] int8 — or
-      nibble-packed uint8 [N, D//2] with ``packed=True`` — sharded on
-      axis 0 across shard_axes, d_inv [N] f32 (same sharding).
+      q_codes [Q, D] int8 (replicated), d_codes [N, D] int8 sharded on
+      axis 0 across shard_axes — or with ``packed=True`` nibble-packed
+      uint8 [D//2, N] (``ops.pack_scan_corpus``) sharded on axis 1 —
+      and d_inv [N] f32 sharded as the documents are
+      (``engine_input_shardings``).
     Output: (scores [Q, k], global ids [Q, k]) replicated.
 
     ``block_q`` None sizes every leaf's query tile from the request
@@ -176,11 +191,12 @@ def make_distributed_search(
     )
 
 
-def engine_input_shardings(mesh: Mesh, shard_axes=("data", "model")):
+def engine_input_shardings(mesh: Mesh, shard_axes=("data", "model"),
+                           packed: bool = False):
     """NamedShardings matching make_distributed_search's expectations."""
     return (
         NamedSharding(mesh, P()),
-        NamedSharding(mesh, P(shard_axes)),
+        NamedSharding(mesh, _codes_spec(shard_axes, packed)),
         NamedSharding(mesh, P(shard_axes)),
     )
 
@@ -324,13 +340,14 @@ def flat_engine_inputs_from_snapshot(
     coarse_levels: int = None,
 ) -> Tuple[jax.Array, jax.Array]:
     """Host-side shared flat-engine inputs from a snapshot's unpacked
-    codes: (codes [nibble-packed when ``packed``], inverse doc norms).
+    codes: (codes [nibble-packed [D//2, N] when ``packed``], inverse doc
+    norms).
     Replica-independent, so a rolling swap computes them once per
     snapshot and reuses them for every replica's device placement
     (``launch/lifecycle.EngineBuilder``). With ``coarse_levels`` the
     inputs are the hot coarse tier of a bi-granular engine: level-prefix
     codes and their inverse norms at ``coarse_levels`` levels."""
-    from repro.core.binarize_lib import coarse_codes, pack_codes_nibbles
+    from repro.core.binarize_lib import coarse_codes
     from repro.kernels.sdc import ref as _ref
 
     codes = jnp.asarray(codes)
@@ -339,7 +356,7 @@ def flat_engine_inputs_from_snapshot(
         n_levels = coarse_levels
     inv = _ref.doc_inv_norms(codes, n_levels)
     if packed:
-        codes = pack_codes_nibbles(codes)
+        codes = pack_scan_corpus(codes)
     return codes, inv
 
 
@@ -411,7 +428,7 @@ def engine_search_from_snapshot(
             mesh, n_levels=n_levels, k=k, shard_axes=shard_axes,
             backend=backend, packed=packed, block_q=block_q, block_n=block_n,
         )
-        qspec, *in_specs = engine_input_shardings(mesh, shard_axes)
+        qspec, *in_specs = engine_input_shardings(mesh, shard_axes, packed)
         ins = [jax.device_put(a, s) for a, s in zip(prepared, in_specs)]
 
         def snapshot_search(q_codes):
@@ -435,7 +452,7 @@ def engine_search_from_snapshot(
         mesh, n_levels=c_levels, k=k_coarse, shard_axes=shard_axes,
         backend=backend, packed=packed_c, block_q=block_q, block_n=block_n,
     )
-    qspec, *in_specs = engine_input_shardings(mesh, shard_axes)
+    qspec, *in_specs = engine_input_shardings(mesh, shard_axes, packed_c)
     ins = [jax.device_put(a, s) for a, s in zip(prepared, in_specs)]
     fine_codes = codes if isinstance(codes, np.ndarray) else jnp.asarray(codes)
     fine_inv = fine_inv_norms(fine_codes, n_levels)

@@ -15,16 +15,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.binarize_lib import (
-    coarse_codes,
-    pack_bitplanes,
-    pack_codes_nibbles,
-    unpack_codes,
-)
+from repro.core.binarize_lib import coarse_codes, pack_bitplanes, unpack_codes
 from repro.kernels.binary_dot.ops import binary_dot_search
 from repro.kernels.sdc import ref as sdc_ref
 from repro.kernels.sdc.defaults import BLOCK_N, BlockPlan, plan_for
-from repro.kernels.sdc.ops import sdc_search_backend
+from repro.kernels.sdc.ops import pack_scan_corpus, sdc_search_backend
 from repro.kernels.sdc.rerank import fine_inv_norms, sdc_rerank_backend
 
 
@@ -48,7 +43,9 @@ class FlatFloat:
 
 @dataclasses.dataclass
 class FlatSDC:
-    codes: jax.Array  # [N, m] int8; nibble-packed uint8 [N, m//2] if packed
+    # [N, m] int8; if packed, nibble-packed uint8 [m//2, N], documents
+    # along lanes (ops.pack_scan_corpus), as the scan streams it
+    codes: jax.Array
     inv_norm: jax.Array  # [N] f32
     n_levels: int
     packed: bool = False  # int4 code streaming (2 dims/byte in HBM)
@@ -65,14 +62,13 @@ class FlatSDC:
                 raise ValueError(
                     f"packed codes need n_levels <= 4, got {n_levels}"
                 )
-            codes = pack_codes_nibbles(codes)
+            codes = pack_scan_corpus(codes)
         return FlatSDC(codes=codes, inv_norm=inv, n_levels=n_levels,
                        packed=packed, backend=backend)
 
     @property
     def code_dim(self) -> int:
-        m = self.codes.shape[1]
-        return m * 2 if self.packed else m
+        return self.codes.shape[0] * 2 if self.packed else self.codes.shape[1]
 
     def search(
         self, q_codes: jax.Array, k: int, block_n: int = BLOCK_N,
@@ -97,7 +93,7 @@ class FlatSDC:
     def nbytes(self) -> int:
         # 4-bit codes pack two dims per byte on disk; +4B quantised norm.
         packed_codes = (self.code_dim * self.n_levels + 7) // 8
-        return self.codes.shape[0] * (packed_codes + 4)
+        return self.inv_norm.shape[0] * (packed_codes + 4)
 
 
 @dataclasses.dataclass

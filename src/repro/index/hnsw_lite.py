@@ -371,8 +371,10 @@ def hnsw_frontier_search(
     e_valid = entries >= 0
     e_ids = jnp.where(e_valid, entries, 0)
     e_inv = jnp.where(e_valid, inv_norm[e_ids], 0.0)
+    e_codes = codes[e_ids]
     res_vals, e_pos = sdc_search_xla(
-        q_codes, codes[e_ids], e_inv, n_levels=n_levels, k=ef, packed=packed
+        q_codes, e_codes.T if packed else e_codes, e_inv, n_levels=n_levels,
+        k=ef, packed=packed,
     )
     res_ids = jnp.where(
         e_pos >= 0, entries[jnp.clip(e_pos, 0, E - 1)], -1

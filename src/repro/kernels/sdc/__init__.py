@@ -25,9 +25,17 @@ engine):
 int4 packed code layout (``packed=True``, requires ``n_levels <= 4``):
   document codes are stored nibble-packed at 2 dims/byte — byte ``j``
   holds dim ``2j`` in its low nibble and dim ``2j+1`` in its high nibble
-  (``binarize_lib.pack_codes_nibbles``). Kernels unpack with shift+mask
-  on the VPU and score via two half-width int8 MXU matmuls
-  (q_even . lo + q_odd . hi), so HBM traffic per scanned document halves
-  while integer partial sums — and therefore scores — stay bit-identical
-  to the int8 path. Queries stay unpacked (they are tiny and replicated).
+  (``binarize_lib.pack_codes_nibbles``). Two layouts hold those bytes:
+    * the flat scan's corpus (``sdc``: ``FlatSDC``, the engine's leaves,
+      the bi-granular coarse tier) is uint8 [D//2, N], documents along
+      lanes (``ops.pack_scan_corpus``). A tile is unpacked with
+      shift+mask on the VPU, its two nibble planes stacked along
+      sublanes, and scored by one NN int8 MXU matmul against queries
+      permuted even dims then odd;
+    * lists that the gather kernel reads (``gather``: IVF, HNSW, the
+      rerank's candidates) stay uint8 [L, D//2], a document a row, and
+      are scored by two half-width NT matmuls (q_even . lo + q_odd . hi).
+  Either way HBM traffic per scanned document halves while integer
+  partial sums — and therefore scores — stay bit-identical to the int8
+  path. Queries stay unpacked (they are tiny and replicated).
 """

@@ -21,6 +21,7 @@ import jax.numpy as jnp
 
 from repro.core.binarize_lib import (
     SDC_NEG_INF,
+    pack_codes_nibbles,
     sdc_affine_epilogue,
     unpack_nibble_planes,
 )
@@ -42,14 +43,22 @@ def resolve_backend(backend: str = "auto") -> str:
     return backend
 
 
-def _pad_to(x: jax.Array, axis: int, multiple: int, value=0):
-    n = x.shape[axis]
-    pad = (-n) % multiple
+@jax.jit
+def pack_scan_corpus(codes: jax.Array) -> jax.Array:
+    """Integer codes [N, D] (values < 16) -> the flat scan's packed corpus:
+    nibble-packed uint8 [D//2, N], documents along lanes. Done once, when
+    an index is built; every packed flat scan reads this layout."""
+    return pack_codes_nibbles(codes).T
+
+
+def _pad_to(x: jax.Array, axis: int, multiple: int) -> jax.Array:
+    """``x`` zero-padded along ``axis`` to a multiple; as it is if whole."""
+    pad = (-x.shape[axis]) % multiple
     if pad == 0:
-        return x, n
+        return x
     widths = [(0, 0)] * x.ndim
     widths[axis] = (0, pad)
-    return jnp.pad(x, widths, constant_values=value), n
+    return jnp.pad(x, widths)
 
 
 def _ceil_mult(n: int, m: int) -> int:
@@ -79,8 +88,9 @@ def sdc_search(
 
     Args:
       q_codes: [Q, D] int8 recurrent-binary codes of queries.
-      d_codes: [N, D] int8 codes of documents, or nibble-packed uint8
-        [N, D//2] when ``packed=True``.
+      d_codes: [N, D] int8 codes of documents, or with ``packed=True`` the
+        nibble-packed uint8 [D//2, N] corpus, documents along lanes
+        (``pack_scan_corpus``).
       d_inv_norm: [N] f32 reciprocal doc-value norms (0 => excluded).
       block_q: query tile; None derives it from Q
         (``defaults.scan_block_q``).
@@ -96,14 +106,13 @@ def sdc_search(
     # The fused kernel tiles the running top-k against its N block, so the
     # effective block must hold k entries; keep it a multiple of block_n so
     # lane alignment survives. N is padded against the same effective block
-    # (this also guarantees padded N >= k for the final top_k).
+    # (this also guarantees padded N >= k for the final top_k). Padded
+    # documents get a zero inverse norm, which every kernel excludes; a
+    # corpus that is already a whole number of blocks is passed as it is.
     eff_bn = _ceil_mult(max(k, block_n), block_n)
-    q_codes, _ = _pad_to(q_codes, 0, block_q)
-    d_codes, N0 = _pad_to(d_codes, 0, eff_bn)
-    d_inv_norm, _ = _pad_to(d_inv_norm, 0, eff_bn)
-    # Force padded docs out of the ranking (kernels treat inv 0 as excluded).
-    valid = jnp.arange(d_codes.shape[0]) < N0
-    d_inv_norm = jnp.where(valid, d_inv_norm, 0.0)
+    q_codes = _pad_to(q_codes, 0, block_q)
+    d_codes = _pad_to(d_codes, 1 if packed else 0, eff_bn)
+    d_inv_norm = _pad_to(d_inv_norm, 0, eff_bn)
 
     if fused:
         vals, idx = sdc_topk(
@@ -150,15 +159,16 @@ def sdc_search_xla(
     Same contract as ``sdc_search``; XLA fuses the affine epilogue into the
     int32 matmul so CPU meshes get one matmul + top-k without the Pallas
     interpreter's Python overhead. Packed corpora are scored through the
-    same even/odd half-matmul decomposition as the kernel, so scores stay
-    bit-identical to the unpacked path.
+    same even/odd half-matmul decomposition as the kernel (the packed
+    corpus is [D//2, N], documents along lanes, as the kernel reads it),
+    so scores stay bit-identical to the unpacked path.
     """
     D = q_codes.shape[-1]
     cq = q_codes.astype(jnp.int32)
     if packed:
         lo, hi = unpack_nibble_planes(d_codes)
-        dot = cq[:, 0::2] @ lo.T + cq[:, 1::2] @ hi.T
-        sd = (jnp.sum(lo, -1) + jnp.sum(hi, -1))[None, :]
+        dot = cq[:, 0::2] @ lo + cq[:, 1::2] @ hi
+        sd = (jnp.sum(lo, 0) + jnp.sum(hi, 0))[None, :]
     else:
         cd = d_codes.astype(jnp.int32)
         dot = cq @ cd.T

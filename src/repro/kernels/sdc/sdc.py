@@ -12,7 +12,9 @@ Layout/tiling:
   * codes stream HBM -> VMEM at 8 bits/dim (4 meaningful), documents tiled
     along N, queries tiled along Q; the code dim D stays whole (D <= 2048
     in all BEBR deployments => a (512, D) int8 tile is <= 1 MiB of VMEM).
-  * MXU tiles want multiples of (128, 128); defaults TQ=128, TN=512.
+  * MXU tiles want multiples of (128, 128); the query tile follows the
+    request (``defaults.scan_block_q``), the document tile is
+    ``defaults.BLOCK_N``.
   * reciprocal norms travel as a [1, N] row so their (1, TN) blocks obey
     the (8, 128) block rule and broadcast over the (TQ, TN) score tile.
   * int32 accumulation is exact — unlike the paper's saturating int8/16
@@ -25,12 +27,24 @@ int4 packed code streaming (``packed=True``):
     nibble-packed (2 dims/byte; byte j = dim 2j | dim 2j+1 << 4, see
     ``binarize_lib.pack_codes_nibbles``), halving HBM traffic per scanned
     document — the scan is memory-bound, so this is ~2x effective speedup.
-  * in-kernel unpack is shift+mask on the VPU in int32; queries (tiny) stay
-    unpacked and are pre-split into even/odd dim halves so the scan is two
-    half-width int8 MXU matmuls (same MAC count as one full-width one):
-        c_q . c_d = q_even . lo(d_packed) + q_odd . hi(d_packed).
+  * the flat scan's packed corpus lies documents along lanes: uint8
+    [D//2, N], D//2 = 64 sublanes by N lanes at the web
+    widths, so every vreg of a (D//2, TN) tile is full. A [N, D//2] corpus
+    would fill half of each 128-lane row; XLA then relayouts the whole
+    corpus to 128 lanes in front of every call, and each step would
+    transpose its document planes for an NT matmul.
+  * in-kernel unpack is shift+mask on the VPU in int32; the two nibble
+    planes stack along sublanes into one [D, TN] int8 plane (even dims,
+    then odd), and the queries (tiny) are permuted to match outside the
+    kernel (``_interleave_queries``), so the scan is one NN int8 MXU
+    matmul with no transpose:
+        c_q . c_d = [q_even | q_odd] . [lo(d_packed); hi(d_packed)].
+    The document code sums are a sublane sum, which lands as the (1, TN)
+    row the epilogue broadcasts.
   * integer partial sums are identical to the int8 path, so packed scores
     are bit-identical to unpacked scores.
+  * lists that the gather kernel reads one row at a time (IVF, HNSW,
+    rerank) stay [L, D//2] (``_tile_scores_packed``).
 
 Top-k inside a kernel (``select_topk``): Mosaic has no sort or top_k, so
 the running top-k is k rounds of "row max, lowest key among the maxima,
@@ -76,6 +90,16 @@ def _check_code_dim(d_codes, D: int, packed: bool) -> None:
         raise ValueError(
             f"document code dim {d_codes.shape[-1]} (shape {d_codes.shape}) "
             f"!= expected {want} for query dim D={D}, packed={packed}"
+        )
+
+
+def _check_packed_corpus(d_codes, D: int) -> None:
+    if d_codes.shape[0] != D // 2:
+        raise ValueError(
+            f"packed corpus code dim {d_codes.shape[0]} (shape "
+            f"{d_codes.shape}) != expected {D // 2} for query dim D={D}: "
+            "the flat scan's packed corpus is [D//2, N], documents along "
+            "lanes (ops.pack_scan_corpus)"
         )
 
 
@@ -138,20 +162,38 @@ def _tile_scores_packed(qe, qo, p, inv, *, n_levels: int, dim: int) -> jax.Array
     return _epilogue(dot, sq, sd, inv, n_levels=n_levels, dim=dim)
 
 
-def _sdc_kernel(q_ref, d_ref, dnorm_ref, out_ref, *, n_levels: int, dim: int):
-    """One (TQ, TN) score tile (unpacked int8 codes)."""
-    out_ref[...] = _tile_scores(
-        q_ref[...], d_ref[...], dnorm_ref[...], n_levels=n_levels, dim=dim
+def _tile_scores_lanes(q, p, inv, *, n_levels: int, dim: int) -> jax.Array:
+    """Same scores for a tile of the flat scan's packed corpus, which lies
+    documents along lanes.
+
+    q: [TQ, D] int8 query codes, even dims then odd
+       (``_interleave_queries``).
+    p: [D//2, TN] uint8 packed document codes, one document a lane.
+    The nibble planes stack along sublanes in the same even-then-odd
+    order, so one (TQ, D) @ (D, TN) matmul forms the dot with no
+    transpose, and the document sums are a sublane sum that is already
+    the (1, TN) row the epilogue broadcasts. The integers equal the
+    unpacked path's, so the scores are bit-identical to it.
+    """
+    planes = jnp.concatenate(unpack_nibble_planes(p), axis=0)  # [D, TN]
+    dot = jax.lax.dot_general(
+        q, planes.astype(jnp.int8),
+        dimension_numbers=(((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.int32,
     )
+    sq = jnp.sum(q.astype(jnp.int32), axis=-1, keepdims=True)
+    sd = jnp.sum(planes, axis=0, keepdims=True)
+    scores = sdc_affine_epilogue(
+        dot, sq + sd, dim=dim, n_levels=n_levels, inv_norm=inv
+    )
+    return jnp.where(inv > 0, scores, SDC_NEG_INF)
 
 
-def _sdc_kernel_packed(
-    qe_ref, qo_ref, d_ref, dnorm_ref, out_ref, *, n_levels: int, dim: int
-):
-    """One (TQ, TN) score tile (nibble-packed document codes)."""
-    out_ref[...] = _tile_scores_packed(
-        qe_ref[...], qo_ref[...], d_ref[...], dnorm_ref[...],
-        n_levels=n_levels, dim=dim,
+def _sdc_kernel(q_ref, d_ref, dnorm_ref, out_ref, *, score, n_levels: int,
+                dim: int):
+    """One (TQ, TN) score tile."""
+    out_ref[...] = score(
+        q_ref[...], d_ref[...], dnorm_ref[...], n_levels=n_levels, dim=dim
     )
 
 
@@ -160,12 +202,36 @@ def _split_queries(q_codes: jax.Array):
     return q_codes[:, 0::2], q_codes[:, 1::2]
 
 
+def _interleave_queries(q_codes: jax.Array) -> jax.Array:
+    """[Q, D] int8 -> [Q, D], even dims then odd: the order in which
+    ``_tile_scores_lanes`` stacks a packed tile's nibble planes."""
+    return jnp.concatenate(_split_queries(q_codes), axis=1)
+
+
 def _doc_specs(block_n: int, Dc: int):
     """BlockSpecs of the document codes and their [1, N] norm row."""
     return [
         pl.BlockSpec((block_n, Dc), lambda i, j: (j, 0)),
         pl.BlockSpec((1, block_n), lambda i, j: (0, j)),
     ]
+
+
+def _scan_operands(q_codes, d_codes, *, packed: bool, block_n: int):
+    """(queries, N, tile scorer, document BlockSpecs) of a flat scan.
+
+    An unpacked corpus is [N, D] int8, a document a row. A packed one is
+    [D//2, N] uint8, a document a lane: its tiles are (D//2, block_n) at
+    block (0, j), and the queries are permuted to its plane order.
+    """
+    D = q_codes.shape[1]
+    norm_spec = pl.BlockSpec((1, block_n), lambda i, j: (0, j))
+    if packed:
+        _check_packed_corpus(d_codes, D)
+        spec = pl.BlockSpec((D // 2, block_n), lambda i, j: (0, j))
+        return (_interleave_queries(q_codes), d_codes.shape[1],
+                _tile_scores_lanes, [spec, norm_spec])
+    _check_code_dim(d_codes, D, False)
+    return q_codes, d_codes.shape[0], _tile_scores, _doc_specs(block_n, D)
 
 
 @functools.partial(
@@ -186,41 +252,21 @@ def sdc_scores(
 
     Q and N must be multiples of block_q / block_n (callers pad; see
     ops.sdc_search which handles padding + top-k). With ``packed=True``,
-    d_codes is the nibble-packed uint8 [N, D//2] corpus. Documents with
-    d_inv_norm == 0 score SDC_NEG_INF (excluded).
+    d_codes is the nibble-packed uint8 [D//2, N] corpus, documents along
+    lanes. Documents with d_inv_norm == 0 score SDC_NEG_INF (excluded).
     """
+    q, N, score, d_specs = _scan_operands(q_codes, d_codes, packed=packed,
+                                          block_n=block_n)
     Q, D = q_codes.shape
-    N = d_codes.shape[0]
-    _check_code_dim(d_codes, D, packed)
     _check_block_tiling(Q, N, block_q, block_n)
-
-    grid = (Q // block_q, N // block_n)
-    out_spec = pl.BlockSpec((block_q, block_n), lambda i, j: (i, j))
-    out_shape = jax.ShapeDtypeStruct((Q, N), jnp.float32)
-    d_specs = _doc_specs(block_n, d_codes.shape[1])
-    inv_row = d_inv_norm.reshape(1, N)
-    if packed:
-        qe, qo = _split_queries(q_codes)
-        return pl.pallas_call(
-            functools.partial(_sdc_kernel_packed, n_levels=n_levels, dim=D),
-            grid=grid,
-            in_specs=[
-                pl.BlockSpec((block_q, D // 2), lambda i, j: (i, 0)),
-                pl.BlockSpec((block_q, D // 2), lambda i, j: (i, 0)),
-                *d_specs,
-            ],
-            out_specs=out_spec,
-            out_shape=out_shape,
-            interpret=interpret,
-        )(qe, qo, d_codes, inv_row)
     return pl.pallas_call(
-        functools.partial(_sdc_kernel, n_levels=n_levels, dim=D),
-        grid=grid,
+        functools.partial(_sdc_kernel, score=score, n_levels=n_levels, dim=D),
+        grid=(Q // block_q, N // block_n),
         in_specs=[pl.BlockSpec((block_q, D), lambda i, j: (i, 0)), *d_specs],
-        out_specs=out_spec,
-        out_shape=out_shape,
+        out_specs=pl.BlockSpec((block_q, block_n), lambda i, j: (i, j)),
+        out_shape=jax.ShapeDtypeStruct((Q, N), jnp.float32),
         interpret=interpret,
-    )(q_codes, d_codes, inv_row)
+    )(q, d_codes, d_inv_norm.reshape(1, N))
 
 
 def select_topk(parts, k: int):
@@ -309,24 +355,12 @@ def _fold_tile(vals_ref, idx_ref, scores, *, j, k: int, block_n: int):
 
 
 def _sdc_topk_kernel(
-    q_ref, d_ref, dnorm_ref, vals_ref, idx_ref, *, n_levels, dim, k, block_n
+    q_ref, d_ref, dnorm_ref, vals_ref, idx_ref, *, score, n_levels, dim, k,
+    block_n,
 ):
     """Fused scan + running top-k (streaming reduction over the N grid)."""
-    scores = _tile_scores(
+    scores = score(
         q_ref[...], d_ref[...], dnorm_ref[...], n_levels=n_levels, dim=dim
-    )
-    _fold_tile(vals_ref, idx_ref, scores, j=pl.program_id(1), k=k,
-               block_n=block_n)
-
-
-def _sdc_topk_kernel_packed(
-    qe_ref, qo_ref, d_ref, dnorm_ref, vals_ref, idx_ref,
-    *, n_levels, dim, k, block_n,
-):
-    """Packed-int4 variant of the fused scan+top-k kernel."""
-    scores = _tile_scores_packed(
-        qe_ref[...], qo_ref[...], d_ref[...], dnorm_ref[...],
-        n_levels=n_levels, dim=dim,
     )
     _fold_tile(vals_ref, idx_ref, scores, j=pl.program_id(1), k=k,
                block_n=block_n)
@@ -352,56 +386,33 @@ def sdc_topk(
 
     Avoids materialising the [Q, N] score matrix in HBM — the dominant
     memory term of the naive pipeline (hillclimbed in EXPERIMENTS.md §Perf).
-    Excluded documents (inv norm 0) surface as SDC_NEG_INF values.
+    Excluded documents (inv norm 0) surface as SDC_NEG_INF values. The
+    corpus is laid out as for ``sdc_scores``.
     """
+    q, N, score, d_specs = _scan_operands(q_codes, d_codes, packed=packed,
+                                          block_n=block_n)
     Q, D = q_codes.shape
-    N = d_codes.shape[0]
     _check_block_tiling(Q, N, block_q, block_n)
     if k > block_n:
         raise ValueError(
             f"fused top-k needs k <= block_n, got k={k}, block_n={block_n} "
             "(ops.sdc_search widens the effective block for large k)"
         )
-    grid = (Q // block_q, N // block_n)
-    _check_code_dim(d_codes, D, packed)
-    d_specs = _doc_specs(block_n, d_codes.shape[1])
     kp = lane_pad(k)
-    out_specs = [
-        pl.BlockSpec((block_q, kp), lambda i, j: (i, 0)),
-        pl.BlockSpec((block_q, kp), lambda i, j: (i, 0)),
-    ]
-    out_shape = [
-        jax.ShapeDtypeStruct((Q, kp), jnp.float32),
-        jax.ShapeDtypeStruct((Q, kp), jnp.int32),
-    ]
-    inv_row = d_inv_norm.reshape(1, N)
-    kw = dict(n_levels=n_levels, dim=D, k=k, block_n=block_n)
+    out_spec = pl.BlockSpec((block_q, kp), lambda i, j: (i, 0))
     # Named by its query tile, so a device trace shows the tile it ran:
     # the corpus streams Q // block_q times a call.
-    name = f"sdc_topk_q{block_q}"
-    if packed:
-        qe, qo = _split_queries(q_codes)
-        vals, idx = pl.pallas_call(
-            functools.partial(_sdc_topk_kernel_packed, **kw),
-            grid=grid,
-            name=name,
-            in_specs=[
-                pl.BlockSpec((block_q, D // 2), lambda i, j: (i, 0)),
-                pl.BlockSpec((block_q, D // 2), lambda i, j: (i, 0)),
-                *d_specs,
-            ],
-            out_specs=out_specs,
-            out_shape=out_shape,
-            interpret=interpret,
-        )(qe, qo, d_codes, inv_row)
-    else:
-        vals, idx = pl.pallas_call(
-            functools.partial(_sdc_topk_kernel, **kw),
-            grid=grid,
-            name=name,
-            in_specs=[pl.BlockSpec((block_q, D), lambda i, j: (i, 0)), *d_specs],
-            out_specs=out_specs,
-            out_shape=out_shape,
-            interpret=interpret,
-        )(q_codes, d_codes, inv_row)
+    vals, idx = pl.pallas_call(
+        functools.partial(_sdc_topk_kernel, score=score, n_levels=n_levels,
+                          dim=D, k=k, block_n=block_n),
+        grid=(Q // block_q, N // block_n),
+        name=f"sdc_topk_q{block_q}",
+        in_specs=[pl.BlockSpec((block_q, D), lambda i, j: (i, 0)), *d_specs],
+        out_specs=[out_spec, out_spec],
+        out_shape=[
+            jax.ShapeDtypeStruct((Q, kp), jnp.float32),
+            jax.ShapeDtypeStruct((Q, kp), jnp.int32),
+        ],
+        interpret=interpret,
+    )(q, d_codes, d_inv_norm.reshape(1, N))
     return vals[:, :k], idx[:, :k]

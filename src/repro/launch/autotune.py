@@ -59,7 +59,9 @@ __all__ = [
     "tuned_block_plan",
 ]
 
-_SCHEMA = 1
+# 2: the packed scan's corpus is [D//2, N], documents along lanes; a plan
+# tuned on the [N, D//2] layout of schema 1 does not reload.
+_SCHEMA = 2
 
 # Kernel kinds whose launch geometry the list layout fixes.
 FIXED_GEOMETRY = ("gather", "rerank")
@@ -137,7 +139,8 @@ def _sweep_operands(
         rng.integers(0, hi, size=(sample_q, code_dim)).astype(np.int8)
     )
     if packed:
-        d = rng.integers(0, 256, size=(n_shard, code_dim // 2))
+        # The packed flat corpus lies documents along lanes.
+        d = rng.integers(0, 256, size=(code_dim // 2, n_shard))
         d = jnp.asarray(d.astype(np.uint8))
     else:
         d = jnp.asarray(

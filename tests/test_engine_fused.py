@@ -9,6 +9,9 @@ import subprocess
 import sys
 import textwrap
 
+import numpy as np
+import pytest
+
 SRC = os.path.join(os.path.dirname(__file__), "..", "src")
 
 
@@ -24,42 +27,58 @@ def _run(code: str) -> str:
     return out.stdout
 
 
-def test_fused_leaf_matches_xla_leaf_and_exact():
-    stdout = _run("""
+@pytest.mark.parametrize(
+    "mesh_shape,n_docs,excluded",
+    [((4, 2), 4096, False),
+     # four leaves, as the four-chip cell: 1,100 documents a leaf pads
+     # each leaf's tail, and drained documents (inv 0) stay out
+     ((2, 2), 4400, True)],
+    ids=["8-leaves", "4-leaves-tail-excluded"],
+)
+def test_fused_leaf_matches_xla_leaf_and_exact(mesh_shape, n_docs, excluded):
+    stdout = _run(f"""
         import jax, jax.numpy as jnp, numpy as np
-        from repro.core.binarize_lib import pack_codes_nibbles
+        from repro.core.binarize_lib import SDC_NEG_INF
         from repro.index.engine import make_distributed_search, engine_input_shardings
         from repro.kernels.sdc import ref as R
+        from repro.kernels.sdc.ops import pack_scan_corpus
         key = jax.random.PRNGKey(0)
-        codes = jax.random.randint(key, (4096, 64), 0, 16).astype(jnp.int8)
+        codes = jax.random.randint(key, ({n_docs}, 64), 0, 16).astype(jnp.int8)
         q = jax.random.randint(jax.random.fold_in(key,1), (8, 64), 0, 16).astype(jnp.int8)
         inv = R.doc_inv_norms(codes, 4)
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
-        qs, ds, vs = engine_input_shardings(mesh)
-        outs = {}
+        if {excluded}:
+            inv = inv.at[::3].set(0.0)
+        mesh = jax.make_mesh({mesh_shape}, ("data", "model"),
+                             devices=jax.devices()[:{np.prod(mesh_shape)}])
+        outs = {{}}
         with mesh:
-            qd = jax.device_put(q, qs); ivd = jax.device_put(inv, vs)
-            dd = jax.device_put(codes, ds)
-            pd = jax.device_put(pack_codes_nibbles(codes), ds)
-            for name, backend, d, packed in [
-                ("xla", "xla", dd, False),
-                ("fused", "interpret", dd, False),       # Pallas kernel leaf
-                ("fused_packed", "interpret", pd, True), # int4 streaming leaf
-                ("xla_packed", "xla", pd, True),
+            for name, backend, packed in [
+                ("xla", "xla", False),
+                ("fused", "interpret", False),       # Pallas kernel leaf
+                ("fused_packed", "interpret", True), # int4 streaming leaf
+                ("xla_packed", "xla", True),
             ]:
+                qs, ds, vs = engine_input_shardings(mesh, packed=packed)
+                d = pack_scan_corpus(codes) if packed else codes
+                assert d.shape == ((32, {n_docs}) if packed else ({n_docs}, 64))
                 search = make_distributed_search(
                     mesh, n_levels=4, k=10, backend=backend, packed=packed,
                     block_q=8)
-                outs[name] = search(qd, d, ivd)
+                outs[name] = search(jax.device_put(q, qs),
+                                    jax.device_put(d, ds),
+                                    jax.device_put(inv, vs))
         base_v, base_i = map(np.asarray, outs["xla"])
         for name in ("fused", "fused_packed", "xla_packed"):
             v, i = map(np.asarray, outs[name])
             np.testing.assert_array_equal(base_v, v)
             np.testing.assert_array_equal(base_i, i)
-        ev, ei = jax.lax.top_k(R.sdc_ref(q, codes, 4), 10)
-        agree = np.mean([len(set(base_i[i]) & set(np.asarray(ei[i])))/10
-                         for i in range(8)])
-        print("AGREE", agree)
+        # ref.py: the affine identity's scores, excluded documents out,
+        # ties to the lower id
+        s = R.sdc_ref_affine(q, codes, 4, inv)
+        ev, ei = jax.lax.top_k(jnp.where(inv[None, :] > 0, s, SDC_NEG_INF), 10)
+        np.testing.assert_array_equal(base_v, np.asarray(ev))
+        np.testing.assert_array_equal(base_i, np.asarray(ei))
+        print("AGREE", 1.0)
     """)
     assert "AGREE 1.0" in stdout
 
@@ -110,21 +129,26 @@ def test_hnsw_engine_backends_agree_and_recall():
     assert agree >= 0.9, stdout
 
 
-def test_failover_excludes_dead_leaf_under_kernel_path():
-    stdout = _run("""
+@pytest.mark.parametrize("packed", [False, True],
+                         ids=["unpacked", "packed"])
+def test_failover_excludes_dead_leaf_under_kernel_path(packed):
+    stdout = _run(f"""
         import jax, jax.numpy as jnp, numpy as np
         from repro.index.engine import make_failover_search, engine_input_shardings
         from repro.kernels.sdc import ref as R
+        from repro.kernels.sdc.ops import pack_scan_corpus
         key = jax.random.PRNGKey(0)
         codes = jax.random.randint(key, (4096, 64), 0, 16).astype(jnp.int8)
         q = jax.random.randint(jax.random.fold_in(key,1), (8, 64), 0, 16).astype(jnp.int8)
         inv = R.doc_inv_norms(codes, 4)
         mesh = jax.make_mesh((4, 2), ("data", "model"))
         search = make_failover_search(mesh, n_levels=4, k=10,
-                                      backend="interpret", block_q=8)
-        qs, ds, vs = engine_input_shardings(mesh)
+                                      backend="interpret", block_q=8,
+                                      packed={packed})
+        qs, ds, vs = engine_input_shardings(mesh, packed={packed})
+        d = pack_scan_corpus(codes) if {packed} else codes
         with mesh:
-            qd = jax.device_put(q, qs); dd = jax.device_put(codes, ds)
+            qd = jax.device_put(q, qs); dd = jax.device_put(d, ds)
             ivd = jax.device_put(inv, vs)
             alive = jnp.ones((8,), bool)
             v_all, i_all = search(qd, dd, ivd, alive)

@@ -19,7 +19,7 @@ from repro.core.binarize_lib import (
 from repro.index import ivf as ivf_lib
 from repro.kernels.sdc import ref as R
 from repro.kernels.sdc.gather import sdc_gather_topk
-from repro.kernels.sdc.ops import sdc_search, sdc_search_xla
+from repro.kernels.sdc.ops import pack_scan_corpus, sdc_search, sdc_search_xla
 
 
 def _corpus(seed, q, n, d, n_levels=4):
@@ -109,23 +109,70 @@ def test_nibble_pack_roundtrip():
                                   np.asarray(codes))
 
 
-@pytest.mark.parametrize("n_levels", [1, 2, 3, 4])
-def test_packed_scan_bit_identical(n_levels):
-    """int4-packed streaming must produce bit-identical scores to int8."""
-    cq, cd, _ = _corpus(n_levels, 5, 150, 64, n_levels)
-    inv = R.doc_inv_norms(cd, n_levels)
-    dp = pack_codes_nibbles(cd)
+def _world(world, cd, inv):
+    """Corpus variants the packed layout must survive: ``ties`` repeats
+    a few code rows (massive score ties across tiles), ``excluded``
+    zeroes every third inverse norm (drained or tombstoned documents)."""
+    if world == "ties":
+        cd = jnp.tile(cd[:7], (-(-cd.shape[0] // 7), 1))[:cd.shape[0]]
+        inv = R.doc_inv_norms(cd, 4)
+    elif world == "excluded":
+        inv = inv.at[::3].set(0.0)
+    return cd, inv
+
+
+def _ref_topk(cq, cd, inv, n_levels, k):
+    """Exact top-k from ref.py: the affine identity's scores, excluded
+    documents out, ties to the lower id (a stable top-k)."""
+    s = R.sdc_ref_affine(cq, cd, n_levels, inv)
+    s = jnp.where(inv[None, :] > 0, s, SDC_NEG_INF)
+    return jax.lax.top_k(s, k)
+
+
+@pytest.mark.parametrize(
+    "n_levels,q,n,block_n,world",
+    [
+        (1, 5, 150, 64, "random"),
+        (2, 5, 150, 64, "random"),
+        (3, 5, 150, 64, "random"),
+        (4, 5, 150, 64, "random"),
+        # documents along lanes at the served tiles: Q of 1, 8 and 128
+        # rows, the default document tile (None) and an explicit 512, a
+        # 2048-document tile, a padded tail (N not a multiple of the
+        # tile), ties, exclusions
+        (4, 1, 1100, 512, "random"),
+        (4, 8, 1100, 512, "ties"),
+        (4, 128, 1100, 512, "excluded"),
+        (4, 1, 2500, None, "excluded"),
+        (4, 8, 2500, None, "random"),
+        (4, 128, 2500, None, "ties"),
+        (4, 8, 2500, 2048, "excluded"),
+        (4, 128, 2500, 2048, "ties"),
+    ],
+)
+def test_packed_scan_bit_identical(n_levels, q, n, block_n, world):
+    """int4-packed streaming over the documents-along-lanes corpus must
+    produce bit-identical scores and ids to int8 and to ref.py."""
+    cq, cd, _ = _corpus(n_levels + 10 * q + n, q, n, 64, n_levels)
+    cd, inv = _world(world, cd, R.doc_inv_norms(cd, n_levels))
+    dp = pack_scan_corpus(cd)
+    assert dp.shape == (32, n) and dp.dtype == jnp.uint8
+    k = 9
+    ev, ei = _ref_topk(cq, cd, inv, n_levels, k)
+    tile = {} if block_n is None else {"block_n": block_n}
     for fused in (True, False):
-        v8, _ = sdc_search(cq, cd, inv, n_levels=n_levels, k=9, block_q=8,
-                           block_n=64, interpret=True, fused=fused)
-        v4, _ = sdc_search(cq, dp, inv, n_levels=n_levels, k=9, block_q=8,
-                           block_n=64, interpret=True, fused=fused,
-                           packed=True)
-        np.testing.assert_array_equal(np.asarray(v8), np.asarray(v4))
-    x8, _ = sdc_search_xla(cq, cd, inv, n_levels=n_levels, k=9)
-    x4, _ = sdc_search_xla(cq, dp, inv, n_levels=n_levels, k=9, packed=True)
+        v8, i8 = sdc_search(cq, cd, inv, n_levels=n_levels, k=k, block_q=8,
+                            interpret=True, fused=fused, **tile)
+        v4, i4 = sdc_search(cq, dp, inv, n_levels=n_levels, k=k,
+                            interpret=True, fused=fused, packed=True, **tile)
+        for got in ((v4, i4), (v8, i8)):
+            np.testing.assert_array_equal(np.asarray(got[0]), np.asarray(ev))
+            np.testing.assert_array_equal(np.asarray(got[1]), np.asarray(ei))
+    x8, _ = sdc_search_xla(cq, cd, inv, n_levels=n_levels, k=k)
+    x4, xi4 = sdc_search_xla(cq, dp, inv, n_levels=n_levels, k=k, packed=True)
     np.testing.assert_array_equal(np.asarray(x8), np.asarray(x4))
-    np.testing.assert_allclose(np.asarray(v8), np.asarray(x8), atol=1e-5)
+    np.testing.assert_array_equal(np.asarray(x4), np.asarray(ev))
+    np.testing.assert_array_equal(np.asarray(xi4), np.asarray(ei))
 
 
 def test_xla_backend_matches_kernel():

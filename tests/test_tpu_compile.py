@@ -6,7 +6,8 @@ main serving path (scan, IVF/HNSW gather, bi-granular rerank) at code
 dim 128 over a 2**20-document corpus or 1024 lists of 1024, and checks
 that the compiled program holds the Pallas kernel (``tpu_custom_call``)
 — what interpret-mode tests cannot see: block shapes that break the
-(8, 128) rule, ops Mosaic cannot lower, VMEM overruns.
+(8, 128) rule, ops Mosaic cannot lower, VMEM overruns, and XLA copies
+of the corpus in front of the kernel.
 
 The topology is described inside a module fixture (never at import),
 so only the worker that runs this file loads the TPU library.
@@ -63,17 +64,26 @@ def _codes(sharding, rows, packed):
 def _assert_kernel(fn, *args):
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+    return compiled
 
 
+@pytest.mark.parametrize("q_rows", [8, Q])
 @pytest.mark.parametrize("packed", [False, True], ids=["unpacked", "packed"])
-def test_flat_scan_compiles(one_chip, packed):
-    _assert_kernel(
+def test_flat_scan_compiles(one_chip, packed, q_rows):
+    """The served flat scan compiles with no corpus-sized temporary: the
+    packed corpus, [D//2, N] with documents along lanes, reaches the
+    kernel as it is stored (a corpus stored a document a row, half a
+    vreg wide, was relaid out to 128 lanes in front of every call)."""
+    corpus = (_shape(one_chip, (D // 2, N), jnp.uint8) if packed
+              else _codes(one_chip, (N,), False))
+    compiled = _assert_kernel(
         lambda q, d, inv: sdc_search(q, d, inv, n_levels=LEVELS, k=K,
                                      packed=packed),
-        _shape(one_chip, (Q, D), jnp.int8),
-        _codes(one_chip, (N,), packed),
+        _shape(one_chip, (q_rows, D), jnp.int8),
+        corpus,
         _shape(one_chip, (N,), jnp.float32),
     )
+    assert compiled.memory_analysis().temp_size_in_bytes < N
 
 
 @pytest.mark.parametrize("masked", [False, True], ids=["ivf", "masked"])
